@@ -1,7 +1,8 @@
-"""Kernel micro-benchmark stages: Pallas (interpret on CPU / compiled on
-TPU) vs the pure-jnp oracle, over a shape sweep. On this CPU container
-the number that matters is parity (max |diff|); the us/call column is
-only meaningful on real TPU hardware.
+"""Kernel micro-benchmark stages: Pallas vs the pure-jnp oracle, over a
+shape sweep. The kernels run as the platform decides
+(``kernels.interpret_mode``): compiled on a TPU, interpreted elsewhere.
+On a CPU the number that matters is parity (max |diff|); the us/call
+column times the interpreter there and is a kernel time only on a TPU.
 
 ``stage_shape`` wraps one (n, d) point as a campaign run (the ``kernels``
 stage of campaign ``all``): results land in ``kernels.<n>x<d>`` sections
@@ -33,17 +34,15 @@ def bench_shape(n: int, d: int) -> dict:
     unif = jax.random.uniform(jax.random.fold_in(key, 1), (n, d))
     qrange = jnp.max(jnp.abs(theta), axis=-1)
     delta = 2.0 * qrange / 15.0
-    us_k, out_k = time_per_call(
-        lambda *a: stoch_quantize(*a, interpret=True),
-        theta, qprev, unif, delta, qrange)
+    us_k, out_k = time_per_call(stoch_quantize,
+                                theta, qprev, unif, delta, qrange)
     us_r, out_r = time_per_call(jax.jit(ref.stoch_quantize_ref),
                                 theta, qprev, unif, delta, qrange)
     quant_diff = float(jnp.max(jnp.abs(out_k - out_r)))
 
     adj = (jax.random.uniform(key, (n, n)) > 0.5).astype(jnp.float32)
     v = jax.random.normal(key, (n, d))
-    us_mk, out_mk = time_per_call(
-        lambda *a: bipartite_mix(*a, interpret=True), adj, v)
+    us_mk, out_mk = time_per_call(bipartite_mix, adj, v)
     us_mr, out_mr = time_per_call(jax.jit(ref.bipartite_mix_ref), adj, v)
     mix_diff = float(jnp.max(jnp.abs(out_mk - out_mr)))
     return {"n": n, "d": d,

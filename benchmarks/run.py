@@ -51,6 +51,8 @@ def main(argv=None) -> int:
                     help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import campaigns
     from repro.campaign.runner import Runner
     from repro.campaign.spec import CAMPAIGNS

@@ -87,6 +87,19 @@ class ModelConfig:
     def with_overrides(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
+    def cut_depth(self, layers: int) -> "ModelConfig":
+        """The same model ``layers`` deep: only ``num_layers`` changes,
+        in whole periods of ``block_unit``, never beyond the published
+        depth; every width stays as published."""
+        period = len(self.block_unit)
+        whole = layers % period == 0 or layers == self.num_layers
+        if not (0 < layers <= self.num_layers and whole):
+            raise ValueError(
+                f"{self.name}: {layers} layers is not a whole number of "
+                f"{period}-block units {self.block_unit} within the "
+                f"published {self.num_layers}")
+        return dataclasses.replace(self, num_layers=layers)
+
     def for_long_context(self) -> "ModelConfig":
         if self.long_context == "swa_variant":
             return self.with_overrides(sliding_window=self.long_context_window)
@@ -136,6 +149,22 @@ def get_config(name: str) -> ModelConfig:
 def get_smoke_config(name: str) -> ModelConfig:
     _ensure_imported()
     return _SMOKE_REGISTRY[name]()
+
+
+def cli_config(arch: str, *, smoke: bool, layers: Optional[int],
+               tag: str) -> ModelConfig:
+    """The config a launcher runs: published (or smoke) widths, cut to
+    ``layers`` when given. Prints the cut as ``reduced: num_layers a->b``
+    and turns an impossible cut into a usage error."""
+    cfg = get_smoke_config(arch) if smoke else get_config(arch)
+    if layers is None:
+        return cfg
+    try:
+        cut = cfg.cut_depth(layers)
+    except ValueError as e:
+        raise SystemExit(f"[{tag}] bad --layers: {e}") from e
+    print(f"[{tag}] reduced: num_layers {cfg.num_layers}->{cut.num_layers}")
+    return cut
 
 
 def list_architectures():

@@ -118,6 +118,43 @@ def tree_dim(a: Tree) -> int:
     return sum(int(x.size // x.shape[0]) for x in leaves)
 
 
+# Two programs that sum the same f32 terms in another order — a Pallas mix
+# and a jnp matmul, a sparse and a dense mix — agree only to rounding, and
+# stochastic quantization turns a rounding difference into a whole
+# quantization step, which later rounds carry on. Such runs are compared by
+# their censor decisions and, for states and trajectories, by
+# ``tree_rel_err`` against this bound (tests and ``chip_smoke.py`` alike).
+# The dual ``alpha`` is where a mix's rounding shows: on the tests'
+# workloads the Pallas and sparse mixes part it from the dense matmul's by
+# at most 4.3e-3, while a mix whose operands are rounded to bfloat16 (a
+# TPU's default f32 matmul precision) parts it by 2.7e-2 and more.
+CROSS_PROGRAM_RTOL = 1e-2
+
+
+def tree_rel_err(a: Tree, b: Tree) -> float:
+    """Relative L2 error ``||a - b|| / ||b||`` over all leaves of two trees
+    of equal structure, summed in float64 on the host one row at a time
+    (LM-width leaves would lose the sum in float32). Zero when both are
+    zero; infinite when only ``b`` is."""
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    if len(la) != len(lb):
+        raise ValueError(f"trees differ: {len(la)} vs {len(lb)} leaves")
+    num = den = 0.0
+    for x, y in zip(la, lb):
+        x, y = np.asarray(x), np.asarray(y)
+        if x.shape != y.shape:
+            raise ValueError(f"leaf shapes differ: {x.shape} vs {y.shape}")
+        for xr, yr in zip(x.reshape(-1, x.shape[-1] if x.ndim else 1),
+                          y.reshape(-1, y.shape[-1] if y.ndim else 1)):
+            yr = yr.astype(np.float64)
+            d = xr.astype(np.float64) - yr
+            num += float(d @ d)
+            den += float(yr @ yr)
+    if den == 0.0:
+        return 0.0 if num == 0.0 else float("inf")
+    return float(np.sqrt(num / den))
+
+
 def tree_mix(adjacency: jax.Array, a: Tree, use_kernel: bool = False) -> Tree:
     """Neighbor sum: out_n = sum_m A[n, m] leaf_m (dense backend on a bare
     adjacency array; the engine itself mixes through the pluggable

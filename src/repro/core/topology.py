@@ -43,6 +43,7 @@ semantics.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -70,6 +71,14 @@ def _apply_flat(fn, a: Tree) -> Tree:
         buf = packing.pack(pk, a, dtype=leaves[0].dtype)
         return packing.unpack(pk, fn(buf), like=a)
     return jax.tree_util.tree_map(one, a)
+
+
+def _neighbor_sum(adjacency: jax.Array, values: jax.Array) -> jax.Array:
+    """``A @ V`` at full f32 precision. A TPU's default f32 matmul rounds
+    its operands to bfloat16, which would round every model the workers
+    exchange; with a 0/1 adjacency the full-precision product is exact."""
+    return jnp.matmul(adjacency.astype(values.dtype), values,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -157,7 +166,7 @@ class DenseTopology(Topology):
         if self.use_pallas:
             from repro.kernels import ops as kernel_ops
             return kernel_ops.bipartite_mix(self.adjacency, flat)
-        return self.adjacency.astype(flat.dtype) @ flat
+        return _neighbor_sum(self.adjacency, flat)
 
     def primal_residual(self, theta: jax.Array) -> jax.Array:
         diffs = theta[:, None, :] - theta[None, :, :]
@@ -225,7 +234,6 @@ class ShardedTopology(DenseTopology):
     backend = "sharded"
 
     def _mix_flat(self, flat: jax.Array) -> jax.Array:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         rest_size = 1
@@ -239,23 +247,45 @@ class ShardedTopology(DenseTopology):
             if self.use_pallas:
                 from repro.kernels import ops as kernel_ops
                 return kernel_ops.bipartite_mix(a_blk, v_all)
-            return a_blk.astype(v_all.dtype) @ v_all
+            return _neighbor_sum(a_blk, v_all)
 
         vspec = P(self.axis, feat)
-        return shard_map(local, mesh=self.mesh,
-                         in_specs=(P(self.axis, None), vspec),
-                         out_specs=vspec, check_rep=False)(
-                             self.adjacency, flat)
+        return jax.shard_map(local, mesh=self.mesh,
+                             in_specs=(P(self.axis, None), vspec),
+                             out_specs=vspec, check_vma=False)(
+                                 self.adjacency, flat)
+
+
+def make_mesh(shape, names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``. JAX's own default is
+    ``Explicit`` axes, which type the sharded mix's outputs as sharded and
+    then refuse to vmap them against unsharded operands (the local
+    solves); every mesh in this repo is built here."""
+    from jax.sharding import AxisType
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
+
+
+def worker_mesh(devices):
+    """1-D ``workers`` mesh over ``devices`` (one row block per device)."""
+    return make_mesh((len(devices),), ("workers",), devices=devices)
+
+
+def worker_devices(n_workers: int, devices=None) -> list:
+    """The devices ``n_workers`` workers are spread over: the most of
+    ``devices`` (default: all local devices) that take equal blocks of
+    worker rows — all of them when they divide the worker count, one when
+    nothing else divides it."""
+    devices = list(jax.devices() if devices is None else devices)
+    return devices[:math.gcd(n_workers, len(devices))]
 
 
 def _default_worker_mesh(n: int):
-    """1-D device mesh for standalone sharded runs (tests / quickstart):
-    all local devices when they divide the worker count, else degenerate
-    1-wide (the shard_map then runs single-shard — same math, same
-    explicit-sharding program structure)."""
-    n_dev = len(jax.devices())
-    width = n_dev if n_dev > 0 and n % n_dev == 0 else 1
-    return jax.make_mesh((width,), ("workers",))
+    """1-D device mesh for standalone sharded runs (tests / quickstart)
+    over ``worker_devices(n)`` (1-wide on one device: the shard_map then
+    runs single-shard — same math, same program structure)."""
+    return worker_mesh(worker_devices(n))
 
 
 def build(graph: WorkerGraph, backend: str = "dense", *,

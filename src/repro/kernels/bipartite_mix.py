@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode, worker_block
+
 BLOCK_M = 8
 BLOCK_N = 512
 BLOCK_K = 128
@@ -32,15 +34,19 @@ def _mix_kernel(a_ref, v_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
+    # full f32 precision, as the jnp mix (``core.topology``): a 0/1
+    # adjacency then sums the exchanged models exactly
     out_ref[...] += jnp.dot(a_ref[...], v_ref[...],
-                            preferred_element_type=out_ref.dtype)
+                            preferred_element_type=out_ref.dtype,
+                            precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "interpret"))
 def bipartite_mix(adjacency: jax.Array, values: jax.Array, *,
                   block_m: int = BLOCK_M, block_n: int = BLOCK_N,
-                  block_k: int = BLOCK_K, interpret: bool = True) -> jax.Array:
+                  block_k: int = BLOCK_K,
+                  interpret: bool | None = None) -> jax.Array:
     """A @ V with VMEM-tiled accumulation.
 
     Args:
@@ -57,6 +63,8 @@ def bipartite_mix(adjacency: jax.Array, values: jax.Array, *,
     d = values.shape[1]
     dtype = values.dtype
 
+    block_m = worker_block(n_rows, block_m)
+    block_k = worker_block(n, block_k)
     m_pad = (-n_rows) % block_m
     k_pad = (-n) % block_k
     d_pad = (-d) % block_n
@@ -75,6 +83,6 @@ def bipartite_mix(adjacency: jax.Array, values: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, dp), dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a_p, v_p)
     return out[:n_rows, :d]

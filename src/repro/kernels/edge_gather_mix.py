@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 BLOCK_D = 512
 
 
@@ -47,7 +49,7 @@ def _edge_gather_kernel(nbr_ref, valid_ref, v_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def edge_gather_mix(values: jax.Array, nbr_table: jax.Array,
                     nbr_valid: jax.Array, *, block_d: int = BLOCK_D,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Neighbor sum over a degree-padded CSR table.
 
     Args:
@@ -55,7 +57,8 @@ def edge_gather_mix(values: jax.Array, nbr_table: jax.Array,
       nbr_table: (N, S) int32 neighbor ids, S = max degree (pad slots may
         point anywhere in range; their contribution is zeroed).
       nbr_valid: (N, S) float 1/0 slot validity.
-      interpret: interpreter mode (CPU validation); pass False on TPU.
+      interpret: interpreter mode; ``None`` decides by platform
+        (``kernels.interpret_mode``).
 
     Returns:
       (N, d) neighbor sums, f32.
@@ -64,24 +67,28 @@ def edge_gather_mix(values: jax.Array, nbr_table: jax.Array,
     assert nbr_table.shape == nbr_valid.shape and nbr_table.shape[0] == n
     s = nbr_table.shape[1]
     d_pad = (-d) % block_d
-    v_p = jnp.pad(values.astype(jnp.float32), ((0, 0), (0, d_pad)))
-    dp = v_p.shape[1]
+    # rows as (N, 1, d): a one-row block then ends in (1, block_d), whose
+    # row dimension spans the array's, as the TPU tiling requires
+    v_p = jnp.pad(values.astype(jnp.float32),
+                  ((0, 0), (0, d_pad)))[:, None, :]
+    dp = v_p.shape[2]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n, dp // block_d, s),
         in_specs=[
-            pl.BlockSpec((1, block_d),
+            pl.BlockSpec((None, 1, block_d),
                          lambda i, j, s, nbr_ref, valid_ref:
-                         (nbr_ref[i, s], j)),
+                         (nbr_ref[i, s], 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, block_d),
-                               lambda i, j, s, nbr_ref, valid_ref: (i, j)),
+        out_specs=pl.BlockSpec((None, 1, block_d),
+                               lambda i, j, s, nbr_ref, valid_ref:
+                               (i, 0, j)),
     )
     out = pl.pallas_call(
         _edge_gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, dp), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((n, 1, dp), jnp.float32),
+        interpret=interpret_mode(interpret),
     )(nbr_table.astype(jnp.int32), nbr_valid.astype(jnp.float32), v_p)
-    return out[:, :d]
+    return out[:, 0, :d]

@@ -1,9 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Backend dispatch: on TPU the kernels run compiled; elsewhere (this CPU
-container) they run with ``interpret=True``, which executes the kernel body
-in Python/XLA-CPU — semantics identical, so the oracle tests in
-``tests/test_kernels.py`` validate the TPU program logic.
+Backend dispatch: every kernel entry point takes ``interpret=None``, which
+``kernels.interpret_mode`` resolves from the platform — compiled by Mosaic
+on a TPU, interpreted everywhere else. Interpret mode runs the kernel body
+through XLA on the CPU with the same semantics, so the oracle tests in
+``tests/test_kernels.py`` check the program logic; what only the TPU
+compiler checks (tiling, VMEM budget) is covered by the compiles against a
+described v5e in ``tests/test_chip_compile.py``.
 
 Every wrapper bumps the obs-layer ``kernel_dispatch`` counter with the
 variant it selected. The bump happens in the Python wrapper — i.e. at
@@ -18,11 +21,8 @@ import jax
 
 from repro.kernels import bipartite_mix as _mix
 from repro.kernels import stoch_quant as _quant
+from repro.kernels import worker_block
 from repro.obs.metrics import kernel_dispatch_counter
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _count(kernel: str, variant: str) -> None:
@@ -33,8 +33,7 @@ def stoch_quantize(theta: jax.Array, q_hat_prev: jax.Array,
                    uniforms: jax.Array, delta: jax.Array,
                    qrange: jax.Array) -> jax.Array:
     _count("stoch_quantize", "flat")
-    return _quant.stoch_quantize(theta, q_hat_prev, uniforms, delta, qrange,
-                                 interpret=_interpret())
+    return _quant.stoch_quantize(theta, q_hat_prev, uniforms, delta, qrange)
 
 
 def stoch_quantize_grouped(theta: jax.Array, q_hat_prev: jax.Array,
@@ -43,8 +42,21 @@ def stoch_quantize_grouped(theta: jax.Array, q_hat_prev: jax.Array,
                            group_ids: jax.Array) -> jax.Array:
     _count("stoch_quantize", "grouped")
     return _quant.stoch_quantize_grouped(theta, q_hat_prev, uniforms, delta,
-                                         qrange, group_ids,
-                                         interpret=_interpret())
+                                         qrange, group_ids)
+
+
+# The single-slab fused kernel keeps one (rows, D) block of each of its
+# three (N, D) inputs and of its output in VMEM, plus the f32 temporaries of
+# the quantize chain. Compiled for a v5e, a 1 MiB slab still fits and 2 MiB
+# (2 rows) or 4 MiB (8 rows) run out of VMEM; past this budget the D-tiled
+# variant, whose VMEM use does not grow with D, takes over.
+FUSED_SLAB_BYTES = 512 * 1024
+
+
+def fused_slab_bytes(n: int, d: int) -> int:
+    """VMEM bytes of one f32 operand slab of the single-slab fused kernel
+    at an (n, d) packed buffer (rows per block, lane-padded width)."""
+    return worker_block(n, _quant.BLOCK_N) * (d + (-d) % 128) * 4
 
 
 def stoch_quantize_grouped_fused(theta: jax.Array, q_hat_prev: jax.Array,
@@ -56,22 +68,17 @@ def stoch_quantize_grouped_fused(theta: jax.Array, q_hat_prev: jax.Array,
     """Grouped quantize round with the (N, G) range reduction folded into
     the same ``pallas_call`` (no separate side-information pass).
 
-    ``REPRO_QUANT_TILE_D=<block_d>`` routes through the D-tiled two-phase
-    grid variant (bit-identical; bounded VMEM for LM-scale widths — the
-    single-slab default holds a full (BLOCK_N, D) row slab)."""
-    import os
-    tile_d = int(os.environ.get("REPRO_QUANT_TILE_D", "0"))
-    if tile_d > 0:
-        _count("stoch_quantize_fused", "tiled")
-        return _quant.stoch_quantize_grouped_fused_tiled(
+    Selection by shape: the single-slab kernel (one read of the buffers)
+    while its slab fits ``FUSED_SLAB_BYTES``, the D-tiled two-phase kernel
+    (two reads, bounded VMEM) beyond. Both are bit-identical."""
+    if fused_slab_bytes(*theta.shape) > FUSED_SLAB_BYTES:
+        return stoch_quantize_grouped_fused_tiled(
             theta, q_hat_prev, uniforms, bits_prev, range_prev, initialized,
-            group_ids, omega=omega, b0=b0, b_max=b_max, block_d=tile_d,
-            interpret=_interpret())
+            group_ids, omega=omega, b0=b0, b_max=b_max)
     _count("stoch_quantize_fused", "slab")
     return _quant.stoch_quantize_grouped_fused(
         theta, q_hat_prev, uniforms, bits_prev, range_prev, initialized,
-        group_ids, group_runs=group_runs, omega=omega, b0=b0, b_max=b_max,
-        interpret=_interpret())
+        group_ids, group_runs=group_runs, omega=omega, b0=b0, b_max=b_max)
 
 
 def stoch_quantize_grouped_fused_tiled(theta, q_hat_prev, uniforms,
@@ -82,21 +89,19 @@ def stoch_quantize_grouped_fused_tiled(theta, q_hat_prev, uniforms,
     _count("stoch_quantize_fused", "tiled")
     return _quant.stoch_quantize_grouped_fused_tiled(
         theta, q_hat_prev, uniforms, bits_prev, range_prev, initialized,
-        group_ids, omega=omega, b0=b0, b_max=b_max, block_d=block_d,
-        interpret=_interpret())
+        group_ids, omega=omega, b0=b0, b_max=b_max, block_d=block_d)
 
 
 def bipartite_mix(adjacency: jax.Array, values: jax.Array) -> jax.Array:
     _count("bipartite_mix", "dense")
-    return _mix.bipartite_mix(adjacency, values, interpret=_interpret())
+    return _mix.bipartite_mix(adjacency, values)
 
 
 def edge_gather_mix(values: jax.Array, nbr_table: jax.Array,
                     nbr_valid: jax.Array) -> jax.Array:
     from repro.kernels import edge_gather_mix as _edge
     _count("edge_gather_mix", "sparse")
-    return _edge.edge_gather_mix(values, nbr_table, nbr_valid,
-                                 interpret=_interpret())
+    return _edge.edge_gather_mix(values, nbr_table, nbr_valid)
 
 
 # One-shot softmax keeps a (H, pages_per_seq*page_size) f32 logits slab
@@ -144,11 +149,10 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, ctx_lens, *,
     fn = (_paged.paged_attention_decode_online if online
           else _paged.paged_attention_decode)
     return fn(q, k_pages, v_pages, bt, ctx_lens, k_scale=k_scale,
-              v_scale=v_scale, kv_bits=kv_bits, interpret=_interpret())
+              v_scale=v_scale, kv_bits=kv_bits)
 
 
 def slstm_cell(wx, r_w, fbias, c0, n0, m0, h0):
     from repro.kernels import slstm_cell as _cell
     _count("slstm_cell", "fused")
-    return _cell.slstm_cell(wx, r_w, fbias, c0, n0, m0, h0,
-                            interpret=_interpret())
+    return _cell.slstm_cell(wx, r_w, fbias, c0, n0, m0, h0)

@@ -18,12 +18,13 @@ there):
   (the vLLM paged_attention_v1 shape, adapted to the sequential TPU grid):
 
   phase 0  per-page QK^T logits (MXU dots per KV head) land in a
-           (H, P·ps) VMEM scratch slab, masked by the context length;
-  phase 1  at its first step the softmax runs ONCE over the full slab
-           (no online-rescale bookkeeping — bit-stable vs the oracle),
-           then each step re-DMAs its V page and accumulates
-           probs_page @ V_page into the (1, H·hd) output block in page
-           order.
+           (P, H, ps) VMEM scratch, one page per leading index, masked
+           by the context length;
+  phase 1  at its first step the softmax runs ONCE over all pages
+           (``ref.paged_softmax``; no online-rescale bookkeeping —
+           bit-stable vs the oracle), then each step re-DMAs its V page
+           and accumulates probs_page @ V_page into the (H, hd) output
+           block in page order.
 
   Bit-identical to ``ref.paged_attention_ref`` (same per-page dot shapes,
   same one-shot softmax, same page-order f32 accumulation) — the
@@ -65,6 +66,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 from repro.kernels import ref as _ref
 
 _NEG_INF = -1e30
@@ -72,15 +74,19 @@ _NEG_INF = -1e30
 
 def _load_page(page_ref, scale_ref, *, num_kv: int, head_dim: int,
                page_size: int, kv_bits: int):
-    """(ps, KV, hd) f32 page from the DMA'd block: a plain cast for full-
-    precision pools, or the traced ``ref.kv_page_dequantize`` for code
-    pools (scale_ref is the page's (1, ps, KV) side-info block)."""
+    """The DMA'd page as KV per-head (ps, hd) f32 blocks. A full-precision
+    page is cast and sliced along its lanes — the TPU compiler refuses a
+    reshape that splits the lane axis into (KV, hd); a code pool goes
+    through the traced ``ref.kv_page_dequantize`` (scale_ref is the page's
+    (1, ps, KV) side-info block)."""
     if kv_bits == 32:
-        return page_ref[0].reshape(page_size, num_kv,
-                                   head_dim).astype(jnp.float32)
+        page = page_ref[0].astype(jnp.float32)             # (ps, KV*hd)
+        return [page[:, h * head_dim:(h + 1) * head_dim]
+                for h in range(num_kv)]
     codes = page_ref[0].reshape(page_size, num_kv, -1)
-    return _ref.kv_page_dequantize(codes, scale_ref[0], kv_bits=kv_bits,
+    page = _ref.kv_page_dequantize(codes, scale_ref[0], kv_bits=kv_bits,
                                    head_dim=head_dim)
+    return [page[:, h] for h in range(num_kv)]
 
 
 def _page_logits(q_ref, k, p, ctx, *, num_kv: int, head_dim: int,
@@ -89,15 +95,15 @@ def _page_logits(q_ref, k, p, ctx, *, num_kv: int, head_dim: int,
     Slot s of logical page p holds absolute position p*ps + s; the single
     decode query sits at position ctx-1, so causal+written masking
     collapses to slot_index < ctx."""
-    groups = q_ref.shape[-1] // (num_kv * head_dim)
-    q = q_ref[0].reshape(num_kv, groups, head_dim).astype(jnp.float32)
+    q = q_ref[...]                                         # (H, hd) f32
+    groups = q.shape[0] // num_kv
     idx = p * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)
     valid = idx < ctx                                      # (1, ps)
     rows = []
     for kvh in range(num_kv):
         dots = jax.lax.dot_general(
-            q[kvh], k[:, kvh],
+            q[kvh * groups:(kvh + 1) * groups], k[kvh],
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # (G, ps)
         rows.append(dots * scale)
@@ -106,13 +112,14 @@ def _page_logits(q_ref, k, p, ctx, *, num_kv: int, head_dim: int,
 
 
 def _probs_dot_v(probs, v, *, num_kv: int):
-    """(H, ps) probs x (ps, KV, hd) V -> (H, hd), per-KV-head MXU dots."""
+    """(H, ps) probs x KV per-head (ps, hd) V -> (H, hd), per-KV-head MXU
+    dots."""
     groups = probs.shape[0] // num_kv
     outs = []
     for kvh in range(num_kv):
         pg = probs[kvh * groups:(kvh + 1) * groups]        # (G, ps)
         outs.append(jax.lax.dot_general(
-            pg, v[:, kvh], (((1,), (0,)), ((), ())),
+            pg, v[kvh], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32))           # (G, hd)
     return jnp.concatenate(outs, axis=0)
 
@@ -120,10 +127,11 @@ def _probs_dot_v(probs, v, *, num_kv: int):
 def _paged_attn_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest, num_kv:
                        int, head_dim: int, page_size: int, scale: float,
                        kv_bits: int):
-    # bt_ref/ctx_ref are scalar-prefetch (SMEM) refs; q_ref is this
-    # sequence's (1, H*hd) row; k_ref/v_ref are the (1, ps, KV*hd_store)
-    # physical page the index_map already gathered for this (b, phase, p)
-    # step; ks_ref/vs_ref (quantized pools only) its (1, ps, KV) ranges.
+    # bt_ref/ctx_ref are scalar-prefetch (SMEM) refs; q_ref/out_ref are
+    # this sequence's (H, hd) query and output; k_ref/v_ref are the
+    # (1, ps, KV*hd_store) physical page the index_map already gathered for
+    # this (b, phase, p) step; ks_ref/vs_ref (quantized pools only) its
+    # (1, ps, KV) ranges.
     if kv_bits == 32:
         ks_ref = vs_ref = None
         out_ref, logits_ref = rest
@@ -139,19 +147,18 @@ def _paged_attn_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest, num_kv:
     def _logits():
         k = _load_page(k_ref, ks_ref, kv_bits=kv_bits, **dims)
         slab, _ = _page_logits(q_ref, k, p, ctx, scale=scale, **dims)
-        logits_ref[:, pl.ds(p * page_size, page_size)] = slab
+        logits_ref[p] = slab
 
     @pl.when((phase == 1) & (p == 0))
     def _softmax():
-        logits_ref[...] = jax.nn.softmax(logits_ref[...], axis=-1)
+        logits_ref[...] = _ref.paged_softmax(logits_ref[...])
         out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(phase == 1)
     def _accumulate():
         v = _load_page(v_ref, vs_ref, kv_bits=kv_bits, **dims)
-        probs = logits_ref[:, pl.ds(p * page_size, page_size)]  # (H, ps)
-        out_ref[...] += _probs_dot_v(probs, v,
-                                     num_kv=num_kv).reshape(1, -1)
+        probs = logits_ref[p]                                   # (H, ps)
+        out_ref[...] += _probs_dot_v(probs, v, num_kv=num_kv)
 
 
 def _paged_attn_online_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest,
@@ -201,8 +208,7 @@ def _paged_attn_online_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, *rest,
     def _finalize():
         l = l_ref[...]
         # ctx == 0 (inactive slot): l stays 0 -> emit zeros, not NaN
-        out_ref[...] = (acc_ref[...]
-                        / jnp.where(l > 0.0, l, 1.0)).reshape(1, -1)
+        out_ref[...] = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
 
 
 def _prep(q, k_pages, v_pages, block_tables, ctx_lens, k_scale, v_scale,
@@ -224,7 +230,7 @@ def _prep(q, k_pages, v_pages, block_tables, ctx_lens, k_scale, v_scale,
     kvhd = num_kv * hd_store
     k_flat = k_pages.reshape(num_pages, page_size, kvhd)
     v_flat = v_pages.reshape(num_pages, page_size, kvhd)
-    q_flat = q.astype(jnp.float32).reshape(bsz, h * hd)
+    q_flat = q.astype(jnp.float32)
     return (bsz, h, hd, num_pages, page_size, num_kv, kvhd, bt, scale,
             k_flat, v_flat, q_flat)
 
@@ -236,7 +242,7 @@ def paged_attention_decode(q: jax.Array, k_pages: jax.Array,
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None,
                            kv_bits: int = 32,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """Single-token decode attention through a paged KV cache (one-shot
     softmax — the short-context default and bit-oracle; see the module
     docstring for the selection rule vs the online variant).
@@ -256,7 +262,8 @@ def paged_attention_decode(q: jax.Array, k_pages: jax.Array,
       k_scale, v_scale: (num_pages, page_size, KV) f32 per-entry ranges —
         required iff ``kv_bits`` in (8, 4).
       kv_bits: 32 (full precision) | 8 | 4 (quantized pools).
-      interpret: interpreter mode (CPU validation); pass False on TPU.
+      interpret: interpreter mode; ``None`` decides by platform
+        (``kernels.interpret_mode``).
 
     Returns:
       (B, H, hd) f32 attention output, bit-identical to
@@ -268,13 +275,13 @@ def paged_attention_decode(q: jax.Array, k_pages: jax.Array,
     pages_per_seq = block_tables.shape[1]
 
     def qmap(b, ph, p, bt_ref, ctx_ref):
-        return (b, 0)
+        return (b, 0, 0)
 
     def pagemap(b, ph, p, bt_ref, ctx_ref):
         return (bt_ref[b, p], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, h * hd), qmap),
+        pl.BlockSpec((None, h, hd), qmap),
         pl.BlockSpec((1, page_size, kvhd), pagemap),
         pl.BlockSpec((1, page_size, kvhd), pagemap),
     ]
@@ -287,9 +294,9 @@ def paged_attention_decode(q: jax.Array, k_pages: jax.Array,
         num_scalar_prefetch=2,
         grid=(bsz, 2, pages_per_seq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h * hd), qmap),
+        out_specs=pl.BlockSpec((None, h, hd), qmap),
         scratch_shapes=[
-            pltpu.VMEM((h, pages_per_seq * page_size), jnp.float32),
+            pltpu.VMEM((pages_per_seq, h, page_size), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_attn_kernel, num_kv=num_kv,
@@ -298,10 +305,10 @@ def paged_attention_decode(q: jax.Array, k_pages: jax.Array,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, h * hd), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((bsz, h, hd), jnp.float32),
+        interpret=interpret_mode(interpret),
     )(bt, ctx_lens.astype(jnp.int32), *inputs)
-    return out.reshape(bsz, h, hd)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("kv_bits", "interpret"))
@@ -312,7 +319,7 @@ def paged_attention_decode_online(q: jax.Array, k_pages: jax.Array,
                                   k_scale: jax.Array | None = None,
                                   v_scale: jax.Array | None = None,
                                   kv_bits: int = 32,
-                                  interpret: bool = True) -> jax.Array:
+                                  interpret: bool | None = None) -> jax.Array:
     """Online-softmax variant of :func:`paged_attention_decode`: same
     arguments, same masking contract, float-tolerance (not bitwise)
     agreement with ``ref.paged_attention_ref`` — VMEM scratch is ONE
@@ -325,13 +332,13 @@ def paged_attention_decode_online(q: jax.Array, k_pages: jax.Array,
     pages_per_seq = block_tables.shape[1]
 
     def qmap(b, p, bt_ref, ctx_ref):
-        return (b, 0)
+        return (b, 0, 0)
 
     def pagemap(b, p, bt_ref, ctx_ref):
         return (bt_ref[b, p], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, h * hd), qmap),
+        pl.BlockSpec((None, h, hd), qmap),
         pl.BlockSpec((1, page_size, kvhd), pagemap),
         pl.BlockSpec((1, page_size, kvhd), pagemap),
     ]
@@ -344,7 +351,7 @@ def paged_attention_decode_online(q: jax.Array, k_pages: jax.Array,
         num_scalar_prefetch=2,
         grid=(bsz, pages_per_seq),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h * hd), qmap),
+        out_specs=pl.BlockSpec((None, h, hd), qmap),
         scratch_shapes=[
             pltpu.VMEM((h, hd), jnp.float32),      # rescaled accumulator
             pltpu.VMEM((h, 1), jnp.float32),       # running max m
@@ -357,7 +364,7 @@ def paged_attention_decode_online(q: jax.Array, k_pages: jax.Array,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, h * hd), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((bsz, h, hd), jnp.float32),
+        interpret=interpret_mode(interpret),
     )(bt, ctx_lens.astype(jnp.int32), *inputs)
-    return out.reshape(bsz, h, hd)
+    return out

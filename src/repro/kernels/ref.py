@@ -15,6 +15,15 @@ from repro.core.quantization import bit_schedule
 _EPS = 1e-12
 
 
+def quant_levels(qrange: jax.Array, safe_delta: jax.Array) -> jax.Array:
+    """The top quantization level ``2^b - 1`` recovered from ``(R, Δ)``:
+    ``2R/Δ`` is that integer only up to rounding, and how it rounds
+    depends on how the program computing it was compiled. Rounded to the
+    nearest integer it is exact, so every quantize program clips the codes
+    at the same level (traced by the kernels and the oracles alike)."""
+    return jnp.floor(2.0 * qrange / safe_delta + 0.5)
+
+
 def stoch_quantize_ref(theta: jax.Array, q_hat_prev: jax.Array,
                        uniforms: jax.Array, delta: jax.Array,
                        qrange: jax.Array) -> jax.Array:
@@ -39,8 +48,7 @@ def stoch_quantize_ref(theta: jax.Array, q_hat_prev: jax.Array,
     c = (theta32 - qprev32 + r) / safe_delta
     floor_c = jnp.floor(c)
     q = floor_c + (unif32 < (c - floor_c)).astype(jnp.float32)
-    levels = 2.0 * r / safe_delta            # = 2^b - 1
-    q = jnp.clip(q, 0.0, levels)
+    q = jnp.clip(q, 0.0, quant_levels(r, safe_delta))
     return (qprev32 + safe_delta * q - r).astype(dtype)
 
 
@@ -71,8 +79,7 @@ def stoch_quantize_grouped_ref(theta: jax.Array, q_hat_prev: jax.Array,
     c = (theta32 - qprev32 + range_c) / safe_delta
     floor_c = jnp.floor(c)
     q = floor_c + (unif32 < (c - floor_c)).astype(jnp.float32)
-    levels = 2.0 * range_c / safe_delta      # = 2^{b_g} - 1, column-wise
-    q = jnp.clip(q, 0.0, levels)
+    q = jnp.clip(q, 0.0, quant_levels(range_c, safe_delta))
     return (qprev32 + safe_delta * q - range_c).astype(dtype)
 
 
@@ -177,6 +184,19 @@ def kv_page_dequantize(codes: jax.Array, rng: jax.Array, *, kv_bits: int,
     return delta * q.astype(jnp.float32) - rng[..., None]
 
 
+def paged_softmax(logits: jax.Array) -> jax.Array:
+    """Softmax of each head over all its (page, slot) logits, laid out
+    (P, H, ps): max and sum run over the slots of a page, then across the
+    pages. Traced both here and inside the one-shot kernel, whose scratch
+    keeps pages on the leading axis (the TPU stores a page's slab there at
+    a dynamic index; along the lanes it could only at multiples of 128)."""
+    m = jnp.max(jnp.max(logits, axis=2, keepdims=True), axis=0,
+                keepdims=True)
+    e = jnp.exp(logits - m)
+    return e / jnp.sum(jnp.sum(e, axis=2, keepdims=True), axis=0,
+                       keepdims=True)
+
+
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
                         v_pages: jax.Array, block_tables: jax.Array,
                         ctx_lens: jax.Array, *,
@@ -185,8 +205,8 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
                         kv_bits: int = 32) -> jax.Array:
     """Single-token decode attention through a paged KV cache — ground
     truth for the ``paged_attention_decode`` kernel, mirroring its exact
-    evaluation order (per-page QK dots, ONE softmax over the full logits
-    slab, f32 V accumulation in logical page order), so identical inputs
+    evaluation order (per-page QK dots, ONE softmax over all pages'
+    logits, f32 V accumulation in logical page order), so identical inputs
     produce bit-identical outputs.
 
     q: (B, H, hd); k_pages/v_pages: (num_pages, page_size, KV, hd_store);
@@ -224,11 +244,11 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
             slab = jnp.concatenate(rows, axis=0)           # (H, ps)
             idx = p * page_size + jnp.arange(page_size)[None, :]
             slabs.append(jnp.where(idx < ctx_lens[b], slab, -1e30))
-        probs = jax.nn.softmax(jnp.concatenate(slabs, axis=1), axis=-1)
+        probs = paged_softmax(jnp.stack(slabs, axis=0))    # (P, H, ps)
         acc = jnp.zeros((h, hd), jnp.float32)
         for p in range(pages_per_seq):
             v = page(v_pages, v_scale, bt[b, p])           # (ps, KV, hd)
-            pg = probs[:, p * page_size:(p + 1) * page_size]
+            pg = probs[p]
             parts = [jax.lax.dot_general(
                 pg[kvh * groups:(kvh + 1) * groups], v[:, kvh],
                 (((1,), (0,)), ((), ())),

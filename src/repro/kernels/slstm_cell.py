@@ -8,10 +8,11 @@ roofline term even after cell remat). This kernel keeps R (plus the gate
 bias and the running state) in VMEM across the whole sequence: HBM traffic
 collapses to the wx stream + the h output, i.e. state-only traffic.
 
-Tiling: grid (B/bb, H, S/sc) with the sequence chunks as the LAST
-(sequential) grid dimension; the state outputs map every s-chunk to the
-same block, so they persist across chunks (the standard revisited-output
-accumulator pattern). Inside a chunk the time loop is a fori_loop over the
+Tiling: grid (B/bb, H, S/sc) over head-major, time-leading copies of the
+operands, with the sequence chunks as the LAST (sequential) grid
+dimension; the state outputs map every s-chunk to the same block, so they
+persist across chunks (the standard revisited-output accumulator
+pattern). Inside a chunk the time loop is a fori_loop over the
 VMEM-resident wx block; the per-step recurrent matmul (bb, dh) x (dh, 4dh)
 runs on the MXU.
 
@@ -26,12 +27,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 BLOCK_B = 8
 CHUNK_S = 128
 
 
 def _cell_kernel(s_valid, wx_ref, r_ref, fb_ref, c0_ref, n0_ref, m0_ref,
                  h0_ref, hs_ref, c_ref, n_ref, m_ref, h_ref):
+    # one head's blocks: wx (sc, bb, 4dh) and hs (sc, bb, dh) with time on
+    # the leading axis, the state (bb, dh), R (dh, 4dh), the bias (1, dh)
     s_idx = pl.program_id(2)
 
     @pl.when(s_idx == 0)
@@ -41,17 +46,17 @@ def _cell_kernel(s_valid, wx_ref, r_ref, fb_ref, c0_ref, n0_ref, m0_ref,
         m_ref[...] = m0_ref[...].astype(jnp.float32)
         h_ref[...] = h0_ref[...].astype(jnp.float32)
 
-    r_mat = r_ref[0].astype(jnp.float32)          # (dh, 4dh) — VMEM resident
+    r_mat = r_ref[...].astype(jnp.float32)        # (dh, 4dh) — VMEM resident
     fbias = fb_ref[...].astype(jnp.float32)       # (1, dh)
     dh = r_mat.shape[0]
-    sc = wx_ref.shape[1]
+    sc = wx_ref.shape[0]
 
     def step(t, _):
-        c = c_ref[:, 0, :]
-        n = n_ref[:, 0, :]
-        m = m_ref[:, 0, :]
-        h = h_ref[:, 0, :]
-        xt = wx_ref[:, t, 0, :].astype(jnp.float32)        # (bb, 4dh)
+        c = c_ref[...]
+        n = n_ref[...]
+        m = m_ref[...]
+        h = h_ref[...]
+        xt = wx_ref[t].astype(jnp.float32)                 # (bb, 4dh)
         rec = jnp.dot(h, r_mat, preferred_element_type=jnp.float32)
         pre = xt + rec
         i_pre = pre[:, 0 * dh:1 * dh]
@@ -67,11 +72,11 @@ def _cell_kernel(s_valid, wx_ref, r_ref, fb_ref, c0_ref, n0_ref, m0_ref,
         h_new = jax.nn.sigmoid(o_pre) * c_new / n_new
         # padded tail steps (t_global >= s_valid) must not move the state
         live = (s_idx * sc + t) < s_valid
-        c_ref[:, 0, :] = jnp.where(live, c_new, c)
-        n_ref[:, 0, :] = jnp.where(live, n_new, n)
-        m_ref[:, 0, :] = jnp.where(live, m_new, m)
-        h_ref[:, 0, :] = jnp.where(live, h_new, h)
-        hs_ref[:, t, 0, :] = h_new.astype(hs_ref.dtype)
+        c_ref[...] = jnp.where(live, c_new, c)
+        n_ref[...] = jnp.where(live, n_new, n)
+        m_ref[...] = jnp.where(live, m_new, m)
+        h_ref[...] = jnp.where(live, h_new, h)
+        hs_ref[t] = h_new.astype(hs_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, sc, step, 0)
@@ -82,7 +87,7 @@ def _cell_kernel(s_valid, wx_ref, r_ref, fb_ref, c0_ref, n0_ref, m0_ref,
 def slstm_cell(wx: jax.Array, r_w: jax.Array, fbias: jax.Array,
                c0: jax.Array, n0: jax.Array, m0: jax.Array, h0: jax.Array,
                *, block_b: int = BLOCK_B, chunk_s: int = CHUNK_S,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """Fused sLSTM over a whole sequence.
 
     Args:
@@ -99,33 +104,36 @@ def slstm_cell(wx: jax.Array, r_w: jax.Array, fbias: jax.Array,
     sc = min(chunk_s, s)
     b_pad = (-b) % bb
     s_pad = (-s) % sc
+    # head-major layouts, time leading: the TPU tiling wants every block's
+    # last two dimensions to be (batch rows, features), so a block never
+    # takes a single row of the head axis there
     wx_p = jnp.pad(wx, ((0, b_pad), (0, s_pad), (0, 0), (0, 0)))
+    wx_p = jnp.transpose(wx_p, (2, 1, 0, 3))               # (H, S, B, 4dh)
     state0 = [jnp.pad(t, ((0, b_pad), (0, 0), (0, 0)))
               for t in (c0, n0, m0, h0)]
     # padded m must stay the running max's identity
     if b_pad:
         state0[2] = state0[2].at[b:].set(-1e30)
-    bp, sp = wx_p.shape[0], wx_p.shape[1]
+    state0 = [jnp.transpose(t, (1, 0, 2)) for t in state0]  # (H, B, dh)
+    sp, bp = wx_p.shape[1], wx_p.shape[2]
 
     grid = (bp // bb, h, sp // sc)
-    wx_spec = pl.BlockSpec((bb, sc, 1, dh4), lambda i, j, k: (i, k, j, 0))
-    r_spec = pl.BlockSpec((1, dh, dh4), lambda i, j, k: (j, 0, 0))
-    fb_spec = pl.BlockSpec((1, dh), lambda i, j, k: (j, 0))
-    st_spec = pl.BlockSpec((bb, 1, dh), lambda i, j, k: (i, j, 0))
-    hs_spec = pl.BlockSpec((bb, sc, 1, dh), lambda i, j, k: (i, k, j, 0))
+    wx_spec = pl.BlockSpec((None, sc, bb, dh4), lambda i, j, k: (j, k, i, 0))
+    r_spec = pl.BlockSpec((None, dh, dh4), lambda i, j, k: (j, 0, 0))
+    fb_spec = pl.BlockSpec((None, 1, dh), lambda i, j, k: (j, 0, 0))
+    st_spec = pl.BlockSpec((None, bb, dh), lambda i, j, k: (j, i, 0))
+    hs_spec = pl.BlockSpec((None, sc, bb, dh), lambda i, j, k: (j, k, i, 0))
+    st_shape = jax.ShapeDtypeStruct((h, bp, dh), jnp.float32)
 
     hs, c, n, m, h_out = pl.pallas_call(
         functools.partial(_cell_kernel, s),
         grid=grid,
         in_specs=[wx_spec, r_spec, fb_spec] + [st_spec] * 4,
         out_specs=[hs_spec] + [st_spec] * 4,
-        out_shape=[
-            jax.ShapeDtypeStruct((bp, sp, h, dh), jnp.float32),
-            jax.ShapeDtypeStruct((bp, h, dh), jnp.float32),
-            jax.ShapeDtypeStruct((bp, h, dh), jnp.float32),
-            jax.ShapeDtypeStruct((bp, h, dh), jnp.float32),
-            jax.ShapeDtypeStruct((bp, h, dh), jnp.float32),
-        ],
-        interpret=interpret,
-    )(wx_p, r_w, fbias, *state0)
+        out_shape=[jax.ShapeDtypeStruct((h, sp, bp, dh), jnp.float32)]
+        + [st_shape] * 4,
+        interpret=interpret_mode(interpret),
+    )(wx_p, r_w, fbias.reshape(h, 1, dh), *state0)
+    hs = jnp.transpose(hs, (2, 1, 0, 3))
+    c, n, m, h_out = (jnp.transpose(t, (1, 0, 2)) for t in (c, n, m, h_out))
     return hs[:b, :s], (c[:b], n[:b], m[:b], h_out[:b])

@@ -3,7 +3,7 @@
 The elementwise chain
 
     c = (theta - q_prev + R) / Δ ; q = floor(c) + bernoulli(frac(c));
-    q = clip(q, 0, 2R/Δ) ; out = q_prev + Δ q - R
+    q = clip(q, 0, round(2R/Δ)) ; out = q_prev + Δ q - R
 
 is memory-bound (reads 3 arrays, writes 1). On TPU we tile (workers, dim)
 into VMEM blocks of (BLOCK_N, BLOCK_D) with BLOCK_D a multiple of the
@@ -45,7 +45,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantization import bit_schedule
-from repro.kernels.ref import grouped_range_ref
+from repro.kernels import interpret_mode, worker_block
+from repro.kernels.ref import grouped_range_ref, quant_levels
 
 _EPS = 1e-12
 # Default VMEM tile: 8 sublanes x 512 lanes (f32: 16 KiB per operand block;
@@ -67,8 +68,7 @@ def _quant_kernel(theta_ref, qprev_ref, unif_ref, delta_ref, range_ref,
     c = (theta - qprev + rng) / safe_delta
     floor_c = jnp.floor(c)
     q = floor_c + (unif < (c - floor_c)).astype(jnp.float32)
-    levels = 2.0 * rng / safe_delta
-    q = jnp.clip(q, 0.0, levels)
+    q = jnp.clip(q, 0.0, quant_levels(rng, safe_delta))
     out_ref[...] = (qprev + safe_delta * q - rng).astype(out_ref.dtype)
 
 
@@ -98,8 +98,7 @@ def _grouped_quant_kernel(theta_ref, qprev_ref, unif_ref, delta_ref,
     c = (theta - qprev + range_c) / safe_delta
     floor_c = jnp.floor(c)
     q = floor_c + (unif < (c - floor_c)).astype(jnp.float32)
-    levels = 2.0 * range_c / safe_delta
-    q = jnp.clip(q, 0.0, levels)
+    q = jnp.clip(q, 0.0, quant_levels(range_c, safe_delta))
     out_ref[...] = (qprev + safe_delta * q - range_c).astype(out_ref.dtype)
 
 
@@ -143,8 +142,7 @@ def _grouped_fused_kernel(theta_ref, qprev_ref, unif_ref, bprev_ref,
     c = (theta - qprev + range_c) / safe_delta
     floor_c = jnp.floor(c)
     q = floor_c + (unif < (c - floor_c)).astype(jnp.float32)
-    levels = 2.0 * range_c / safe_delta
-    q = jnp.clip(q, 0.0, levels)
+    q = jnp.clip(q, 0.0, quant_levels(range_c, safe_delta))
     out = qprev + safe_delta * q - range_c
     # degenerate groups (nothing moved) pass the previous reconstruction
     # through untouched — folded here so the engine never re-reads (N, D)
@@ -160,7 +158,7 @@ def stoch_quantize_grouped_fused(
     theta: jax.Array, q_hat_prev: jax.Array, uniforms: jax.Array,
     bits_prev: jax.Array, range_prev: jax.Array, initialized: jax.Array,
     group_ids: jax.Array, *, group_runs, omega: float, b0: int, b_max: int,
-    block_n: int = BLOCK_N, interpret: bool = True,
+    block_n: int = BLOCK_N, interpret: bool | None = None,
 ):
     """Grouped quantize round with the range reduction folded in: ONE
     ``pallas_call`` reads the packed buffers exactly once and emits both
@@ -192,6 +190,7 @@ def stoch_quantize_grouped_fused(
     n, d = theta.shape
     n_groups = bits_prev.shape[1]
     dtype = theta.dtype
+    block_n = worker_block(n, block_n)
     n_pad = (-n) % block_n
     d_pad = (-d) % 128                 # lane-align the row slab
 
@@ -227,7 +226,7 @@ def stoch_quantize_grouped_fused(
                    jax.ShapeDtypeStruct((np_, n_groups), jnp.float32),
                    jax.ShapeDtypeStruct((np_, n_groups), jnp.float32),
                    jax.ShapeDtypeStruct((np_, n_groups), jnp.float32)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta_p, qprev_p, unif_p, bprev_p, rprev_p, init_p, gid_p)
     return (out[:n, :d], range_new[:n], bits[:n], delta[:n])
 
@@ -296,8 +295,7 @@ def _grouped_fused_tiled_kernel(theta_ref, qprev_ref, unif_ref, bprev_ref,
         c = (theta - qprev + range_c) / safe_delta
         floor_c = jnp.floor(c)
         q = floor_c + (unif < (c - floor_c)).astype(jnp.float32)
-        levels = 2.0 * range_c / safe_delta
-        q = jnp.clip(q, 0.0, levels)
+        q = jnp.clip(q, 0.0, quant_levels(range_c, safe_delta))
         out = qprev + safe_delta * q - range_c
         out_ref[...] = jnp.where(degen_c > 0.0, qprev,
                                  out).astype(out_ref.dtype)
@@ -311,7 +309,7 @@ def stoch_quantize_grouped_fused_tiled(
     bits_prev: jax.Array, range_prev: jax.Array, initialized: jax.Array,
     group_ids: jax.Array, *, group_runs=None, omega: float, b0: int,
     b_max: int, block_n: int = BLOCK_N, block_d: int = BLOCK_D,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """D-tiled two-phase variant of :func:`stoch_quantize_grouped_fused`
     for LM-scale widths: VMEM residency is O(BLOCK_N * BLOCK_D) instead of
@@ -324,6 +322,7 @@ def stoch_quantize_grouped_fused_tiled(
     n, d = theta.shape
     n_groups = bits_prev.shape[1]
     dtype = theta.dtype
+    block_n = worker_block(n, block_n)
     n_pad = (-n) % block_n
     d_pad = (-d) % block_d
 
@@ -363,7 +362,7 @@ def stoch_quantize_grouped_fused_tiled(
             pltpu.VMEM((block_n, n_groups), jnp.float32),
             pltpu.VMEM((block_n, n_groups), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta_p, qprev_p, unif_p, bprev_p, rprev_p, init_p, gid_p)
     return (out[:n, :d], range_new[:n], bits[:n], delta[:n])
 
@@ -374,7 +373,7 @@ def stoch_quantize_grouped(theta: jax.Array, q_hat_prev: jax.Array,
                            uniforms: jax.Array, delta: jax.Array,
                            qrange: jax.Array, group_ids: jax.Array,
                            *, block_n: int = BLOCK_N, block_d: int = BLOCK_D,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """Fused grouped quantize+reconstruct: ONE ``pallas_call`` for a whole
     packed multi-leaf buffer (the per-leaf loop this replaces paid one
     kernel launch per layer).
@@ -386,7 +385,8 @@ def stoch_quantize_grouped(theta: jax.Array, q_hat_prev: jax.Array,
         per layer group, not per column).
       group_ids: (D,) int32 column -> group id map (static layout from
         ``core.packing``).
-      interpret: interpreter mode (CPU validation); pass False on real TPU.
+      interpret: interpreter mode; ``None`` decides by platform
+        (``kernels.interpret_mode``).
 
     Returns:
       (N, D) reconstruction Q̂^k, bit-identical to
@@ -395,6 +395,7 @@ def stoch_quantize_grouped(theta: jax.Array, q_hat_prev: jax.Array,
     n, d = theta.shape
     n_groups = delta.shape[1]
     dtype = theta.dtype
+    block_n = worker_block(n, block_n)
     n_pad = (-n) % block_n
     d_pad = (-d) % block_d
 
@@ -422,7 +423,7 @@ def stoch_quantize_grouped(theta: jax.Array, q_hat_prev: jax.Array,
                   gid_spec],
         out_specs=mat_spec,
         out_shape=jax.ShapeDtypeStruct((np_, dp_), dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta_p, qprev_p, unif_p, delta_p, range_p, gid_p)
     return out[:n, :d]
 
@@ -432,20 +433,21 @@ def stoch_quantize_grouped(theta: jax.Array, q_hat_prev: jax.Array,
 def stoch_quantize(theta: jax.Array, q_hat_prev: jax.Array,
                    uniforms: jax.Array, delta: jax.Array, qrange: jax.Array,
                    *, block_n: int = BLOCK_N, block_d: int = BLOCK_D,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """Fused quantize+reconstruct for stacked workers.
 
     Args:
       theta, q_hat_prev, uniforms: (N, d).
       delta, qrange: (N,) per-worker step size / range.
-      interpret: run the kernel body in interpreter mode (CPU validation);
-        pass False on real TPU.
+      interpret: interpreter mode; ``None`` decides by platform
+        (``kernels.interpret_mode``).
 
     Returns:
       (N, d) reconstruction Q̂^k.
     """
     n, d = theta.shape
     dtype = theta.dtype
+    block_n = worker_block(n, block_n)
     n_pad = (-n) % block_n
     d_pad = (-d) % block_d
 
@@ -470,6 +472,6 @@ def stoch_quantize(theta: jax.Array, q_hat_prev: jax.Array,
         in_specs=[mat_spec, mat_spec, mat_spec, col_spec, col_spec],
         out_specs=mat_spec,
         out_shape=jax.ShapeDtypeStruct((np_, dp_), dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(theta_p, qprev_p, unif_p, delta_p, range_p)
     return out[:n, :d]

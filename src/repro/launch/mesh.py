@@ -13,19 +13,21 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.topology import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh over whatever devices exist (CPU tests)."""
     n = len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((n, 1), ("data", "model"))
+        return make_mesh((n // 2, 2), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def axis_size(mesh, name: str) -> int:

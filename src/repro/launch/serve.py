@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import base
 from repro.data.lm import SyntheticLM, SyntheticLMConfig
+from repro.launch import compile_cache
 from repro.models import registry
 from repro.obs import trace as obs_trace
 from repro.serving import paging
@@ -178,6 +179,10 @@ def main(argv=None) -> dict:
                     choices=base.list_architectures())
     ap.add_argument("--smoke", action="store_true", default=True)
     ap.add_argument("--full", dest="smoke", action="store_false")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to this many layers (whole periods "
+                         "of its block unit, at most the published depth); "
+                         "every width stays as published")
     ap.add_argument("--engine", choices=("paged", "lockstep"),
                     default="paged")
     ap.add_argument("--batch", type=int, default=4,
@@ -233,10 +238,11 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     if args.trace:
         obs_trace.enable(args.trace)
-    cfg = (base.get_smoke_config(args.arch) if args.smoke
-           else base.get_config(args.arch))
+    cfg = base.cli_config(args.arch, smoke=args.smoke, layers=args.layers,
+                          tag="serve")
     if cfg.is_encoder_decoder and args.engine == "paged":
         # encoder-decoder cross caches are not paged (DESIGN.md §Serving)
         print(f"[serve] {cfg.name} is encoder-decoder: falling back to "

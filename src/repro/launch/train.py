@@ -33,9 +33,11 @@ import numpy as np
 from repro.checkpoint import npz as ckpt
 from repro.configs import base
 from repro.core import engine as E
+from repro.core import topology as topo_lib
 from repro.core.censoring import CensorConfig
 from repro.core.quantization import QuantConfig
 from repro.data.lm import SyntheticLM, SyntheticLMConfig, model_batch
+from repro.launch import compile_cache
 from repro.models import registry
 from repro.obs import trace as obs_trace
 from repro.obs.ledger import CommLedger
@@ -117,10 +119,10 @@ def run_fleet(cfg, args, graph, ecfg, solver, loss_fn, params, data) -> dict:
             "churn_log": m["churn_log"]}
 
 
-def run_admm(cfg, args) -> dict:
-    graph = ST.worker_graph(args.workers, args.topology)
+def engine_config(cfg, args) -> E.EngineConfig:
+    """The ``EngineConfig`` the CLI flags describe."""
     try:
-        ecfg = E.EngineConfig(
+        return E.EngineConfig(
             rho=args.rho,
             censor=CensorConfig(tau0=args.tau0, xi=args.xi)
             if args.tau0 > 0 else CensorConfig(),
@@ -136,6 +138,96 @@ def run_admm(cfg, args) -> dict:
             f"[train] buckets available for {cfg.name}: "
             f"{registry.param_bucket_names(cfg)}") from e
 
+
+def worker_placement(n_workers: int):
+    """The ``workers`` mesh over ``topology.worker_devices`` — one block
+    of worker rows per device — or ``None`` when that is one device, where
+    everything stays on the default device. Says so when it leaves local
+    devices idle."""
+    devices = topo_lib.worker_devices(n_workers)
+    n_local = len(jax.devices())
+    if len(devices) < n_local:
+        print(f"[train] {n_workers} workers take equal row blocks on "
+              f"{len(devices)} of the {n_local} local devices")
+    return None if len(devices) == 1 else topo_lib.worker_mesh(devices)
+
+
+@dataclasses.dataclass
+class AdmmSetup:
+    """Everything one consensus-LM run is built from — model, graph,
+    engine config, solver, data and worker placement — so that the CLI
+    and callers that drive the same step themselves (``chip_smoke.py``)
+    build it one way."""
+
+    cfg: base.ModelConfig
+    args: argparse.Namespace
+    graph: object
+    ecfg: E.EngineConfig
+    solver: E.InexactSolver
+    loss_fn: object
+    data: SyntheticLM
+    mesh: object = None
+
+    def _sharding(self, x):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh, P("workers") if x.ndim else P())
+
+    def _jit_placed(self, fn):
+        """``fn()`` run under jit, its outputs created directly in their
+        worker placement (never first gathered on one device)."""
+        if self.mesh is None:
+            return jax.jit(fn)()
+        out = jax.tree_util.tree_map(self._sharding, jax.eval_shape(fn))
+        return jax.jit(fn, out_shardings=out)()
+
+    def stacked_params(self):
+        """Per-worker parameters, unplaced (to trace or ``eval_shape``):
+        one shared init broadcast to every worker (the paper's
+        theta_n^0 = 0 analog; workers diverge only through their local
+        data)."""
+        one = registry.init_params(self.cfg,
+                                   jax.random.PRNGKey(self.args.seed))
+        n = self.args.workers
+        return jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), one)
+
+    def init_params(self):
+        """Per-worker parameters, leading axis = worker."""
+        return self._jit_placed(self.stacked_params)
+
+    def init_state(self, ecfg: E.EngineConfig | None = None
+                   ) -> E.EngineState:
+        """A fresh engine state (the step donates the one it is given)."""
+        ecfg = ecfg or self.ecfg
+        return self._jit_placed(
+            lambda: E.init_state(self.stacked_params(), ecfg, self.solver))
+
+    def build_step(self, ecfg: E.EngineConfig | None = None):
+        """The jitted round. It donates the state it is given: at LM
+        width the input and output states cannot both be live."""
+        step = E.make_step(self.graph, ecfg or self.ecfg, self.solver,
+                           extra_metrics=E.consensus_metrics(self.loss_fn),
+                           mesh=self.mesh,
+                           worker_axis="workers" if self.mesh else None)
+        return jax.jit(step, donate_argnums=0)
+
+    def batch(self, i: int):
+        """Round ``i``'s stacked per-worker batch, in worker placement."""
+        a = self.args
+        raw = self.data.worker_batch(i, a.workers, a.batch // a.workers)
+        b = model_batch(self.cfg, raw, key=jax.random.PRNGKey(i))
+        if self.mesh is None:
+            return b
+        return jax.tree_util.tree_map(
+            lambda x: jax.device_put(x, self._sharding(x)), b)
+
+    @staticmethod
+    def key(i: int) -> jax.Array:
+        """Round ``i``'s engine key (quantizer uniforms)."""
+        return jax.random.PRNGKey(1000 + i)
+
+
+def admm_setup(cfg, args) -> AdmmSetup:
     def grad_fn(theta, batch):
         return jax.vmap(lambda p, b: jax.grad(
             lambda pp: registry.lm_loss(pp, cfg, b)[0])(p))(theta, batch)
@@ -144,53 +236,55 @@ def run_admm(cfg, args) -> dict:
         return jnp.mean(jax.vmap(
             lambda p, b: registry.lm_loss(p, cfg, b)[0])(theta, batch))
 
-    solver = E.InexactSolver(grad_fn=grad_fn, local_steps=args.local_steps,
-                             local_lr=args.lr)
+    return AdmmSetup(
+        cfg=cfg, args=args,
+        graph=ST.worker_graph(args.workers, args.topology),
+        ecfg=engine_config(cfg, args),
+        solver=E.InexactSolver(grad_fn=grad_fn,
+                               local_steps=args.local_steps,
+                               local_lr=args.lr),
+        loss_fn=loss_fn,
+        data=SyntheticLM(SyntheticLMConfig(cfg.vocab_size, args.seq,
+                                           seed=args.seed)),
+        mesh=worker_placement(args.workers))
 
-    # identical worker initialization (the paper's theta_n^0 = 0 analog —
-    # one shared init; workers diverge only through their local data)
-    one = registry.init_params(cfg, jax.random.PRNGKey(args.seed))
-    params = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (args.workers,) + x.shape), one)
+
+def run_admm(cfg, args) -> dict:
+    setup = admm_setup(cfg, args)
+    ecfg = setup.ecfg
     # resolve the spec against the real tree up front: a semantically
     # malformed spec (unknown/empty bucket, bad index buckets) must fail
     # here with the model's bucket vocabulary, not deep inside the jit
     try:
-        cur_ids = E.resolve_groups(params, ecfg.groups)
+        cur_ids = E.resolve_groups(jax.eval_shape(setup.stacked_params),
+                                   ecfg.groups)
     except E.GroupSpecError as e:
         raise SystemExit(
             f"[train] bad --groups spec for {cfg.name}: {e}\n"
             f"[train] buckets: {registry.param_buckets(cfg)}") from e
-    state = E.init_state(params, ecfg, solver)
-    n_groups = state.quant.n_groups
     grouper = E.AutoGrouper.from_config(ecfg)
 
-    def build_step(cfg_):
-        return jax.jit(E.make_step(graph, cfg_, solver,
-                                   extra_metrics=E.consensus_metrics(
-                                       loss_fn)))
-
-    data = SyntheticLM(SyntheticLMConfig(cfg.vocab_size, args.seq,
-                                         seed=args.seed))
     if args.fleet:
         if args.regroup_every:
             raise SystemExit(
                 "[train] --fleet is incompatible with --regroup-every: "
                 "auto regrouping re-jits the step on a schedule the fleet "
                 "driver owns (churn already rebuilds it)")
-        return run_fleet(cfg, args, graph, ecfg, solver, loss_fn, params,
-                         data)
-    step = build_step(ecfg)
+        return run_fleet(cfg, args, setup.graph, ecfg, setup.solver,
+                         setup.loss_fn, setup.init_params(), setup.data)
+    state = setup.init_state()
+    n_groups = state.quant.n_groups
+    step = setup.build_step()
     # host-side observers only: the ledger reads device_get copies of the
     # metrics the step already returns, the span brackets the Python-level
     # round — neither adds an op to the jitted program (tests/test_obs.py
     # pins the jaxpr)
     tr = obs_trace.tracer()
-    ledger = CommLedger(graph) if tr is not None else None
+    ledger = CommLedger(setup.graph) if tr is not None else None
     rounds_tid = tr.track("engine", "rounds") if tr is not None else 0
     total_bits = 0.0
     t0 = time.time()
-    history = []
+    history, tx_history, bits_history = [], [], []
     for i in range(args.steps):
         if grouper is not None and grouper.should_regroup(i):
             new_ids = grouper.regroup(state.theta, state.quant.q_hat)
@@ -204,16 +298,15 @@ def run_admm(cfg, args) -> dict:
                                               new_ids),
                     opt_mu=state.opt_mu, opt_nu=state.opt_nu, k=state.k)
                 ecfg = dataclasses.replace(ecfg, groups=new_ids)
-                step = build_step(ecfg)
+                step = setup.build_step(ecfg)
                 cur_ids = new_ids
                 n_groups = max(new_ids) + 1
                 print(f"[train] step {i}: regrouped to G={n_groups} "
                       f"({new_ids})")
-        raw = data.worker_batch(i, args.workers, args.batch // args.workers)
-        batch = model_batch(cfg, raw, key=jax.random.PRNGKey(i))
+        batch = setup.batch(i)
         if tr is not None:
             tr.begin("round", "engine", rounds_tid, args={"round": i})
-        state, m = step(state, batch, jax.random.PRNGKey(1000 + i))
+        state, m = step(state, batch, setup.key(i))
         if ledger is not None:
             ledger.update(jax.device_get(m))
         if tr is not None:
@@ -222,18 +315,21 @@ def run_admm(cfg, args) -> dict:
         total_bits += bits
         mean_bits = float(np.asarray(m["bits_per_group"]).mean())
         history.append(float(m["loss"]))
+        tx_history.append(int(m["tx_mask"].sum()))
+        bits_history.append(bits)
         if i % args.log_every == 0 or i == args.steps - 1:
-            print(f"step {i:4d}  loss={float(m['loss']):.4f}  "
+            print(f"step {i:4d}  loss={history[-1]:.4f}  "
                   f"consensus_err={float(m['consensus_err']):.3e}  "
-                  f"tx={int(m['tx_mask'].sum())}/{args.workers}  "
+                  f"tx={tx_history[-1]}/{args.workers}  "
                   f"groups={n_groups}  b/group={mean_bits:.1f}  "
-                  f"cum_bits={total_bits:.3e}  "
+                  f"bits={bits:.3e}  cum_bits={total_bits:.3e}  "
                   f"({(time.time() - t0) / (i + 1):.2f}s/step)")
         if args.ckpt_dir and (i + 1) % args.ckpt_every == 0:
             ckpt.save(args.ckpt_dir, i + 1, state.theta)
     if args.ckpt_dir:
         ckpt.save(args.ckpt_dir, args.steps, state.theta)
     return {"final_loss": history[-1], "history": history,
+            "tx_history": tx_history, "bits_history": bits_history,
             "total_bits": total_bits, "n_groups": n_groups}
 
 
@@ -371,12 +467,16 @@ def campaign_lm_run(section, quantize=True, groups="model",
                   claims_path=("lm_sweep", "claims"))
 
 
-def main(argv=None) -> dict:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="tinyllama-1.1b",
                     choices=base.list_architectures())
     ap.add_argument("--smoke", action="store_true",
                     help="use the reduced config (CPU-sized)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model to this many layers (whole periods "
+                         "of its block unit, at most the published depth); "
+                         "every width stays as published")
     ap.add_argument("--mode", default="admm", choices=("admm", "fsdp"))
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--topology", default="random",
@@ -451,8 +551,12 @@ def main(argv=None) -> dict:
     ap.add_argument("--campaign-only", default=None, metavar="STAGE",
                     help="with --campaign: run one stage (plus its "
                          "incomplete dependencies)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None) -> dict:
+    args = build_parser().parse_args(argv)
+    compile_cache.enable()
     if args.trace:
         obs_trace.enable(args.trace)
     if args.campaign:
@@ -472,8 +576,8 @@ def main(argv=None) -> dict:
                 "skipped": summary.skipped, "failed": summary.failed,
                 "claim_failures": summary.claims_failed}
 
-    cfg = (base.get_smoke_config(args.arch) if args.smoke
-           else base.get_config(args.arch))
+    cfg = base.cli_config(args.arch, smoke=args.smoke, layers=args.layers,
+                          tag="train")
     print(f"[train] arch={cfg.name} mode={args.mode} workers={args.workers} "
           f"batch={args.batch} seq={args.seq} steps={args.steps}")
     if args.mode == "admm":

@@ -1,0 +1,157 @@
+"""The main-path Pallas kernels compile for a TPU v5e at the shapes
+``chip_smoke.py`` runs: TinyLlama-1.1B's published widths, cut in depth
+only, two workers. Nothing runs — the v5e is described, not attached — so
+these catch what interpret mode cannot: block shapes the TPU tiling
+refuses and VMEM or HBM the chip does not have.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file. Kernels are called with ``interpret=False``: the platform rule
+(``kernels.interpret_mode``) sees the host's CPU here.
+"""
+import importlib.util
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import base
+from repro.core import graph as G
+from repro.kernels import ops
+from repro.kernels import stoch_quant as SQ
+from repro.kernels.bipartite_mix import bipartite_mix
+from repro.kernels.edge_gather_mix import edge_gather_mix
+from repro.kernels.paged_attention import (paged_attention_decode,
+                                           paged_attention_decode_online)
+from repro.kernels.slstm_cell import slstm_cell
+from repro.models import registry
+from repro.serving import paging
+
+ROOT = Path(__file__).resolve().parent.parent
+WORKERS = 2
+
+
+def _chip_smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler: nothing to rehearse
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip can be written to the
+    # persistent cache but not read back without the chip
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(dims), dtype, sharding=one_chip)
+    return make
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    return _chip_smoke()
+
+
+@pytest.fixture(scope="module")
+def packed_width(smoke):
+    """D: the packed per-worker width of the model chip_smoke trains."""
+    cfg = base.get_config(smoke.ARCH).cut_depth(smoke.LAYERS)
+    params = jax.eval_shape(
+        lambda: registry.init_params(cfg, jax.random.PRNGKey(0)))
+    return sum(x.size for x in jax.tree_util.tree_leaves(params))
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_fused_quantize_compiles_at_lm_width(shape, packed_width):
+    d = packed_width
+    tiled = ops.fused_slab_bytes(WORKERS, d) > ops.FUSED_SLAB_BYTES
+    assert tiled, "an LM-width slab cannot fit VMEM"
+    kw = dict(omega=0.999, b0=4, b_max=16, interpret=False)
+    mat, side = shape((WORKERS, d)), shape((WORKERS, 1))
+    compiled = _compile(
+        lambda *a: SQ.stoch_quantize_grouped_fused_tiled(*a, **kw),
+        mat, mat, mat, side, side, side, shape((d,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+    # the worker axis is not padded: no (8, D) copy of any operand
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < WORKERS * d * 4
+
+
+def test_fused_quantize_slab_compiles_at_its_budget(shape):
+    """The largest slab the selection rule keeps on the single-slab
+    kernel fits VMEM."""
+    d = ops.FUSED_SLAB_BYTES // (WORKERS * 4)
+    assert ops.fused_slab_bytes(WORKERS, d) <= ops.FUSED_SLAB_BYTES
+    runs = (((0, d),),)
+    mat, side = shape((WORKERS, d)), shape((WORKERS, 1))
+    _compile(lambda *a: SQ.stoch_quantize_grouped_fused(
+        *a, group_runs=runs, omega=0.999, b0=4, b_max=16, interpret=False),
+        mat, mat, mat, side, side, side, shape((d,), jnp.int32))
+
+
+def test_bipartite_mix_compiles_at_lm_width(shape, packed_width):
+    compiled = _compile(lambda a, v: bipartite_mix(a, v, interpret=False),
+                        shape((WORKERS, WORKERS)),
+                        shape((WORKERS, packed_width)))
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < WORKERS * packed_width * 4
+
+
+def test_edge_gather_mix_compiles_at_lm_width(shape, packed_width):
+    table, _ = G.chain_graph(WORKERS).neighbor_table
+    _compile(lambda v, t, m: edge_gather_mix(v, t, m, interpret=False),
+             shape((WORKERS, packed_width)),
+             shape(table.shape, jnp.int32), shape(table.shape))
+
+
+@pytest.mark.parametrize("online", [False, True])
+def test_paged_attention_compiles_at_tinyllama_width(shape, smoke, online):
+    cfg = base.get_config(smoke.ARCH)
+    bsz, page = 4, 16
+    pages_per_seq = paging.pages_needed(128 + 32, page)
+    num_pages = 2 * bsz * pages_per_seq
+    hd = cfg.resolved_head_dim
+    assert (cfg.num_heads, cfg.num_kv_heads, hd) == (32, 4, 64)
+    fn = paged_attention_decode_online if online else paged_attention_decode
+    pool = shape((num_pages, page, cfg.num_kv_heads, hd), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v, bt, ctx: fn(q, k, v, bt, ctx, interpret=False),
+        shape((bsz, cfg.num_heads, hd)), pool, pool,
+        shape((bsz, pages_per_seq), jnp.int32), shape((bsz,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_slstm_cell_compiles_at_xlstm_width(shape):
+    cfg = base.get_config("xlstm-125m")
+    h = cfg.lstm_heads
+    dh = cfg.d_model // h
+    bsz, seq = 8, 256
+    state = shape((bsz, h, dh))
+    _compile(lambda *a: slstm_cell(*a, interpret=False),
+             shape((bsz, seq, h, 4 * dh)), shape((h, dh, 4 * dh)),
+             shape((h, dh)), state, state, state, state)

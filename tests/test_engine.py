@@ -110,15 +110,19 @@ def test_golden_flat_matches_seed_with_tracing(linreg, tmp_path):
 
 
 def test_golden_with_pallas_kernels(linreg):
-    """Kernel routing flags preserve the seed kernel path bit-for-bit."""
+    """Kernel routing flags keep the seed trajectory: the Pallas mix sums
+    the neighbours in another order than the seed's matmul, so the
+    residuals agree to ``CROSS_PROGRAM_RTOL`` while the censor decisions
+    and the bits on the wire stay identical."""
     g, prob = linreg
     cfg = ab.ALL_SCHEMES["cq-ggadmm"](rho=1.0)
     cfg = dataclasses.replace(cfg, use_pallas_mix=True,
                               use_pallas_quant=True)
     _, out_e = cq.run(g, prob, cfg, dim=DIM, iters=12, seed=3)
     _, out_r = ref.run(g, prob, cfg, dim=DIM, iters=12, seed=3)
-    for key in ("tx_mask", "primal_residual"):
-        np.testing.assert_array_equal(out_e[key], out_r[key], err_msg=key)
+    np.testing.assert_array_equal(out_e["tx_mask"], out_r["tx_mask"])
+    assert E.tree_rel_err(out_e["primal_residual"],
+                          out_r["primal_residual"]) <= E.CROSS_PROGRAM_RTOL
     np.testing.assert_array_equal(out_e["payload_bits"],
                                   out_r["payload_bits"] * out_r["tx_mask"])
 

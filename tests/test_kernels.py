@@ -256,22 +256,51 @@ def test_fused_tiled_bit_parity_with_single_slab(kind, dtype):
                                           err_msg=f"{name}@{block_d}")
 
 
-def test_fused_tiled_env_dispatch(monkeypatch):
-    """REPRO_QUANT_TILE_D routes the ops-layer fused entry point through
-    the tiled kernel without changing a bit."""
-    from repro.kernels import ops
+def _fused_ops_args():
     tree, gids = _fused_tree_case("ragged")
     pk, theta, qprev, unif, bprev, rprev, init = _fused_inputs(
         tree, gids, jnp.float32)
     args = (theta, qprev, unif, bprev, rprev, init,
             jnp.asarray(pk.col_group_ids))
-    kw = dict(group_runs=pk.group_runs, omega=0.97, b0=3, b_max=16)
-    monkeypatch.delenv("REPRO_QUANT_TILE_D", raising=False)
-    slab = ops.stoch_quantize_grouped_fused(*args, **kw)
-    monkeypatch.setenv("REPRO_QUANT_TILE_D", "256")
-    tiled = ops.stoch_quantize_grouped_fused(*args, **kw)
+    return args, pk
+
+
+def test_fused_tiled_env_dispatch():
+    """The ops layer's explicit D-tiled entry equals its shape-selected
+    single-slab dispatch bit for bit."""
+    from repro.kernels import ops
+    args, pk = _fused_ops_args()
+    sched = dict(omega=0.97, b0=3, b_max=16)
+    assert ops.fused_slab_bytes(*args[0].shape) <= ops.FUSED_SLAB_BYTES
+    slab = ops.stoch_quantize_grouped_fused(*args, group_runs=pk.group_runs,
+                                            **sched)
+    tiled = ops.stoch_quantize_grouped_fused_tiled(*args, block_d=256,
+                                                   **sched)
     for g, w in zip(tiled, slab):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_fused_selection_follows_slab_bytes(monkeypatch):
+    """The fused quantize takes the tiled kernel exactly when its slab
+    exceeds ``FUSED_SLAB_BYTES``: small packed buffers stay on the slab,
+    an LM-width buffer (TinyLlama at one layer, two workers) tiles, and
+    lowering the budget under a small buffer tips it over."""
+    from repro.kernels import ops
+    from repro.obs.metrics import kernel_dispatch_counter
+    args, pk = _fused_ops_args()
+    n, d = args[0].shape
+    assert ops.fused_slab_bytes(n, d) == n * (d + (-d) % 128) * 4
+    assert ops.fused_slab_bytes(2, 109_582_336) > ops.FUSED_SLAB_BYTES
+    assert ops.fused_slab_bytes(16, 128) == 8 * 128 * 4     # one row block
+    c = kernel_dispatch_counter()
+    kw = dict(group_runs=pk.group_runs, omega=0.97, b0=3, b_max=16)
+    for budget, variant in ((ops.FUSED_SLAB_BYTES, "slab"),
+                            (ops.fused_slab_bytes(n, d) - 1, "tiled")):
+        monkeypatch.setattr(ops, "FUSED_SLAB_BYTES", budget)
+        before = c.value(kernel="stoch_quantize_fused", variant=variant)
+        ops.stoch_quantize_grouped_fused(*args, **kw)
+        assert c.value(kernel="stoch_quantize_fused",
+                       variant=variant) == before + 1
 
 
 # ------------------------------------------------------ paged attention --
@@ -494,7 +523,7 @@ def test_paged_attention_online_vmem_independent_of_context():
     one2 = shapes(paged_attention_decode, 2)
     one8 = shapes(paged_attention_decode, 8)
     assert one2 != one8
-    assert (4, 8 * 4) in one8          # (h, pps*ps) logits slab grows
+    assert (8, 4, 4) in one8           # (pps, h, ps) logits slab grows
     # online scratch: (h, hd) accumulator + (h, 1) running max and sum
     on = shapes(paged_attention_decode_online, 8)
     assert (4, 16) in on and on.count((4, 1)) == 2

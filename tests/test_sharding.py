@@ -8,20 +8,13 @@ from repro.configs import base
 from repro.launch import sharding as SH
 
 
-def _abstract_mesh(shape, names):
-    try:                              # jax >= 0.5: (axis_sizes, axis_names)
-        return AbstractMesh(shape, names)
-    except TypeError:                 # jax 0.4.x: ((name, size), ...) pairs
-        return AbstractMesh(tuple(zip(names, shape)))
-
-
 @pytest.fixture(scope="module")
 def mesh():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def multi_mesh():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_col_parallel(mesh):

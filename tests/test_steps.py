@@ -23,10 +23,11 @@ SCRIPT = textwrap.dedent("""
     import jax
     from repro.configs import base
     from repro.configs.base import ShapeConfig
+    from repro.core.topology import make_mesh
     from repro.runtime import steps
 
-    mesh_single = jax.make_mesh((4, 2), ("data", "model"))
-    mesh_multi = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh_single = make_mesh((4, 2), ("data", "model"))
+    mesh_multi = make_mesh((2, 2, 2), ("pod", "data", "model"))
     shapes = {
         "train": ShapeConfig("train_4k", 64, 8, "train"),
         "prefill": ShapeConfig("prefill_32k", 128, 4, "prefill"),
@@ -88,12 +89,13 @@ BACKEND_SCRIPT = textwrap.dedent("""
     import jax
     from repro.configs import base
     from repro.configs.base import ShapeConfig
+    from repro.core.topology import make_mesh
     from repro.runtime import steps
 
     cfg = base.get_smoke_config("tinyllama-1.1b")
     shape = ShapeConfig("train_4k", 64, 8, "train")
-    mesh_single = jax.make_mesh((4, 2), ("data", "model"))
-    mesh_multi = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh_single = make_mesh((4, 2), ("data", "model"))
+    mesh_multi = make_mesh((2, 2, 2), ("pod", "data", "model"))
     results = {}
     for backend in ("dense", "sparse", "sharded"):
         os.environ["REPRO_ADMM_MIX_BACKEND"] = backend

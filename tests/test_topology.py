@@ -118,14 +118,30 @@ def test_sharded_backend_runs_under_jit():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("n_workers,width",
+                         [(4, 4), (8, 4), (2, 2), (6, 2), (3, 1)])
+def test_worker_devices_take_equal_row_blocks(n_workers, width):
+    """Workers spread over the most devices that split them evenly: four
+    chips take 4 or 8 workers whole, 2 or 6 workers on two of them, and 3
+    workers on one."""
+    devices = ["d0", "d1", "d2", "d3"]
+    got = T.worker_devices(n_workers, devices)
+    assert got == devices[:width]
+    assert n_workers % len(got) == 0
+
+
 # ------------------------------------------- engine trajectory parity ----
 @pytest.mark.parametrize("backend", ["sparse", "sharded"])
 @pytest.mark.parametrize("scheme", ["ggadmm", "cq-ggadmm"])
 def test_engine_backend_matches_dense_trajectories(linreg, scheme, backend):
     """The full engine under sparse/sharded mixing reproduces the dense
     trajectories on the quickstart workload: identical censor decisions,
-    matching theta / residuals to fp tolerance. (Dense itself stays
-    bit-golden vs the frozen seed — tests/test_engine.py.)"""
+    matching theta / residuals to fp tolerance. The sparse mix sums the
+    neighbours in another order than the dense matmul; quantized, that
+    rounding becomes whole quantization steps, so there the residuals are
+    held to ``CROSS_PROGRAM_RTOL`` and the bits to ``assert_bits_track``.
+    (Dense itself stays bit-golden vs the frozen seed —
+    tests/test_engine.py.)"""
     g, prob = linreg
     outs = {}
     for b in ("dense", backend):
@@ -138,14 +154,34 @@ def test_engine_backend_matches_dense_trajectories(linreg, scheme, backend):
         outs[b] = (np.asarray(out["tx_mask"]),
                    np.asarray(out["primal_residual"]),
                    np.asarray(state.theta),
-                   np.asarray(out["payload_bits"]))
+                   np.asarray(out["payload_bits"]),
+                   np.asarray(out["bits_per_group"]))
     np.testing.assert_array_equal(outs["dense"][0], outs[backend][0])
-    np.testing.assert_allclose(outs["dense"][1], outs[backend][1],
-                               rtol=1e-3, atol=1e-5)
     np.testing.assert_allclose(outs["dense"][2], outs[backend][2],
                                rtol=1e-4, atol=1e-5)
+    if scheme == "cq-ggadmm" and backend == "sparse":
+        assert E.tree_rel_err(outs[backend][1],
+                              outs["dense"][1]) <= E.CROSS_PROGRAM_RTOL
+        assert_bits_track(outs["dense"][3:], outs[backend][3:])
+        return
+    np.testing.assert_allclose(outs["dense"][1], outs[backend][1],
+                               rtol=1e-3, atol=1e-5)
     np.testing.assert_allclose(outs["dense"][3], outs[backend][3],
                                rtol=1e-6)
+
+
+def assert_bits_track(a, b):
+    """Two programs' quantized runs, each given as ``(payload_bits (K, N),
+    bits_per_group (K, N, G))``, put the same bits on the wire until their
+    ranges part by rounding. A bit width is the ceil of a range ratio
+    (Eq. 18), so from a shared previous width the two schedules part by at
+    most one step, and the payload is the same wherever the widths are."""
+    (pay_a, bits_a), (pay_b, bits_b) = a, b
+    np.testing.assert_array_equal(bits_a[0], bits_b[0])
+    shared_prev = bits_a[:-1] == bits_b[:-1]
+    assert np.abs(bits_a[1:] - bits_b[1:])[shared_prev].max(initial=0) <= 1
+    same = (bits_a == bits_b).all(axis=-1)
+    np.testing.assert_array_equal(pay_a[same], pay_b[same])
 
 
 def test_engine_backend_pytree_training_agrees():
@@ -212,7 +248,10 @@ def test_dual_update_uses_pallas_mix(linreg, monkeypatch):
 
 def test_dual_update_with_pallas_stays_golden(linreg):
     """Forwarding the kernel flag to the dual step must not change the
-    numbers: the Pallas MXU mix is bit-identical to the plain matmul."""
+    run: identical censor decisions, duals within ``CROSS_PROGRAM_RTOL``
+    of the plain matmul's (the Pallas mix sums the neighbours in another
+    order, and the quantizer carries that rounding forward), and the bits
+    on the wire as ``assert_bits_track`` allows."""
     g, prob = linreg
     outs = {}
     for use_kernel in (False, True):
@@ -222,6 +261,28 @@ def test_dual_update_with_pallas_stays_golden(linreg):
                            jnp.zeros((N_WORKERS, DIM), jnp.float32),
                            20, seed=3)
         outs[use_kernel] = (np.asarray(out["tx_mask"]),
-                            np.asarray(state.alpha))
+                            np.asarray(state.alpha),
+                            np.asarray(out["payload_bits"]),
+                            np.asarray(out["bits_per_group"]))
     np.testing.assert_array_equal(outs[False][0], outs[True][0])
-    np.testing.assert_array_equal(outs[False][1], outs[True][1])
+    assert E.tree_rel_err(outs[True][1], outs[False][1]) \
+        <= E.CROSS_PROGRAM_RTOL
+    assert_bits_track(outs[False][2:], outs[True][2:])
+
+
+@pytest.mark.parametrize("backend,use_pallas", [
+    ("dense", False), ("dense", True), ("sharded", False),
+    ("sharded", True)])
+def test_mix_runs_at_full_f32_precision(backend, use_pallas):
+    """Every matmul the mix lowers to asks for ``Precision.HIGHEST``: a
+    TPU's default f32 matmul rounds its operands to bfloat16, which would
+    round every model the workers exchange (the CPU computes in f32 either
+    way, so only the lowered program can show it)."""
+    g = random_bipartite_graph(N_WORKERS, 0.4, seed=0)
+    topo = T.build(g, backend, use_pallas_mix=use_pallas)
+    text = jax.jit(topo.mix).lower(
+        jnp.zeros((N_WORKERS, DIM), jnp.float32)).as_text()
+    dots = [ln for ln in text.splitlines() if "dot_general" in ln]
+    assert dots
+    for ln in dots:
+        assert "precision = [HIGHEST, HIGHEST]" in ln, ln

@@ -6,10 +6,11 @@ import jax.numpy as jnp
 
 from repro.configs import base
 from repro.configs.base import ShapeConfig
+from repro.core.topology import make_mesh
 from repro.runtime import steps
 
-mesh_single = jax.make_mesh((4, 2), ("data", "model"))
-mesh_multi = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh_single = make_mesh((4, 2), ("data", "model"))
+mesh_multi = make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 SHAPES = {
     "train": ShapeConfig("train_4k", 64, 8, "train"),
