@@ -850,6 +850,12 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
     (dense matmul / sparse edge gather / sharded SPMD — DESIGN.md
     §Topology).
 
+    Each part runs under a ``jax.named_scope`` (``admm.mix``,
+    ``admm.solve``, ``admm.quantize``, ``admm.censor``, ``admm.commit``;
+    ``make_step`` adds ``admm.dual`` and ``admm.metrics``): metadata that
+    names the compiled program's instructions by the part of the round
+    they belong to, and adds no op (DESIGN.md §Observability).
+
     Returns the 8-tuple ``(new_state, tx_mask (N,), payload_bits (N,),
     candidate_payload_bits (N,), bits (N, G), group_tx (N, G),
     censor_mask (N,), offered_payload_bits (N,))`` restricted to
@@ -877,39 +883,66 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
     group_ids = resolve_groups(state.theta, cfg.groups)
     n_groups = max(group_ids) + 1
     rho = cfg.rho
-    neigh = topo.mix(state.theta_hat)
+    with jax.named_scope("admm.mix"):
+        neigh = topo.mix(state.theta_hat)
+        if cfg.alternating:
+            # GGADMM primal, Eqs. (11)/(12)/(21)/(22)
+            v = jax.tree_util.tree_map(
+                lambda a, nm: a.astype(jnp.float32)
+                - rho * nm.astype(jnp.float32), state.alpha, neigh)
+            quad = rho_d
+        else:
+            # Jacobian C-ADMM primal (Liu et al., 2019b): proximal
+            # self-anchor
+            def jac_v(a, th, nm):
+                shape1 = (th.shape[0],) + (1,) * (th.ndim - 1)
+                return (a.astype(jnp.float32)
+                        - rho_d.reshape(shape1) * th.astype(jnp.float32)
+                        - rho * nm.astype(jnp.float32))
+            v = jax.tree_util.tree_map(jac_v, state.alpha, state.theta_hat,
+                                       neigh)
+            quad = 2.0 * rho_d
 
-    if cfg.alternating:
-        # GGADMM primal, Eqs. (11)/(12)/(21)/(22)
-        v = jax.tree_util.tree_map(
-            lambda a, nm: a.astype(jnp.float32)
-            - rho * nm.astype(jnp.float32), state.alpha, neigh)
-        quad = rho_d
-    else:
-        # Jacobian C-ADMM primal (Liu et al., 2019b): proximal self-anchor
-        def jac_v(a, th, nm):
-            shape1 = (th.shape[0],) + (1,) * (th.ndim - 1)
-            return (a.astype(jnp.float32)
-                    - rho_d.reshape(shape1) * th.astype(jnp.float32)
-                    - rho * nm.astype(jnp.float32))
-        v = jax.tree_util.tree_map(jac_v, state.alpha, state.theta_hat,
-                                   neigh)
-        quad = 2.0 * rho_d
+    with jax.named_scope("admm.solve"):
+        theta_full, mu_full, nu_full = solver.solve(
+            state.theta, v, quad, state.opt_mu, state.opt_nu, batch)
+        theta = tree_where_worker(phase_mask, theta_full, state.theta)
+        mu = tree_where_worker(phase_mask, mu_full, state.opt_mu)
+        nu = tree_where_worker(phase_mask, nu_full, state.opt_nu)
 
-    theta_full, mu_full, nu_full = solver.solve(
-        state.theta, v, quad, state.opt_mu, state.opt_nu, batch)
-    theta = tree_where_worker(phase_mask, theta_full, state.theta)
-    mu = tree_where_worker(phase_mask, mu_full, state.opt_mu)
-    nu = tree_where_worker(phase_mask, nu_full, state.opt_nu)
+    with jax.named_scope("admm.quantize"):
+        if cfg.quantize is not None:
+            quant_new, candidate, bits, payload = grouped_quantize_step(
+                state.quant, theta, key, cfg.quantize, group_ids,
+                use_kernel=cfg.use_pallas_quant)
+        else:
+            quant_new, candidate, bits, payload = identity_quantize_step(
+                state.quant, theta)
 
-    if cfg.quantize is not None:
-        quant_new, candidate, bits, payload = grouped_quantize_step(
-            state.quant, theta, key, cfg.quantize, group_ids,
-            use_kernel=cfg.use_pallas_quant)
-    else:
-        quant_new, candidate, bits, payload = identity_quantize_step(
-            state.quant, theta)
+    with jax.named_scope("admm.censor"):
+        tx_mask, payload_tx, candidate_payload, group_tx, censor_mask, \
+            offered_payload = _censor_and_price(
+                state, candidate, theta, bits, payload, phase_mask, cfg,
+                group_ids, n_groups, participation)
+        pm_col = phase_mask[:, None]
+        phase_bits = bits * pm_col
 
+    with jax.named_scope("admm.commit"):
+        new_state = _commit(state, candidate, quant_new, group_tx,
+                            phase_mask, pm_col, group_ids, theta, mu, nu)
+    return (new_state, tx_mask, payload_tx, candidate_payload, phase_bits,
+            group_tx, censor_mask, offered_payload)
+
+
+def _censor_and_price(state: EngineState, candidate: Tree, theta: Tree,
+                      bits: jax.Array, payload: jax.Array,
+                      phase_mask: jax.Array, cfg: EngineConfig,
+                      group_ids: Sequence[int], n_groups: int,
+                      participation: Optional[jax.Array]):
+    """The censor test (Eq. 13/14), its composition with ``participation``
+    and the phase, and the bits each decision puts on the wire. Returns
+    ``(tx_mask, payload_tx, candidate_payload, group_tx, censor_mask,
+    offered_payload)``."""
     k_next = (state.k + 1).astype(jnp.float32)
     cmask_cens, gmask_cens = _censor_masks(state, candidate, cfg, group_ids,
                                            n_groups, k_next)
@@ -941,7 +974,18 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
         payload_tx = payload * tx_mask
         offered_payload = payload_tx if participation is None \
             else payload * censor_mask
+    return (tx_mask, payload_tx, candidate_payload, group_tx, censor_mask,
+            offered_payload)
 
+
+def _commit(state: EngineState, candidate: Tree,
+            quant_new: GroupQuantState, group_tx: jax.Array,
+            phase_mask: jax.Array, pm_col: jax.Array,
+            group_ids: Sequence[int], theta: Tree, mu: Tree,
+            nu: Tree) -> EngineState:
+    """The phase's new state: ``theta_hat`` committed where each leaf's
+    group transmitted, the quantizer replicas advanced for the acting
+    workers (``pm_col``: ``phase_mask[:, None]``)."""
     # theta_hat: each leaf commits where its group transmitted
     hat_leaves, treedef = jax.tree_util.tree_flatten(state.theta_hat)
     cand_leaves = jax.tree_util.tree_leaves(candidate)
@@ -954,7 +998,6 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
 
     # quantizer replicas advance for the acting phase's workers only (they
     # ran Eq. (20) this phase; censoring does not roll the chain back).
-    pm_col = phase_mask[:, None]
     quant = GroupQuantState(
         q_hat=tree_where_worker(phase_mask, quant_new.q_hat,
                                 state.quant.q_hat),
@@ -967,10 +1010,8 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
         initialized=jnp.where(pm_col > 0, quant_new.initialized,
                               state.quant.initialized),
     )
-    new_state = dataclasses.replace(state, theta=theta, theta_hat=theta_hat,
-                                    quant=quant, opt_mu=mu, opt_nu=nu)
-    return (new_state, tx_mask, payload_tx, candidate_payload,
-            bits * pm_col, group_tx, censor_mask, offered_payload)
+    return dataclasses.replace(state, theta=theta, theta_hat=theta_hat,
+                               quant=quant, opt_mu=mu, opt_nu=nu)
 
 
 MetricsFn = Callable[[EngineState, Any], Dict[str, jax.Array]]
@@ -1041,12 +1082,18 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
         # Laplacian goes through the same topology backend (and therefore
         # the same kernel routing) as the phase mixes — the seed bug where
         # the dual step silently dropped ``use_pallas_mix`` cannot recur.
-        lap = topo.laplacian(state.theta_hat)
+        with jax.named_scope("admm.dual"):
+            lap = topo.laplacian(state.theta_hat)
 
-        def dual(a, lp):
-            return (a.astype(jnp.float32) + cfg.rho * lp).astype(a.dtype)
+            def dual(a, lp):
+                return (a.astype(jnp.float32)
+                        + cfg.rho * lp).astype(a.dtype)
 
-        alpha = jax.tree_util.tree_map(dual, state.alpha, lap)
+            alpha = jax.tree_util.tree_map(dual, state.alpha, lap)
+            # squared norm of the dual step rho (D - A) theta_hat, from
+            # the Laplacian already computed for alpha (no extra mix);
+            # -> 0 exactly at consensus of the transmitted models
+            dual_residual = (cfg.rho ** 2) * topo.dual_residual(lap)
         state = dataclasses.replace(state, alpha=alpha, k=state.k + 1)
 
         metrics = {
@@ -1057,13 +1104,11 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
             "group_tx": group_tx,
             "censor_mask": censor_mask,
             "offered_payload_bits": offered_payload,
-            # squared norm of the dual step rho (D - A) theta_hat, from
-            # the Laplacian already computed for alpha (no extra mix);
-            # -> 0 exactly at consensus of the transmitted models
-            "dual_residual": (cfg.rho ** 2) * topo.dual_residual(lap),
+            "dual_residual": dual_residual,
         }
         if extra_metrics is not None:
-            metrics.update(extra_metrics(state, batch))
+            with jax.named_scope("admm.metrics"):
+                metrics.update(extra_metrics(state, batch))
         return state, metrics
 
     return step

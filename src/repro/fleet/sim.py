@@ -430,28 +430,27 @@ class FleetSim:
                 if self.batch_fn is not None else None
             key = jax.random.fold_in(base, r)
             n = len(self.members)
+            with obs_trace.span("round", "fleet", track="rounds",
+                                args={"round": r, "n_members": n}):
+                if (not rf.drop.any() and not rf.lag.any()
+                        and not self._host_timer.any()):
+                    # fault-free round, nothing in flight: the exact
+                    # program of the synchronous golden arm (bit-identity
+                    # contract)
+                    state, m = self._sync_step(fs.engine, batch, key)
+                    fs = dataclasses.replace(fs, engine=state)
+                    host = jax.device_get(m)
+                    host["fleet_participation"] = np.ones(n, np.float32)
+                    host["fleet_start"] = np.zeros(n, np.float32)
+                    host["fleet_deliver"] = np.zeros(n, np.float32)
+                    host["fleet_timer"] = np.zeros(n, np.int32)
+                else:
+                    fs, m = self._step(fs, batch, key, jnp.asarray(rf.drop),
+                                       jnp.asarray(rf.lag))
+                    host = jax.device_get(m)
+                    self._host_timer = np.asarray(host["fleet_timer"],
+                                                  np.int32)
             if tr is not None:
-                tr.begin("round", "fleet", tr.track("fleet", "rounds"),
-                         args={"round": r, "n_members": n})
-            if (not rf.drop.any() and not rf.lag.any()
-                    and not self._host_timer.any()):
-                # fault-free round, nothing in flight: the exact program
-                # of the synchronous golden arm (bit-identity contract)
-                state, m = self._sync_step(fs.engine, batch, key)
-                fs = dataclasses.replace(fs, engine=state)
-                host = jax.device_get(m)
-                host["fleet_participation"] = np.ones(n, np.float32)
-                host["fleet_start"] = np.zeros(n, np.float32)
-                host["fleet_deliver"] = np.zeros(n, np.float32)
-                host["fleet_timer"] = np.zeros(n, np.int32)
-            else:
-                fs, m = self._step(fs, batch, key, jnp.asarray(rf.drop),
-                                   jnp.asarray(rf.lag))
-                host = jax.device_get(m)
-                self._host_timer = np.asarray(host["fleet_timer"],
-                                              np.int32)
-            if tr is not None:
-                tr.end("fleet", tr.track("fleet", "rounds"))
                 self._trace_worker_events(tr, r, rf, host)
                 ledger.update(host)
             host["n_members"] = np.asarray(n, np.int32)

@@ -39,6 +39,7 @@ from repro.core.quantization import QuantConfig
 from repro.data.lm import SyntheticLM, SyntheticLMConfig, model_batch
 from repro.launch import compile_cache
 from repro.models import registry
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.ledger import CommLedger
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
@@ -212,14 +213,19 @@ class AdmmSetup:
         return jax.jit(step, donate_argnums=0)
 
     def batch(self, i: int):
-        """Round ``i``'s stacked per-worker batch, in worker placement."""
+        """Round ``i``'s stacked per-worker batch, in worker placement
+        (program span ``repro.train.input``; its child
+        ``repro.train.input.generate`` times the token stream alone)."""
         a = self.args
-        raw = self.data.worker_batch(i, a.workers, a.batch // a.workers)
-        b = model_batch(self.cfg, raw, key=jax.random.PRNGKey(i))
-        if self.mesh is None:
-            return b
-        return jax.tree_util.tree_map(
-            lambda x: jax.device_put(x, self._sharding(x)), b)
+        with obs_trace.span("input", "train", track="input"):
+            with obs_trace.span("input.generate", "train", track="input"):
+                raw = self.data.worker_batch(i, a.workers,
+                                             a.batch // a.workers)
+            b = model_batch(self.cfg, raw, key=jax.random.PRNGKey(i))
+            if self.mesh is None:
+                return b
+            return jax.tree_util.tree_map(
+                lambda x: jax.device_put(x, self._sharding(x)), b)
 
     @staticmethod
     def key(i: int) -> jax.Array:
@@ -228,6 +234,8 @@ class AdmmSetup:
 
 
 def admm_setup(cfg, args) -> AdmmSetup:
+    obs_metrics.install_compile_listener()
+
     def grad_fn(theta, batch):
         return jax.vmap(lambda p, b: jax.grad(
             lambda pp: registry.lm_loss(pp, cfg, b)[0])(p))(theta, batch)
@@ -279,9 +287,7 @@ def run_admm(cfg, args) -> dict:
     # metrics the step already returns, the span brackets the Python-level
     # round — neither adds an op to the jitted program (tests/test_obs.py
     # pins the jaxpr)
-    tr = obs_trace.tracer()
-    ledger = CommLedger(setup.graph) if tr is not None else None
-    rounds_tid = tr.track("engine", "rounds") if tr is not None else 0
+    ledger = CommLedger(setup.graph) if obs_trace.enabled() else None
     total_bits = 0.0
     t0 = time.time()
     history, tx_history, bits_history = [], [], []
@@ -304,13 +310,11 @@ def run_admm(cfg, args) -> dict:
                 print(f"[train] step {i}: regrouped to G={n_groups} "
                       f"({new_ids})")
         batch = setup.batch(i)
-        if tr is not None:
-            tr.begin("round", "engine", rounds_tid, args={"round": i})
-        state, m = step(state, batch, setup.key(i))
-        if ledger is not None:
-            ledger.update(jax.device_get(m))
-        if tr is not None:
-            tr.end("engine", rounds_tid)
+        with obs_trace.span("round", "engine", track="rounds",
+                            args={"round": i}):
+            state, m = step(state, batch, setup.key(i))
+            if ledger is not None:
+                ledger.update(jax.device_get(m))
         bits = float(m["payload_bits"].sum())   # already tx-masked
         total_bits += bits
         mean_bits = float(np.asarray(m["bits_per_group"]).mean())

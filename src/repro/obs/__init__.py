@@ -16,6 +16,18 @@ Three host-side-only modules:
 Zero-overhead contract: nothing in this package ever adds an op to a
 jitted/Pallas program — every observer consumes values the traced programs
 already return on host (pinned by ``tests/test_obs.py``'s jaxpr test and
-the tracing-ON golden rows in the engine/fleet/serving suites).
+the tracing-ON golden rows in the engine/fleet/serving suites). The
+engine's phase scopes (``admm.mix`` ... ``admm.metrics``, a
+``jax.named_scope`` around each part of a round) are held to the same
+contract: metadata in the compiled program's ``op_name``, no op.
+
+Measurement on the profiler's clock: :func:`repro.obs.trace.span` enters
+``jax.profiler.TraceAnnotation("repro.<subsystem>.<name>")`` always, and
+records a JSON span only when the tracer is enabled, so a profiler trace
+shows the program's own spans beside the device. The compile counter
+(:func:`repro.obs.metrics.install_compile_listener`) folds JAX's trace,
+lowering and compile events into ``compile_seconds{stage, fun}`` and
+``compiles{stage, fun}``; a set-up's split into those stages, and a
+delta of zero over a steady loop, are read from it.
 """
 from repro.obs import ledger, metrics, trace  # noqa: F401

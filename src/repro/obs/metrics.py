@@ -19,14 +19,23 @@ Design constraints, in order:
 
 Metrics never touch traced/jitted code — callers observe host-side
 floats the programs already returned.
+
+The compile counter (:func:`install_compile_listener`) is fed by JAX's
+own ``jax.monitoring`` events: each jaxpr trace, MLIR lowering and
+backend compile (a compile-cache retrieval included) adds its seconds to
+``compile_seconds{stage, fun}`` and one to ``compiles{stage, fun}``.
 """
 from __future__ import annotations
 
+import re
 import threading
 from collections import OrderedDict, deque
 from typing import Any, Dict, Iterable, Iterator, Optional, Tuple
 
+import jax
 import numpy as np
+
+from repro.obs import trace as obs_trace
 
 # Prometheus-style latency buckets, in seconds; +Inf is implicit.
 DEFAULT_LATENCY_BUCKETS = (
@@ -307,3 +316,92 @@ def kernel_dispatch_counter() -> Counter:
         "kernel_dispatch", labels=("kernel", "variant"),
         help="ops.py wrapper dispatches by kernel variant (trace-time)",
     )
+
+
+# jax.monitoring time-span events -> the compile counter's ``stage``.
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+def compile_counters() -> Tuple[Counter, Counter]:
+    """``(compile_seconds, compiles)``, both labelled ``(stage, fun)``.
+    The seconds of an event exclude the events nested in it (a jitted
+    function traced inside another's trace counts under its own name
+    only), so the series sum to the host time the stages took. JAX
+    reports a trace that its trace cache answers (``.lower()`` of a
+    function already called) as a trace event of some microseconds;
+    such a call reports no lowering and no compile."""
+    return (REGISTRY.counter(
+        "compile_seconds", labels=("stage", "fun"),
+        help="host seconds per compile stage and function, nested "
+             "stages excluded"),
+        REGISTRY.counter(
+            "compiles", labels=("stage", "fun"),
+            help="compile-stage events per stage and function"))
+
+
+_MODULE_NAME = re.compile(r"jit\((.*)\)")
+
+
+class _CompileListener:
+    """Folds ``jax.monitoring`` time spans into the compile counters. An
+    event is reported when it ends, after the events nested in it, so the
+    completed events not yet claimed by an enclosing one are kept on a
+    stack and subtracted from the first event that started before them."""
+
+    def __init__(self, depth: int = 65536):
+        self._done: deque = deque(maxlen=depth)
+        self._lock = threading.Lock()
+
+    def __call__(self, event: str, start_time: float, end_time: float,
+                 **kwargs: Any) -> None:
+        stage = COMPILE_EVENTS.get(event)
+        if stage is None:
+            return
+        fun = str(kwargs.get("fun_name", "?"))
+        module = _MODULE_NAME.fullmatch(fun)
+        if module:                     # ``jit(step)`` -> ``step``
+            fun = module.group(1)
+        with self._lock:
+            nested = 0.0
+            while self._done and self._done[-1][0] >= start_time:
+                s, e = self._done.pop()
+                nested += e - s
+            self._done.append((start_time, end_time))
+        seconds, count = compile_counters()
+        seconds.inc(max(end_time - start_time - nested, 0.0), stage=stage,
+                    fun=fun)
+        count.inc(1, stage=stage, fun=fun)
+        tr = obs_trace.tracer()
+        if tr is not None:
+            tr.record_span(f"{stage} {fun}", "jax",
+                           tr.track("jax", "compile"), start_time, end_time)
+
+
+_compile_listener: Optional[_CompileListener] = None
+
+
+def install_compile_listener() -> None:
+    """Register the compile counter's listener with ``jax.monitoring``
+    once per process; later calls do nothing."""
+    global _compile_listener
+    if _compile_listener is None:
+        _compile_listener = _CompileListener()
+        jax.monitoring.register_event_time_span_listener(_compile_listener)
+
+
+def compile_stage_seconds(snapshot: Optional[Dict[str, Any]] = None
+                          ) -> Dict[str, float]:
+    """Seconds per stage (``trace``, ``lower``, ``compile``) summed over
+    functions, from ``snapshot`` (a :meth:`Registry.snapshot` or
+    :meth:`Registry.delta`) or the live registry."""
+    snap = REGISTRY.snapshot() if snapshot is None else snapshot
+    series = snap.get("compile_seconds", {}).get("series", {})
+    out = dict.fromkeys(COMPILE_EVENTS.values(), 0.0)
+    for key, v in series.items():
+        stage = key.split(",", 1)[0]
+        out[stage] = out.get(stage, 0.0) + v
+    return out

@@ -20,6 +20,13 @@ Spans on one track must nest (validated by :func:`validate_events`);
 :meth:`Tracer.save` synthesizes ``E`` events for still-open spans in the
 *written* file only, so mid-run saves stay balanced without corrupting
 the live state.
+
+Program spans (:func:`span`) also go to the profiler: each enters
+``jax.profiler.TraceAnnotation("repro.<subsystem>.<name>")`` whether or
+not this tracer is enabled, so a ``jax.profiler`` trace shows them on the
+host clock it aligns with the device. With no profiler session open the
+annotation costs about a microsecond. The JSON file keeps its own clock
+for use without a profiler.
 """
 from __future__ import annotations
 
@@ -31,7 +38,11 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
+from jax import profiler as jax_profiler
+
 ENV_VAR = "REPRO_TRACE"
+# Prefix of every program span's name on the profiler's trace.
+PROFILER_PREFIX = "repro."
 
 _PHASES = ("B", "E", "i", "C", "M")
 
@@ -42,6 +53,7 @@ class Tracer:
     def __init__(self, path: str):
         self.path = str(path)
         self._t0 = time.perf_counter()
+        self._wall0 = time.time()
         self._lock = threading.Lock()
         self._events: List[Dict[str, Any]] = []
         self._pids: Dict[str, int] = {}
@@ -117,6 +129,22 @@ class Tracer:
         finally:
             self.end(subsystem, tid)
 
+    def record_span(self, name: str, subsystem: str, tid: int,
+                    start_s: float, end_s: float,
+                    args: Optional[Dict[str, Any]] = None) -> None:
+        """A span that has already ended, from its ``time.time()`` start
+        and end (reported after the fact, as ``jax.monitoring`` does).
+        Spans recorded this way on one track must nest or be disjoint."""
+        with self._lock:
+            pid = self._pid(subsystem)
+            for ph, t in (("B", start_s), ("E", end_s)):
+                ev: Dict[str, Any] = {"name": name, "cat": subsystem,
+                                      "ph": ph, "pid": pid, "tid": tid,
+                                      "ts": (t - self._wall0) * 1e6}
+                if args and ph == "B":
+                    ev["args"] = args
+                self._events.append(ev)
+
     def instant(self, name: str, subsystem: str, tid: int = 0,
                 args: Optional[Dict[str, Any]] = None) -> None:
         with self._lock:
@@ -188,6 +216,26 @@ def disable(save: bool = True) -> None:
 
 def save() -> Optional[str]:
     return _tracer.save() if _tracer is not None else None
+
+
+@contextmanager
+def span(name: str, subsystem: str, track: Optional[str] = None,
+         args: Optional[Dict[str, Any]] = None) -> Iterator[None]:
+    """A program span: ``repro.<subsystem>.<name>`` on the profiler's
+    trace always, and a ``name`` span on ``track`` (tid 0 when ``None``)
+    of ``subsystem`` in the JSON trace when that is enabled. ``args`` go
+    to the JSON trace only."""
+    with jax_profiler.TraceAnnotation(f"{PROFILER_PREFIX}{subsystem}.{name}"):
+        tr = _tracer
+        if tr is None:
+            yield
+            return
+        tid = tr.track(subsystem, track) if track is not None else 0
+        tr.begin(name, subsystem, tid, args)
+        try:
+            yield
+        finally:
+            tr.end(subsystem, tid)
 
 
 @atexit.register
