@@ -65,7 +65,20 @@ is exactly the seed draw, so the G=1 flat path reproduces the seed
 ``cq_ggadmm`` trajectories bit-for-bit (golden tests in
 ``tests/test_engine.py``); for multi-leaf trees the packed draw replaces
 the per-leaf key split of the old unfused loop (kept as
-``grouped_quantize_step_unfused`` for parity benchmarks).
+``grouped_quantize_step_unfused`` for parity benchmarks). A phase that
+quantizes only its acting workers' rows draws only those rows
+(``quantization.uniform_rows``): bit for bit the same rows of the draw
+over all N workers, so the row path changes no number.
+
+**Acting rows** (DESIGN.md §Engine): in an alternating step each phase
+solves and quantizes only its own group's workers — their rows are
+static per graph — and writes them back into the N-row state. The
+masked path (compute all N rows, keep the acting ones) remains where rows
+cannot be split off: the Jacobian step (every worker acts), workers
+placed across devices (a mesh or the sharded backend), and, for the
+solve only, an :class:`ExactSolver` or a call without a batch, whose
+problem data is not per-row input. ``obs.metrics.engine_phase_rows``
+counts, at trace time, the rows each part computes on each path.
 """
 from __future__ import annotations
 
@@ -84,6 +97,7 @@ from repro.core import topology as topo_lib
 from repro.core.censoring import CensorConfig, threshold
 from repro.core.graph import WorkerGraph
 from repro.core.quantization import QuantConfig
+from repro.obs.metrics import engine_phase_rows_counter
 
 _EPS = 1e-12
 
@@ -173,6 +187,33 @@ def tree_where_worker(mask: jax.Array, a: Tree, b: Tree) -> Tree:
         m = mask.reshape((mask.shape[0],) + (1,) * (x.ndim - 1))
         return jnp.where(m > 0, x, y)
     return jax.tree_util.tree_map(sel, a, b)
+
+
+def _contiguous(rows: np.ndarray) -> bool:
+    return bool(np.all(np.diff(rows) == 1))
+
+
+def take_rows(a: Tree, rows: np.ndarray) -> Tree:
+    """Rows ``rows`` (static) of every leaf: a slice when they are
+    contiguous, else a gather."""
+    if _contiguous(rows):
+        lo, hi = int(rows[0]), int(rows[-1]) + 1
+        return jax.tree_util.tree_map(lambda x: x[lo:hi], a)
+    idx = jnp.asarray(rows, jnp.int32)
+    return jax.tree_util.tree_map(lambda x: jnp.take(x, idx, axis=0), a)
+
+
+def put_rows(a: Tree, rows: np.ndarray, new: Tree) -> Tree:
+    """``a`` with rows ``rows`` (static) of every leaf replaced by ``new``'s:
+    one ``dynamic-update-slice`` when the rows are contiguous (in place in
+    a donated buffer), else a scatter."""
+    if _contiguous(rows):
+        lo = int(rows[0])
+        return jax.tree_util.tree_map(
+            lambda x, y: jax.lax.dynamic_update_slice_in_dim(
+                x, y.astype(x.dtype), lo, axis=0), a, new)
+    idx = jnp.asarray(rows, jnp.int32)
+    return jax.tree_util.tree_map(lambda x, y: x.at[idx].set(y), a, new)
 
 
 # ------------------------------------------------------- group resolution --
@@ -296,6 +337,7 @@ def _leaf_keys(key: jax.Array, n_leaves: int):
 def grouped_quantize_step(
     state: GroupQuantState, theta: Tree, key: jax.Array, cfg: QuantConfig,
     group_ids: Sequence[int], use_kernel: bool = False,
+    rows: Optional[np.ndarray] = None, n_workers: Optional[int] = None,
 ) -> Tuple[GroupQuantState, Tree, jax.Array, jax.Array]:
     """One grouped stochastic-quantization round (Eqs. 14-20, group-wise).
 
@@ -303,15 +345,34 @@ def grouped_quantize_step(
     trees run the fused packed-buffer path — one segment-reduced range, one
     uniform draw, one quantize call for the whole tree.
 
+    With ``rows``, ``state`` and ``theta`` hold only those rows of an
+    ``n_workers``-row state, and each draws exactly its own rows of the
+    uniforms the full state would draw (:func:`_uniforms`), so the result
+    equals those rows of the full round.
+
     Returns ``(new_state, candidate_tree, bits (N, G), payload (N,))`` where
     payload = sum_g b_g d_g + G * overhead — each group ships its own
     ``(R_g, b_g)`` side information.
     """
     if len(jax.tree_util.tree_leaves(theta)) == 1:
         return grouped_quantize_step_unfused(state, theta, key, cfg,
-                                             group_ids, use_kernel)
+                                             group_ids, use_kernel,
+                                             rows, n_workers)
     return _grouped_quantize_step_packed(state, theta, key, cfg, group_ids,
-                                         use_kernel)
+                                         use_kernel, rows, n_workers)
+
+
+def _uniforms(key: jax.Array, shape: Tuple[int, ...],
+              rows: Optional[np.ndarray], n_workers: Optional[int]
+              ) -> jax.Array:
+    """The stochastic rounding's uniforms for a ``shape`` block: the whole
+    draw ``jax.random.uniform(key, shape)``, or, with ``rows``, those rows
+    of the draw over ``(n_workers,) + shape[1:]``."""
+    if rows is None:
+        return jax.random.uniform(key, shape, jnp.float32)
+    width = int(np.prod(shape[1:], dtype=np.int64))
+    return quant_lib.uniform_rows(key, n_workers, width,
+                                  rows).reshape(shape)
 
 
 def _finish_packed_step(state: GroupQuantState, pk, out, range_new, bits,
@@ -337,6 +398,7 @@ def _finish_packed_step(state: GroupQuantState, pk, out, range_new, bits,
 def _grouped_quantize_step_packed(
     state: GroupQuantState, theta: Tree, key: jax.Array, cfg: QuantConfig,
     group_ids: Sequence[int], use_kernel: bool = False,
+    rows: Optional[np.ndarray] = None, n_workers: Optional[int] = None,
 ) -> Tuple[GroupQuantState, Tree, jax.Array, jax.Array]:
     """Fused path: the whole grouped round — range reduction, Eq. (18) bit
     schedule, quantize, degenerate passthrough — in one call over the
@@ -351,7 +413,7 @@ def _grouped_quantize_step_packed(
 
     # One draw for the whole packed buffer with the phase key (the fused
     # analog of the seed's single whole-vector draw).
-    uniforms = jax.random.uniform(key, theta_p.shape, jnp.float32)
+    uniforms = _uniforms(key, theta_p.shape, rows, n_workers)
     gid_cols = jnp.asarray(pk.col_group_ids)
     if use_kernel:
         from repro.kernels import ops as kernel_ops
@@ -403,6 +465,7 @@ def grouped_quantize_step_twopass(
 def grouped_quantize_step_unfused(
     state: GroupQuantState, theta: Tree, key: jax.Array, cfg: QuantConfig,
     group_ids: Sequence[int], use_kernel: bool = False,
+    rows: Optional[np.ndarray] = None, n_workers: Optional[int] = None,
 ) -> Tuple[GroupQuantState, Tree, jax.Array, jax.Array]:
     """Per-leaf reference loop (one uniform draw + one quantize call per
     leaf). Single-leaf trees MUST take this path — it draws with the phase
@@ -434,7 +497,7 @@ def grouped_quantize_step_unfused(
         shape1 = (n,) + (1,) * (t.ndim - 1)
         d_g = jnp.maximum(delta[:, g], _EPS)
         r_g = range_new[:, g]
-        uniforms = jax.random.uniform(k, t.shape, jnp.float32)
+        uniforms = _uniforms(k, t.shape, rows, n_workers)
         if use_kernel:
             from repro.kernels import ops as kernel_ops
             flat = t.reshape(n, -1)
@@ -549,7 +612,14 @@ class ExactSolver:
 class InexactSolver:
     """K Adam (or SGD) steps on g(theta) = f(theta) + <theta, v> +
     quad/2 ||theta||^2 — the inexact-ADMM local solver for non-convex f_n
-    (DESIGN.md §5). Optimizer moments persist across outer iterations."""
+    (DESIGN.md §5). Optimizer moments persist across outer iterations.
+
+    When the engine is given a batch, ``grad_fn(theta, batch)`` must be
+    row-wise: worker n's gradient depends only on row n of ``theta`` and
+    row n of every ``batch`` leaf (a ``jax.vmap`` over workers is). An
+    alternating step then solves only the acting group's rows, passing
+    ``grad_fn`` those rows of both. Called without a batch, ``grad_fn``
+    always sees all N rows (it may close over N-row problem data)."""
 
     grad_fn: Optional[Callable[[Tree, Any], Tree]] = None
     local_steps: int = 4
@@ -838,10 +908,39 @@ def _censor_masks(state: EngineState, candidate: Tree, cfg: EngineConfig,
     return jnp.max(gmask, axis=-1), gmask
 
 
+def _solves_rows(solver: LocalSolver, batch: Any) -> bool:
+    """Whether a phase may solve its acting rows alone: an
+    :class:`InexactSolver` given a batch carries each worker's data in that
+    batch's rows (its row-wise ``grad_fn`` contract). An
+    :class:`ExactSolver`'s problem, or a ``grad_fn`` called without a
+    batch, may hold N rows of data of its own."""
+    return batch is not None and isinstance(solver, InexactSolver)
+
+
+def _quantize(quant: GroupQuantState, theta: Tree, key: jax.Array,
+              cfg: EngineConfig, group_ids: Sequence[int],
+              rows: Optional[np.ndarray] = None,
+              n_workers: Optional[int] = None):
+    if cfg.quantize is not None:
+        return grouped_quantize_step(quant, theta, key, cfg.quantize,
+                                     group_ids,
+                                     use_kernel=cfg.use_pallas_quant,
+                                     rows=rows, n_workers=n_workers)
+    return identity_quantize_step(quant, theta)
+
+
+def _count_rows(part: str, rows: Optional[np.ndarray], n: int) -> None:
+    if rows is None:
+        engine_phase_rows_counter().inc(n, part=part, path="masked")
+    else:
+        engine_phase_rows_counter().inc(len(rows), part=part, path="rows")
+
+
 def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
            topo: topo_lib.Topology, rho_d: jax.Array, cfg: EngineConfig,
            key: jax.Array, batch: Any,
            participation: Optional[jax.Array] = None,
+           rows: Optional[np.ndarray] = None,
            ) -> Tuple[EngineState, jax.Array, jax.Array, jax.Array,
                       jax.Array, jax.Array, jax.Array, jax.Array]:
     """One group's primal update + (grouped quantize) + (censor) + commit.
@@ -879,8 +978,16 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
     these at delivery time. With ``participation=None`` (the synchronous
     golden path) ``censor_mask == tx_mask`` and
     ``offered_payload == payload_bits``, bit-for-bit.
+
+    ``rows`` (static; the nonzero rows of ``phase_mask``) selects the
+    acting-rows path: quantize, and solve where the solver's data rides in
+    ``batch`` (:func:`_solves_rows`), take those rows, compute, and write
+    them back into the N-row state; ``None`` computes all N rows and keeps
+    ``phase_mask``'s. Both give the same numbers.
     """
     group_ids = resolve_groups(state.theta, cfg.groups)
+    n = phase_mask.shape[0]
+    solve_rows = rows if _solves_rows(solver, batch) else None
     n_groups = max(group_ids) + 1
     rho = cfg.rho
     with jax.named_scope("admm.mix"):
@@ -903,21 +1010,42 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
                                        neigh)
             quad = 2.0 * rho_d
 
+    _count_rows("solve", solve_rows, n)
     with jax.named_scope("admm.solve"):
-        theta_full, mu_full, nu_full = solver.solve(
-            state.theta, v, quad, state.opt_mu, state.opt_nu, batch)
-        theta = tree_where_worker(phase_mask, theta_full, state.theta)
-        mu = tree_where_worker(phase_mask, mu_full, state.opt_mu)
-        nu = tree_where_worker(phase_mask, nu_full, state.opt_nu)
-
-    with jax.named_scope("admm.quantize"):
-        if cfg.quantize is not None:
-            quant_new, candidate, bits, payload = grouped_quantize_step(
-                state.quant, theta, key, cfg.quantize, group_ids,
-                use_kernel=cfg.use_pallas_quant)
+        if solve_rows is None:
+            theta_full, mu_full, nu_full = solver.solve(
+                state.theta, v, quad, state.opt_mu, state.opt_nu, batch)
+            theta = tree_where_worker(phase_mask, theta_full, state.theta)
+            mu = tree_where_worker(phase_mask, mu_full, state.opt_mu)
+            nu = tree_where_worker(phase_mask, nu_full, state.opt_nu)
         else:
-            quant_new, candidate, bits, payload = identity_quantize_step(
-                state.quant, theta)
+            def rows_of(a):
+                return take_rows(a, solve_rows)
+            theta_r, mu_r, nu_r = solver.solve(
+                rows_of(state.theta), rows_of(v), rows_of(quad),
+                rows_of(state.opt_mu), rows_of(state.opt_nu), rows_of(batch))
+            theta = put_rows(state.theta, solve_rows, theta_r)
+            mu = put_rows(state.opt_mu, solve_rows, mu_r)
+            nu = put_rows(state.opt_nu, solve_rows, nu_r)
+
+    _count_rows("quantize", rows, n)
+    with jax.named_scope("admm.quantize"):
+        if rows is None:
+            quant_new, candidate, bits, payload = _quantize(
+                state.quant, theta, key, cfg, group_ids)
+        else:
+            quant_r, _, bits_r, payload_r = _quantize(
+                take_rows(state.quant, rows), take_rows(theta, rows), key,
+                cfg, group_ids, rows, n)
+            quant_new = put_rows(state.quant, rows, quant_r)
+            # the masked path's candidate on the acting rows; the others
+            # are masked out by the censor step, as there
+            candidate = quant_new.q_hat if cfg.quantize is not None \
+                else theta
+            bits = put_rows(jnp.zeros_like(state.quant.bits_prev), rows,
+                            bits_r)
+            payload = put_rows(jnp.zeros((n,), jnp.float32), rows,
+                               payload_r)
 
     with jax.named_scope("admm.censor"):
         tx_mask, payload_tx, candidate_payload, group_tx, censor_mask, \
@@ -928,8 +1056,13 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
         phase_bits = bits * pm_col
 
     with jax.named_scope("admm.commit"):
+        if rows is None:
+            # quantizer replicas advance for the acting phase's workers
+            # only (they ran Eq. (20) this phase; censoring does not roll
+            # the chain back)
+            quant_new = tree_where_worker(phase_mask, quant_new, state.quant)
         new_state = _commit(state, candidate, quant_new, group_tx,
-                            phase_mask, pm_col, group_ids, theta, mu, nu)
+                            group_ids, theta, mu, nu)
     return (new_state, tx_mask, payload_tx, candidate_payload, phase_bits,
             group_tx, censor_mask, offered_payload)
 
@@ -979,13 +1112,13 @@ def _censor_and_price(state: EngineState, candidate: Tree, theta: Tree,
 
 
 def _commit(state: EngineState, candidate: Tree,
-            quant_new: GroupQuantState, group_tx: jax.Array,
-            phase_mask: jax.Array, pm_col: jax.Array,
+            quant: GroupQuantState, group_tx: jax.Array,
             group_ids: Sequence[int], theta: Tree, mu: Tree,
             nu: Tree) -> EngineState:
     """The phase's new state: ``theta_hat`` committed where each leaf's
-    group transmitted, the quantizer replicas advanced for the acting
-    workers (``pm_col``: ``phase_mask[:, None]``)."""
+    group transmitted, beside the phase's merged primal, quantizer and
+    optimizer state. Reads no field of ``state`` that the phase replaced,
+    so the acting-rows path updates those buffers in place."""
     # theta_hat: each leaf commits where its group transmitted
     hat_leaves, treedef = jax.tree_util.tree_flatten(state.theta_hat)
     cand_leaves = jax.tree_util.tree_leaves(candidate)
@@ -995,26 +1128,26 @@ def _commit(state: EngineState, candidate: Tree,
             (h.shape[0],) + (1,) * (h.ndim - 1))
         new_hat.append(jnp.where(m > 0, c.astype(h.dtype), h))
     theta_hat = jax.tree_util.tree_unflatten(treedef, new_hat)
-
-    # quantizer replicas advance for the acting phase's workers only (they
-    # ran Eq. (20) this phase; censoring does not roll the chain back).
-    quant = GroupQuantState(
-        q_hat=tree_where_worker(phase_mask, quant_new.q_hat,
-                                state.quant.q_hat),
-        range_prev=jnp.where(pm_col > 0, quant_new.range_prev,
-                             state.quant.range_prev),
-        bits_prev=jnp.where(pm_col > 0, quant_new.bits_prev,
-                            state.quant.bits_prev),
-        delta_prev=jnp.where(pm_col > 0, quant_new.delta_prev,
-                             state.quant.delta_prev),
-        initialized=jnp.where(pm_col > 0, quant_new.initialized,
-                              state.quant.initialized),
-    )
     return dataclasses.replace(state, theta=theta, theta_hat=theta_hat,
                                quant=quant, opt_mu=mu, opt_nu=nu)
 
 
 MetricsFn = Callable[[EngineState, Any], Dict[str, jax.Array]]
+
+
+def _phase_rows(graph: WorkerGraph, cfg: EngineConfig,
+                topo: topo_lib.Topology, mesh: Any
+                ) -> Tuple[Optional[np.ndarray], Optional[np.ndarray]]:
+    """The head and tail phases' acting rows (static), or ``None`` for a
+    phase that keeps the masked path: every phase of a Jacobian step, and
+    every phase when the workers are spread over devices (a ``mesh`` or
+    the sharded backend), where a row gather would move parameters between
+    them."""
+    if not cfg.alternating or mesh is not None or topo.backend == "sharded":
+        return None, None
+    is_head = np.asarray(graph.head_mask, bool)
+    return tuple(np.flatnonzero(m) if m.any() else None
+                 for m in (is_head, ~is_head))
 
 
 def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
@@ -1053,6 +1186,7 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
     head = jnp.asarray(graph.head_mask, jnp.float32)
     tail = 1.0 - head
     rho_d = cfg.rho * topo.degrees
+    head_rows, tail_rows = _phase_rows(graph, cfg, topo, mesh)
 
     def step(state: EngineState, batch, key: jax.Array,
              participation: Optional[jax.Array] = None):
@@ -1060,10 +1194,10 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
         if cfg.alternating:
             state, tx_h, pay_h, cand_h, bits_h, gtx_h, cm_h, off_h = _phase(
                 state, head, solver, topo, rho_d, cfg, k1, batch,
-                participation=participation)
+                participation=participation, rows=head_rows)
             state, tx_t, pay_t, cand_t, bits_t, gtx_t, cm_t, off_t = _phase(
                 state, tail, solver, topo, rho_d, cfg, k2, batch,
-                participation=participation)
+                participation=participation, rows=tail_rows)
             tx_mask = tx_h + tx_t
             payload = pay_h + pay_t
             candidate_payload = cand_h + cand_t

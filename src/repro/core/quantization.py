@@ -27,6 +27,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.extend.random import threefry2x32_p
 
 _EPS = 1e-12
 
@@ -113,6 +115,64 @@ def bit_schedule(bits_prev: jax.Array, range_new: jax.Array,
     delta = 2.0 * range_new / jnp.maximum(levels, 1.0)
     degen = range_new <= _EPS
     return bits, delta, degen
+
+
+def _threefry_key_data(key: jax.Array) -> Optional[jax.Array]:
+    """The uint32[2] data of a single threefry key (typed or raw), or None
+    when the key draws with another generator."""
+    if jax.dtypes.issubdtype(key.dtype, jax.dtypes.prng_key):
+        if str(jax.random.key_impl(key)) != "threefry2x32":
+            return None
+        return jax.random.key_data(key)
+    if jax.config.jax_default_prng_impl != "threefry2x32":
+        return None
+    return key
+
+
+def uniform_rows(key: jax.Array, n: int, d: int,
+                 rows: np.ndarray) -> jax.Array:
+    """Rows ``rows`` of ``jax.random.uniform(key, (n, d), float32)``,
+    bit for bit, computing only those rows: ``(len(rows), d)``.
+
+    Partitionable threefry (``jax_threefry_partitionable``, the default)
+    draws element ``(r, j)`` of an ``(n, d)`` array from the 64-bit counter
+    ``r * d + j`` alone, so each run of consecutive rows is one range of
+    counters, evaluated on its own and turned into floats exactly as
+    ``jax.random.uniform`` does. Any other generator falls back to the full
+    draw and a row slice."""
+    rows = np.asarray(rows, np.int64)
+    data = _threefry_key_data(key)
+    if data is None or not jax.config.jax_threefry_partitionable:
+        return jax.random.uniform(key, (n, d), jnp.float32)[rows]
+    runs = np.split(rows, np.flatnonzero(np.diff(rows) != 1) + 1)
+    parts = [_uniform_counters(data, int(run[0]) * d, run.size * d)
+             .reshape(run.size, d) for run in runs]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
+_LANES = 128
+_TILE = 8 * _LANES
+
+
+def _uniform_counters(data: jax.Array, start: int, count: int) -> jax.Array:
+    """Uniforms of threefry key ``data`` at the counters ``start ..
+    start + count - 1``, flat. They are computed on an ``(m, 128)`` block
+    with ``m`` a multiple of 8, whole (8, 128) tiles: a TPU lays out a
+    ``(1, d)`` row one sublane in eight, and the draw's arithmetic would
+    run at an eighth of the vector unit's width there."""
+    m = -(-count // _TILE) * 8
+    if m * _LANES >= 2 ** 32:
+        raise ValueError(f"{count} counters exceed one 32-bit offset")
+    offset = (jax.lax.broadcasted_iota(jnp.uint32, (m, _LANES), 0)
+              * jnp.uint32(_LANES)
+              + jax.lax.broadcasted_iota(jnp.uint32, (m, _LANES), 1))
+    lo0 = jnp.uint32(start & 0xFFFFFFFF)
+    lo = lo0 + offset
+    hi = jnp.uint32(start >> 32) + (lo < lo0).astype(jnp.uint32)  # carry
+    bits1, bits2 = threefry2x32_p.bind(data[0], data[1], hi, lo)
+    mant = ((bits1 ^ bits2) >> jnp.uint32(32 - 23)) | jnp.uint32(0x3F800000)
+    floats = jax.lax.bitcast_convert_type(mant, jnp.float32) - 1.0
+    return jnp.maximum(0.0, floats).reshape(-1)[:count]
 
 
 def stochastic_round(c: jax.Array, uniforms: jax.Array) -> jax.Array:

@@ -318,6 +318,18 @@ def kernel_dispatch_counter() -> Counter:
     )
 
 
+def engine_phase_rows_counter() -> Counter:
+    """Rows each traced phase of the consensus engine computes, by
+    ``part`` (``solve``, ``quantize``) and ``path`` (``rows``: the acting
+    group's rows alone; ``masked``: all N rows, the acting ones kept).
+    Bumped in ``core/engine.py`` ``_phase`` once per trace of a phase,
+    never inside a compiled program."""
+    return REGISTRY.counter(
+        "engine_phase_rows", labels=("part", "path"),
+        help="rows a traced engine phase computes, by part and path",
+    )
+
+
 # jax.monitoring time-span events -> the compile counter's ``stage``.
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",

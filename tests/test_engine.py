@@ -408,3 +408,131 @@ def test_engine_pytree_kernels_match_plain():
     np.testing.assert_allclose(outs[True][0], outs[False][0],
                                rtol=1e-5, atol=1e-5)
     assert outs[True][1] == outs[False][1]
+
+
+# -------------------------------------------------------- acting rows ----
+def _rowwise_regression(n=6):
+    """Per-worker linear regression whose data rides in ``batch`` (row-wise
+    ``grad_fn``, a vmap over workers): two leaves, each its own group."""
+    kx, kw = jax.random.split(jax.random.PRNGKey(4))
+    x = jax.random.normal(kx, (n, 16, 5))
+    w_true = jax.random.normal(kw, (n, 5, 3))
+    batch = {"x": x, "y": x @ w_true + 0.5}
+
+    def loss(p, b):
+        return jnp.mean(jnp.square(b["x"] @ p["w"] + p["b"] - b["y"]))
+
+    def grad_fn(theta, b):
+        return jax.vmap(jax.grad(loss))(theta, b)
+
+    theta0 = {"w": jnp.zeros((n, 5, 3)), "b": jnp.zeros((n, 3))}
+    return theta0, batch, grad_fn
+
+
+def _masked_path(monkeypatch):
+    monkeypatch.setattr(E, "_phase_rows", lambda *a: (None, None))
+
+
+@pytest.mark.parametrize("participation", [False, True],
+                         ids=["synchronous", "participation"])
+def test_acting_rows_match_masked_path(monkeypatch, participation):
+    """An alternating step that solves and quantizes only each phase's
+    acting rows (non-contiguous heads and tails, Adam, data in the batch)
+    matches the masked path: identical transmit decisions, payloads, bit
+    widths and quantizer replicas; states equal to rounding."""
+    n = 6
+    g = random_bipartite_graph(n, 0.5, seed=0)
+    heads = np.flatnonzero(g.head_mask)
+    assert np.any(np.diff(heads) > 1)
+    assert np.any(np.diff(np.flatnonzero(~g.head_mask)) > 1)
+    theta0, batch, grad_fn = _rowwise_regression(n)
+    solver = E.InexactSolver(grad_fn=grad_fn, local_steps=3, local_lr=0.05)
+    cfg = E.EngineConfig(rho=0.5, censor=CensorConfig(tau0=0.05, xi=0.9),
+                         quantize=QuantConfig(b0=3, omega=0.99),
+                         groups="leaf", censor_mode="group")
+    part = (jnp.asarray([1, 0, 1, 1, 1, 0], jnp.float32)
+            if participation else None)
+
+    def run():
+        step = jax.jit(E.make_step(g, cfg, solver))
+        state = E.init_state(theta0, cfg, solver)
+        hist = []
+        for i in range(5):
+            args = (state, batch, jax.random.PRNGKey(i))
+            state, m = step(*args, part) if participation else step(*args)
+            hist.append(m)
+        return state, hist
+
+    s_rows, m_rows = run()
+    _masked_path(monkeypatch)
+    s_mask, m_mask = run()
+    for a, b in zip(m_rows, m_mask):
+        for name in ("tx_mask", "payload_bits", "bits_per_group",
+                     "censor_mask", "offered_payload_bits", "group_tx"):
+            np.testing.assert_array_equal(np.asarray(a[name]),
+                                          np.asarray(b[name]), err_msg=name)
+    assert sum(float(m["tx_mask"].sum()) for m in m_rows) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(s_rows.quant),
+                    jax.tree_util.tree_leaves(s_mask.quant)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for name in ("theta", "alpha", "opt_mu", "opt_nu", "theta_hat"):
+        for a, b in zip(jax.tree_util.tree_leaves(getattr(s_rows, name)),
+                        jax.tree_util.tree_leaves(getattr(s_mask, name))):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-7, err_msg=name)
+
+
+def _phase_rows_read(step, state, batch):
+    from repro.obs import metrics as obs_metrics
+    c = obs_metrics.engine_phase_rows_counter()
+    keys = [(part, path) for part in ("solve", "quantize")
+            for path in ("rows", "masked")]
+    before = {k: c.value(part=k[0], path=k[1]) for k in keys}
+    jax.eval_shape(step, state, batch, jax.random.PRNGKey(0))
+    return {k: c.value(part=k[0], path=k[1]) - before[k] for k in keys}
+
+
+# (id, solver, batch?, alternating, backend, expected rows per part/path)
+PHASE_ROWS = [
+    ("cells", "inexact", True, True, "dense",
+     {("solve", "rows"): 2, ("quantize", "rows"): 2}),
+    ("no-batch", "inexact", False, True, "dense",
+     {("solve", "masked"): 4, ("quantize", "rows"): 2}),
+    ("exact", "exact", False, True, "dense",
+     {("solve", "masked"): 4, ("quantize", "rows"): 2}),
+    ("jacobian", "inexact", True, False, "dense",
+     {("solve", "masked"): 2, ("quantize", "masked"): 2}),
+    ("sharded", "inexact", True, True, "sharded",
+     {("solve", "masked"): 4, ("quantize", "masked"): 4}),
+]
+
+
+@pytest.mark.parametrize("solver_kind,with_batch,alternating,backend,want",
+                         [c[1:] for c in PHASE_ROWS],
+                         ids=[c[0] for c in PHASE_ROWS])
+def test_phase_rows_counter(solver_kind, with_batch, alternating, backend,
+                            want):
+    """``engine_phase_rows`` counts the rows each traced phase computes:
+    the benchmark cells' shape (2 workers, one head, an inexact solver fed
+    a batch) reads ``rows`` 1 + 1 for both parts; a solve without a batch
+    or with an exact solver, the Jacobian step and the sharded backend
+    read ``masked``, N rows a phase."""
+    from repro.core.graph import pod_pair_graph
+    from repro.core.solvers import LinearRegressionProblem as LRP
+    n = 2
+    theta0, batch, grad_fn = _rowwise_regression(n)
+    if solver_kind == "exact":
+        x = np.asarray(jax.random.normal(jax.random.PRNGKey(1), (n, 8, 4)))
+        solver = E.ExactSolver(LRP(jnp.asarray(x), jnp.asarray(x[..., 0])))
+        theta0 = jnp.zeros((n, 4))
+    elif with_batch:
+        solver = E.InexactSolver(grad_fn=grad_fn, local_steps=2)
+    else:                    # a grad_fn that closes over N rows of data
+        solver = E.InexactSolver(
+            grad_fn=lambda theta, _: grad_fn(theta, batch), local_steps=2)
+    cfg = E.EngineConfig(rho=0.5, quantize=QuantConfig(b0=3, omega=0.99),
+                         alternating=alternating, mix_backend=backend)
+    step = E.make_step(pod_pair_graph(), cfg, solver)
+    state = E.init_state(theta0, cfg, solver)
+    got = _phase_rows_read(step, state, batch if with_batch else None)
+    assert got == {k: want.get(k, 0) for k in got}

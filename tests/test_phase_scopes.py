@@ -3,7 +3,8 @@
 * Phase scopes: the compiled program of a quantized, censored engine step
   names its instructions by the part of the round they belong to — all
   seven ``admm.*`` scopes reach the compiled HLO's ``op_name`` metadata,
-  on the alternating path and on the Jacobian path.
+  on the alternating path, on the Jacobian path, and where each phase
+  solves and quantizes only its acting rows.
 * Program spans: ``obs.trace.span`` puts ``repro.<subsystem>.<name>`` on
   the profiler's host plane whether or not the JSON tracer is on, and a
   balanced span in the JSON trace when it is; the trainer's input path
@@ -35,12 +36,22 @@ SCOPES = ("admm.mix", "admm.solve", "admm.quantize", "admm.censor",
           "admm.commit", "admm.dual", "admm.metrics")
 
 
-def _step_and_state(alternating: bool):
+def _rowwise_solver():
+    """An inexact solver whose data rides in the batch (row-wise grad)."""
+    def loss(p, b):
+        pred = b["x"][:, :DIM - 4] @ p["w"] + b["x"][:, DIM - 4:] @ p["b"]
+        return jnp.mean(jnp.square(pred - b["y"]))
+
+    return E.InexactSolver(grad_fn=lambda theta, b: jax.vmap(
+        jax.grad(loss))(theta, b), local_steps=2)
+
+
+def _step_and_state(alternating: bool, batched: bool = False):
     data = R.synth_linear(n=N * 30, d=DIM, seed=0)
     g = random_bipartite_graph(N, 0.4, seed=0)
     x, y = R.partition_uniform(data, N)
-    solver = E.ExactSolver(LinearRegressionProblem(jnp.asarray(x),
-                                                   jnp.asarray(y)))
+    solver = _rowwise_solver() if batched else E.ExactSolver(
+        LinearRegressionProblem(jnp.asarray(x), jnp.asarray(y)))
     cfg = E.EngineConfig(rho=1.0, censor=CensorConfig(tau0=0.5, xi=0.97),
                          quantize=QuantConfig(b0=2, omega=0.99),
                          groups="leaf", censor_mode="group",
@@ -49,14 +60,18 @@ def _step_and_state(alternating: bool):
               "b": jnp.zeros((N, 4), jnp.float32)}
     step = jax.jit(E.make_step(g, cfg, solver,
                                extra_metrics=E.consensus_metrics()))
-    return step, E.init_state(theta0, cfg, solver)
+    batch = {"x": jnp.asarray(x), "y": jnp.asarray(y)} if batched else None
+    return step, E.init_state(theta0, cfg, solver), batch
 
 
-@pytest.mark.parametrize("alternating", [True, False],
-                         ids=["alternating", "jacobian"])
-def test_compiled_step_carries_every_phase_scope(alternating):
-    step, state = _step_and_state(alternating)
-    text = step.lower(state, None, jax.random.PRNGKey(0)).compile().as_text()
+@pytest.mark.parametrize("alternating,batched",
+                         [(True, False), (False, False), (True, True)],
+                         ids=["alternating", "jacobian", "acting-rows"])
+def test_compiled_step_carries_every_phase_scope(alternating, batched):
+    """``acting-rows``: the solve and the quantize take only each phase's
+    rows (an inexact solver fed a batch) and keep their scopes."""
+    step, state, batch = _step_and_state(alternating, batched)
+    text = step.lower(state, batch, jax.random.PRNGKey(0)).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', text)
     found = {m for n in names for m in re.findall(r"admm\.[a-z]+", n)}
     assert found == set(SCOPES)

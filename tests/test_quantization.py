@@ -4,6 +4,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 try:
     from hypothesis import given, settings, strategies as st
 except ImportError:        # offline: property tests skip, rest runs
@@ -11,7 +12,8 @@ except ImportError:        # offline: property tests skip, rest runs
 
 from repro.core.quantization import (QuantConfig, QuantizerState,
                                      identity_quantize_step, quantize_step,
-                                     required_bits, stochastic_round)
+                                     required_bits, stochastic_round,
+                                     uniform_rows)
 
 
 def _state(n, d, b0=2):
@@ -133,3 +135,56 @@ def test_degenerate_zero_diff_keeps_state():
     np.testing.assert_array_equal(np.asarray(q_hat), 0.0)
     np.testing.assert_array_equal(np.asarray(new_state.range_prev),
                                   np.asarray(state.range_prev))
+
+
+# Rows of the stochastic rounding's draw (the engine's acting-rows path):
+# (id, worker-leading shape, rows, key, partitionable threefry).
+_RAW = jax.random.PRNGKey(11)
+ROW_DRAWS = [
+    ("odd-width", (3, 1001), [1], _RAW, True),
+    ("even-width-contiguous", (4, 64), [1, 2], _RAW, True),
+    ("non-contiguous", (5, 37), [0, 2, 4], _RAW, True),
+    ("single-leaf", (6, 12, 12), [3], _RAW, True),
+    # the packed width of {"w": (6, 12, 12), "b": (6, 256)}
+    ("packed-multi-leaf", (6, 12 * 12 + 256), [0, 3, 5], _RAW, True),
+    ("typed-threefry-key", (4, 33), [2, 3], jax.random.key(5), True),
+    ("fallback-rbg-key", (4, 33), [0, 2], jax.random.key(5, impl="rbg"),
+     True),
+    ("fallback-not-partitionable", (4, 33), [1, 3], _RAW, False),
+]
+
+
+@pytest.mark.parametrize("shape,rows,key,partitionable",
+                         [c[1:] for c in ROW_DRAWS],
+                         ids=[c[0] for c in ROW_DRAWS])
+def test_row_draw_equals_rows_of_full_draw(shape, rows, key, partitionable):
+    """The engine's row draw is, bit for bit, those rows of the draw over
+    all workers — jitted, as the engine runs it."""
+    from repro.core import engine as E
+    rows = np.asarray(rows)
+    with jax.threefry_partitionable(partitionable):
+        got = jax.jit(lambda k: E._uniforms(
+            k, (rows.size,) + shape[1:], rows, shape[0]))(key)
+        want = jax.random.uniform(key, shape, jnp.float32)[rows]
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
+@pytest.mark.parametrize("row,d", [(4165826, 1031), (4194304, 1031)],
+                         ids=["carry-inside-row", "high-word-set"])
+def test_row_draw_counters_cross_32_bits(row, d):
+    """Where ``row * d + j`` reaches 2**32 the counter's high word is set,
+    as partitionable threefry's 64-bit iota sets it (first row: the carry
+    happens inside the row)."""
+    from jax.extend.random import threefry2x32_p
+    counters = np.arange(row * d, row * d + d, dtype=np.uint64)
+    assert counters[-1] >= 2 ** 32
+    key = jax.random.PRNGKey(3)
+    b1, b2 = threefry2x32_p.bind(
+        key[0], key[1], jnp.asarray((counters >> 32).astype(np.uint32)),
+        jnp.asarray((counters & 0xFFFFFFFF).astype(np.uint32)))
+    mant = ((np.asarray(b1) ^ np.asarray(b2)) >> 9) | 0x3F800000
+    want = mant.astype(np.uint32).view(np.float32) - np.float32(1.0)
+    got = uniform_rows(key, row + 1, d, np.array([row]))[0]
+    np.testing.assert_array_equal(np.asarray(got), want)
