@@ -1,7 +1,8 @@
 """h2o-danube-1.8b [dense]: llama+mistral mix with sliding-window attention.
 
 24 layers, d_model=2560, 32 heads (GQA kv=8), d_ff=6912, vocab=32000,
-sliding window 4096. [arXiv:2401.16818]
+sliding window 4096, RoPE theta 10000, RMSNorm eps 1e-5, untied input and
+output embeddings (1.83B parameters). [arXiv:2401.16818]
 """
 from repro.configs.base import ModelConfig, register
 
@@ -11,7 +12,8 @@ def config() -> ModelConfig:
         name="h2o-danube-1.8b", arch_type="dense",
         num_layers=24, d_model=2560, num_heads=32, num_kv_heads=8,
         d_ff=6912, vocab_size=32000, block_unit=("swa",),
-        sliding_window=4096,
+        sliding_window=4096, rope_theta=10000.0, norm_eps=1e-5,
+        tie_embeddings=False,
         source="arXiv:2401.16818",
         long_context="native",   # base config is already windowed
     )
@@ -22,7 +24,8 @@ def smoke_config() -> ModelConfig:
         name="h2o-danube-smoke", arch_type="dense",
         num_layers=2, d_model=256, num_heads=8, num_kv_heads=2,
         d_ff=512, vocab_size=512, block_unit=("swa",), sliding_window=64,
-        source="arXiv:2401.16818", long_context="native",
+        norm_eps=1e-5, tie_embeddings=False, source="arXiv:2401.16818",
+        long_context="native",
     )
 
 

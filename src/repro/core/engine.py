@@ -97,7 +97,8 @@ from repro.core import topology as topo_lib
 from repro.core.censoring import CensorConfig, threshold
 from repro.core.graph import WorkerGraph
 from repro.core.quantization import QuantConfig
-from repro.obs.metrics import engine_phase_rows_counter
+from repro.obs.metrics import (engine_phase_rows_counter,
+                               engine_worker_layout_counter)
 
 _EPS = 1e-12
 
@@ -380,8 +381,13 @@ def _finish_packed_step(state: GroupQuantState, pk, out, range_new, bits,
     """Shared tail of the packed paths: degenerate-group state carry,
     unpack, QSGD payload accounting. All (N, G)-sized — the (N, D) work is
     already done by the quantize call."""
+    return _finish_step(state, packing.unpack(pk, out, like=state.q_hat),
+                        range_new, bits, delta, cfg, pk.group_dims)
+
+
+def _finish_step(state: GroupQuantState, q_hat_new, range_new, bits, delta,
+                 cfg: QuantConfig, dims: Sequence[int]):
     degen = range_new <= _EPS                                     # (N, G)
-    q_hat_new = packing.unpack(pk, out, like=state.q_hat)
     new_state = GroupQuantState(
         q_hat=q_hat_new,
         range_prev=jnp.where(degen, state.range_prev, range_new),
@@ -389,9 +395,9 @@ def _finish_packed_step(state: GroupQuantState, pk, out, range_new, bits,
         delta_prev=jnp.where(degen, state.delta_prev, delta),
         initialized=jnp.ones_like(state.initialized),
     )
-    dims_arr = jnp.asarray(pk.group_dims, jnp.float32)
+    dims_arr = jnp.asarray(dims, jnp.float32)
     payload = jnp.sum(bits * dims_arr[None, :], axis=-1) \
-        + float(pk.n_groups * cfg.b_overhead)
+        + float(len(dims) * cfg.b_overhead)
     return new_state, q_hat_new, bits, payload
 
 
@@ -426,6 +432,113 @@ def _grouped_quantize_step_packed(
         state.initialized, gid_cols, group_runs=pk.group_runs,
         omega=cfg.omega, b0=cfg.b0, b_max=cfg.b_max)
     return _finish_packed_step(state, pk, out, range_new, bits, delta, cfg)
+
+
+def _over_worker(reduce_fn, x: jax.Array) -> jax.Array:
+    """``reduce_fn`` over every axis of ``x`` but the leading worker axis:
+    (N,)."""
+    return reduce_fn(x, axis=tuple(range(1, x.ndim)))
+
+
+def _on_blocks(topo: topo_lib.ShardedTopology, fn, in_specs, out_specs,
+               *args):
+    """``fn`` on each device's own blocks of ``args``, laid out as
+    ``in_specs``: what crosses devices is what ``fn`` reduces itself over
+    ``topo.rest`` (the axes a worker spans)."""
+    return jax.shard_map(fn, mesh=topo.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _block_starts(spec, local_shape: Tuple[int, ...], mesh) -> list:
+    """Where a device's block of a leaf laid out as ``spec`` starts, per
+    axis (inside ``shard_map``)."""
+    starts = []
+    for k, size in enumerate(local_shape):
+        ax = spec[k] if k < len(spec) else None
+        idx = 0
+        for a in (() if ax is None else ax if isinstance(ax, tuple)
+                  else (ax,)):
+            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
+        starts.append(idx * size)
+    return starts
+
+
+def _quant_specs(topo: topo_lib.ShardedTopology):
+    from jax.sharding import PartitionSpec
+    rows = PartitionSpec(topo.axis)
+    return GroupQuantState(q_hat=topo.leaf_specs, range_prev=rows,
+                           bits_prev=rows, delta_prev=rows,
+                           initialized=rows)
+
+
+def grouped_quantize_step_split(
+    state: GroupQuantState, theta: Tree, key: jax.Array, cfg: QuantConfig,
+    group_ids: Sequence[int], topo: topo_lib.ShardedTopology,
+) -> Tuple[GroupQuantState, Tree, jax.Array, jax.Array]:
+    """The packed round (:func:`_grouped_quantize_step_packed`) for workers
+    whose leaves are split over several devices (``topo.leaf_specs``), on
+    each device's own blocks, leaf by leaf: packing the leaves into one
+    row would gather each worker's whole row onto every one of its
+    devices. Each block draws the uniforms at its own positions of the
+    packed draw (:func:`quantization.uniform_columns`), so every parameter
+    is rounded with the number the packed path gives it; only the (N, G)
+    ranges reduce over the worker's devices."""
+    from jax.sharding import PartitionSpec
+    from repro.kernels.ref import stoch_quantize_ref
+    leaves = jax.tree_util.tree_leaves(theta)
+    n = leaves[0].shape[0]
+    shapes = [x.shape for x in leaves]
+    widths = [int(x.size // n) for x in leaves]
+    offsets = [int(o) for o in np.cumsum([0] + widths[:-1])]
+    dims = group_dims(theta, group_ids)
+    specs = jax.tree_util.tree_leaves(
+        topo.leaf_specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
+    f32 = jnp.float32
+
+    def local(theta_l, q_hat_l, bits_prev, range_prev, initialized, key):
+        t_leaves, treedef = jax.tree_util.tree_flatten(theta_l)
+        q_leaves = jax.tree_util.tree_leaves(q_hat_l)
+        range_new = _group_reduce(
+            [_over_worker(jnp.max, jnp.abs(t.astype(f32) - q.astype(f32)))
+             for t, q in zip(t_leaves, q_leaves)],
+            group_ids, len(dims), lambda s: jnp.max(s, axis=0))  # (n, G)
+        range_new = jax.lax.pmax(range_new, topo.rest)
+        bits, delta, degen = quant_lib.bit_schedule(
+            bits_prev, range_new, range_prev, initialized, cfg.omega,
+            cfg.b0, cfg.b_max)
+        out = []
+        for i, (t, q) in enumerate(zip(t_leaves, q_leaves)):
+            g, rows = group_ids[i], t.shape[0]
+            u = quant_lib.uniform_columns(
+                key, n, sum(widths), offsets[i], shapes[i],
+                block=(_block_starts(specs[i], t.shape, topo.mesh),
+                       t.shape))
+            q32 = q.astype(f32).reshape(rows, -1)
+            fresh = stoch_quantize_ref(
+                t.astype(f32).reshape(rows, -1), q32, u.reshape(rows, -1),
+                delta[:, g], range_new[:, g])
+            out.append(jnp.where(degen[:, g][:, None], q32, fresh)
+                       .reshape(q.shape).astype(q.dtype))
+        return (jax.tree_util.tree_unflatten(treedef, out), range_new, bits,
+                delta)
+
+    rows = PartitionSpec(topo.axis)
+    q_hat_new, range_new, bits, delta = _on_blocks(
+        topo, local,
+        (topo.leaf_specs, topo.leaf_specs, rows, rows, rows, PartitionSpec()),
+        (topo.leaf_specs, rows, rows, rows),
+        theta, state.q_hat, state.bits_prev, state.range_prev,
+        state.initialized, key)
+    return _finish_step(state, q_hat_new, range_new, bits, delta, cfg, dims)
+
+
+def _where_rows_on_blocks(topo: topo_lib.ShardedTopology, mask: jax.Array,
+                          a: Tree, b: Tree, specs) -> Tree:
+    """:func:`tree_where_worker` on each device's own blocks."""
+    from jax.sharding import PartitionSpec
+    return _on_blocks(topo, tree_where_worker,
+                      (PartitionSpec(topo.axis), specs, specs), specs,
+                      mask, a, b)
 
 
 def grouped_quantize_step_twopass(
@@ -877,20 +990,48 @@ def init_state(theta: Tree, cfg: EngineConfig,
 
 def _censor_masks(state: EngineState, candidate: Tree, cfg: EngineConfig,
                   group_ids: Sequence[int], n_groups: int,
-                  k_next: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Returns ``(worker_mask (N,), group_mask (N, G))`` censoring decisions."""
+                  k_next: jax.Array,
+                  split: Optional[topo_lib.ShardedTopology] = None
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """Returns ``(worker_mask (N,), group_mask (N, G))`` censoring decisions.
+    ``split`` (the topology of workers whose leaves are split over
+    devices): the norms sum on each device's own blocks, leaf by leaf, and
+    only the (N, G) sums cross devices; no row is packed."""
     leaves = jax.tree_util.tree_leaves(candidate)
     n = leaves[0].shape[0]
     if not cfg.censor.enabled:
         ones = jnp.ones((n,), jnp.float32)
         return ones, jnp.ones((n, n_groups), jnp.float32)
 
-    diff = jax.tree_util.tree_map(
-        lambda c, h: c.astype(jnp.float32) - h.astype(jnp.float32),
-        candidate, state.theta_hat)
+    def sub(c, h):
+        return c.astype(jnp.float32) - h.astype(jnp.float32)
+
+    diff = jax.tree_util.tree_map(sub, candidate, state.theta_hat)
+    tau = threshold(cfg.censor, k_next)
+    if split is not None:
+        from jax.sharding import PartitionSpec
+
+        def local(cand_l, hat_l):
+            sq = _group_reduce(
+                [_over_worker(jnp.sum, jnp.square(sub(c, h)))
+                 for c, h in zip(jax.tree_util.tree_leaves(cand_l),
+                                 jax.tree_util.tree_leaves(hat_l))],
+                group_ids, n_groups, lambda s: jnp.sum(s, axis=0))  # (n, G)
+            return jax.lax.psum(sq, split.rest)
+
+        sq = _on_blocks(split, local, (split.leaf_specs, split.leaf_specs),
+                        PartitionSpec(split.axis), candidate,
+                        state.theta_hat)
+        if cfg.censor_mode == "global":
+            cmask = (jnp.sqrt(jnp.sum(sq, axis=-1)) >= tau).astype(
+                jnp.float32)
+            return cmask, jnp.broadcast_to(cmask[:, None], (n, n_groups))
+        gmask = censor_lib.group_censor_mask(
+            jnp.sqrt(sq), censor_lib.group_thresholds(
+                tau, group_dims(candidate, group_ids), tree_dim(candidate)))
+        return jnp.max(gmask, axis=-1), gmask
     pk = packing.make_packing(diff, group_ids)
     diff_p = packing.pack(pk, diff)                       # (N, D) f32
-    tau = threshold(cfg.censor, k_next)
     if cfg.censor_mode == "global":
         # the packed view makes the multi-leaf norm identical to the seed
         # flat path's jnp.linalg.norm over the whole model vector
@@ -920,7 +1061,11 @@ def _solves_rows(solver: LocalSolver, batch: Any) -> bool:
 def _quantize(quant: GroupQuantState, theta: Tree, key: jax.Array,
               cfg: EngineConfig, group_ids: Sequence[int],
               rows: Optional[np.ndarray] = None,
-              n_workers: Optional[int] = None):
+              n_workers: Optional[int] = None,
+              split: Optional[topo_lib.ShardedTopology] = None):
+    if cfg.quantize is not None and split is not None:
+        return grouped_quantize_step_split(quant, theta, key, cfg.quantize,
+                                           group_ids, split)
     if cfg.quantize is not None:
         return grouped_quantize_step(quant, theta, key, cfg.quantize,
                                      group_ids,
@@ -941,6 +1086,7 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
            key: jax.Array, batch: Any,
            participation: Optional[jax.Array] = None,
            rows: Optional[np.ndarray] = None,
+           split: Optional[topo_lib.ShardedTopology] = None,
            ) -> Tuple[EngineState, jax.Array, jax.Array, jax.Array,
                       jax.Array, jax.Array, jax.Array, jax.Array]:
     """One group's primal update + (grouped quantize) + (censor) + commit.
@@ -984,6 +1130,11 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
     ``batch`` (:func:`_solves_rows`), take those rows, compute, and write
     them back into the N-row state; ``None`` computes all N rows and keeps
     ``phase_mask``'s. Both give the same numbers.
+
+    ``split`` (the topology of workers that span several devices each,
+    with ``rows`` ``None``): quantize, censor and commit on each device's
+    own blocks (:func:`grouped_quantize_step_split`), the same numbers
+    again.
     """
     group_ids = resolve_groups(state.theta, cfg.groups)
     n = phase_mask.shape[0]
@@ -1032,7 +1183,7 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
     with jax.named_scope("admm.quantize"):
         if rows is None:
             quant_new, candidate, bits, payload = _quantize(
-                state.quant, theta, key, cfg, group_ids)
+                state.quant, theta, key, cfg, group_ids, split=split)
         else:
             quant_r, _, bits_r, payload_r = _quantize(
                 take_rows(state.quant, rows), take_rows(theta, rows), key,
@@ -1051,7 +1202,7 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
         tx_mask, payload_tx, candidate_payload, group_tx, censor_mask, \
             offered_payload = _censor_and_price(
                 state, candidate, theta, bits, payload, phase_mask, cfg,
-                group_ids, n_groups, participation)
+                group_ids, n_groups, participation, split)
         pm_col = phase_mask[:, None]
         phase_bits = bits * pm_col
 
@@ -1060,9 +1211,15 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
             # quantizer replicas advance for the acting phase's workers
             # only (they ran Eq. (20) this phase; censoring does not roll
             # the chain back)
-            quant_new = tree_where_worker(phase_mask, quant_new, state.quant)
+            if split is None:
+                quant_new = tree_where_worker(phase_mask, quant_new,
+                                              state.quant)
+            else:
+                quant_new = _where_rows_on_blocks(
+                    split, phase_mask, quant_new, state.quant,
+                    _quant_specs(split))
         new_state = _commit(state, candidate, quant_new, group_tx,
-                            group_ids, theta, mu, nu)
+                            group_ids, theta, mu, nu, split)
     return (new_state, tx_mask, payload_tx, candidate_payload, phase_bits,
             group_tx, censor_mask, offered_payload)
 
@@ -1071,14 +1228,15 @@ def _censor_and_price(state: EngineState, candidate: Tree, theta: Tree,
                       bits: jax.Array, payload: jax.Array,
                       phase_mask: jax.Array, cfg: EngineConfig,
                       group_ids: Sequence[int], n_groups: int,
-                      participation: Optional[jax.Array]):
+                      participation: Optional[jax.Array],
+                      split: Optional[topo_lib.ShardedTopology] = None):
     """The censor test (Eq. 13/14), its composition with ``participation``
     and the phase, and the bits each decision puts on the wire. Returns
     ``(tx_mask, payload_tx, candidate_payload, group_tx, censor_mask,
     offered_payload)``."""
     k_next = (state.k + 1).astype(jnp.float32)
     cmask_cens, gmask_cens = _censor_masks(state, candidate, cfg, group_ids,
-                                           n_groups, k_next)
+                                           n_groups, k_next, split)
     if participation is not None:
         # timeout composes AFTER the censor test: tx = timeout & censor.
         # The censor decision itself (and the quantizer chain below) is
@@ -1114,20 +1272,32 @@ def _censor_and_price(state: EngineState, candidate: Tree, theta: Tree,
 def _commit(state: EngineState, candidate: Tree,
             quant: GroupQuantState, group_tx: jax.Array,
             group_ids: Sequence[int], theta: Tree, mu: Tree,
-            nu: Tree) -> EngineState:
+            nu: Tree, split: Optional[topo_lib.ShardedTopology] = None
+            ) -> EngineState:
     """The phase's new state: ``theta_hat`` committed where each leaf's
     group transmitted, beside the phase's merged primal, quantizer and
     optimizer state. Reads no field of ``state`` that the phase replaced,
-    so the acting-rows path updates those buffers in place."""
-    # theta_hat: each leaf commits where its group transmitted
-    hat_leaves, treedef = jax.tree_util.tree_flatten(state.theta_hat)
-    cand_leaves = jax.tree_util.tree_leaves(candidate)
-    new_hat = []
-    for i, (h, c) in enumerate(zip(hat_leaves, cand_leaves)):
-        m = group_tx[:, group_ids[i]].reshape(
-            (h.shape[0],) + (1,) * (h.ndim - 1))
-        new_hat.append(jnp.where(m > 0, c.astype(h.dtype), h))
-    theta_hat = jax.tree_util.tree_unflatten(treedef, new_hat)
+    so the acting-rows path updates those buffers in place. ``split``: the
+    commit runs on each device's own blocks."""
+    def commit_hat(group_tx, theta_hat, candidate):
+        # theta_hat: each leaf commits where its group transmitted
+        hat_leaves, treedef = jax.tree_util.tree_flatten(theta_hat)
+        cand_leaves = jax.tree_util.tree_leaves(candidate)
+        new_hat = []
+        for i, (h, c) in enumerate(zip(hat_leaves, cand_leaves)):
+            m = group_tx[:, group_ids[i]].reshape(
+                (h.shape[0],) + (1,) * (h.ndim - 1))
+            new_hat.append(jnp.where(m > 0, c.astype(h.dtype), h))
+        return jax.tree_util.tree_unflatten(treedef, new_hat)
+
+    if split is None:
+        theta_hat = commit_hat(group_tx, state.theta_hat, candidate)
+    else:
+        from jax.sharding import PartitionSpec
+        theta_hat = _on_blocks(
+            split, commit_hat,
+            (PartitionSpec(split.axis), split.leaf_specs, split.leaf_specs),
+            split.leaf_specs, group_tx, state.theta_hat, candidate)
     return dataclasses.replace(state, theta=theta, theta_hat=theta_hat,
                                quant=quant, opt_mu=mu, opt_nu=nu)
 
@@ -1153,7 +1323,8 @@ def _phase_rows(graph: WorkerGraph, cfg: EngineConfig,
 def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
               extra_metrics: Optional[MetricsFn] = None, *,
               mesh: Any = None, worker_axis: Optional[str] = None,
-              topology: Optional[topo_lib.Topology] = None):
+              topology: Optional[topo_lib.Topology] = None,
+              leaf_specs: Any = None):
     """Build the jittable per-iteration engine step.
 
     ``step(state, batch, key[, participation]) -> (state, metrics)``;
@@ -1179,10 +1350,19 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
     production ADMM bundle passes its SPMD mesh — other callers can leave
     them unset). A caller that already built a matching ``topology``
     (e.g. to share it with a metrics fn) can pass it instead.
+
+    Where the mesh has axes besides ``worker_axis`` (each worker spans
+    several devices, its leaves split over them as ``leaf_specs``, the
+    PartitionSpecs of the worker-stacked parameter tree, say), quantize
+    and censor run leaf by leaf in each leaf's own layout and the sharded
+    backend mixes each leaf in its layout: only (N, G) scalars cross a
+    worker's devices outside the local solve (DESIGN.md §Engine).
     """
     topo = topology if topology is not None else topo_lib.build(
         graph, cfg.mix_backend, use_pallas_mix=cfg.use_pallas_mix,
-        mesh=mesh, worker_axis=worker_axis)
+        mesh=mesh, worker_axis=worker_axis, leaf_specs=leaf_specs)
+    per_worker = topo_lib.devices_per_worker(mesh, worker_axis)
+    split = topo if getattr(topo, "leaf_specs", None) is not None else None
     head = jnp.asarray(graph.head_mask, jnp.float32)
     tail = 1.0 - head
     rho_d = cfg.rho * topo.degrees
@@ -1190,14 +1370,16 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
 
     def step(state: EngineState, batch, key: jax.Array,
              participation: Optional[jax.Array] = None):
+        engine_worker_layout_counter().inc(
+            1, workers=str(graph.n), chips_per_worker=str(per_worker))
         k1, k2 = jax.random.split(key)
         if cfg.alternating:
             state, tx_h, pay_h, cand_h, bits_h, gtx_h, cm_h, off_h = _phase(
                 state, head, solver, topo, rho_d, cfg, k1, batch,
-                participation=participation, rows=head_rows)
+                participation=participation, rows=head_rows, split=split)
             state, tx_t, pay_t, cand_t, bits_t, gtx_t, cm_t, off_t = _phase(
                 state, tail, solver, topo, rho_d, cfg, k2, batch,
-                participation=participation, rows=tail_rows)
+                participation=participation, rows=tail_rows, split=split)
             tx_mask = tx_h + tx_t
             payload = pay_h + pay_t
             candidate_payload = cand_h + cand_t
@@ -1210,7 +1392,7 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
             (state, tx_mask, payload, candidate_payload, bits_g, group_tx,
              censor_mask, offered_payload) = \
                 _phase(state, all_mask, solver, topo, rho_d, cfg, k1,
-                       batch, participation=participation)
+                       batch, participation=participation, split=split)
 
         # Dual update, Eq. (23): alpha += rho * (D - A) theta_hat. The
         # Laplacian goes through the same topology backend (and therefore
@@ -1271,8 +1453,13 @@ def flat_metrics(graph: WorkerGraph,
     return fn
 
 
-def consensus_metrics(loss_fn: Optional[Callable] = None) -> MetricsFn:
-    """Training diagnostics: deviation from the worker mean (+ loss)."""
+def consensus_metrics(loss_fn: Optional[Callable] = None, *,
+                      split: bool = False) -> MetricsFn:
+    """Training diagnostics: deviation from the worker mean (+ loss).
+    ``split``: the workers' leaves are split over devices, so the squared
+    deviations sum in each leaf's own shape, and the loss, a forward pass
+    with the model's own collectives, runs under its own scope
+    (``admm.metrics/loss``)."""
 
     def fn(state: EngineState, batch) -> Dict[str, jax.Array]:
         mean_theta = jax.tree_util.tree_map(
@@ -1280,8 +1467,16 @@ def consensus_metrics(loss_fn: Optional[Callable] = None) -> MetricsFn:
             state.theta)
         dev = jax.tree_util.tree_map(
             lambda x, m: x.astype(jnp.float32) - m, state.theta, mean_theta)
-        out = {"consensus_err": jnp.sum(tree_worker_sqnorm(dev))}
-        if loss_fn is not None:
+        if split:
+            err = sum(jnp.sum(jnp.square(x))
+                      for x in jax.tree_util.tree_leaves(dev))
+        else:
+            err = jnp.sum(tree_worker_sqnorm(dev))
+        out = {"consensus_err": err}
+        if loss_fn is not None and split:
+            with jax.named_scope("loss"):
+                out["loss"] = loss_fn(state.theta, batch)
+        elif loss_fn is not None:
             out["loss"] = loss_fn(state.theta, batch)
         return out
 

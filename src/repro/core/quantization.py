@@ -150,6 +150,61 @@ def uniform_rows(key: jax.Array, n: int, d: int,
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
+def uniform_columns(key: jax.Array, n: int, d: int, offset: int,
+                    shape: Tuple[int, ...], block=None) -> jax.Array:
+    """Columns ``offset .. offset + prod(shape[1:]) - 1`` of every row of
+    ``jax.random.uniform(key, (n, d), float32)``, bit for bit, drawn in
+    ``shape`` (``(n,) + leaf shape``, row-major over the leaf): a packed
+    leaf's own uniforms, computed without the packed row. With ``block =
+    (starts, local_shape)`` only the block of that shape at index
+    ``starts`` (one start per axis, axis 0 the rows; traced values allowed)
+    is drawn: a device's own part of a leaf split over devices.
+
+    Element ``idx`` of row ``r`` takes the counter ``r * d + offset +
+    flat(idx)`` (partitionable threefry, as :func:`uniform_rows`), made of
+    iotas in the block's shape. Any other generator falls back to the full
+    draw and a slice."""
+    starts, local = block if block is not None else ((0,) * len(shape),
+                                                     tuple(shape))
+    size = int(np.prod(shape[1:], dtype=np.int64))
+    data = _threefry_key_data(key)
+    if data is None or not jax.config.jax_threefry_partitionable:
+        full = jax.random.uniform(key, (n, d), jnp.float32)
+        leaf = full[:, offset:offset + size].reshape(shape)
+        return jax.lax.dynamic_slice(leaf, tuple(starts), tuple(local))
+    if size >= 2 ** 32:
+        raise ValueError(f"a leaf of {size} elements exceeds one 32-bit "
+                         f"counter range")
+    flat = jnp.zeros(local, jnp.uint32)
+    stride = 1
+    for axis in range(len(shape) - 1, 0, -1):
+        idx = jax.lax.broadcasted_iota(jnp.uint32, local, axis)
+        if block is not None:
+            idx = idx + jnp.asarray(starts[axis]).astype(jnp.uint32)
+        flat = flat + idx * jnp.uint32(stride)
+        stride *= int(shape[axis])
+    start = np.arange(n, dtype=np.uint64) * np.uint64(d) + np.uint64(offset)
+    lo_rows = jnp.asarray((start & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+    hi_rows = jnp.asarray((start >> np.uint64(32)).astype(np.uint32))
+    if block is not None:
+        lo_rows = jax.lax.dynamic_slice(lo_rows, (starts[0],), (local[0],))
+        hi_rows = jax.lax.dynamic_slice(hi_rows, (starts[0],), (local[0],))
+    col = (local[0],) + (1,) * (len(local) - 1)
+    lo0, hi0 = lo_rows.reshape(col), hi_rows.reshape(col)
+    lo = lo0 + flat
+    hi = hi0 + (lo < lo0).astype(jnp.uint32)                  # carry
+    return _counter_floats(data, hi, lo)
+
+
+def _counter_floats(data: jax.Array, hi: jax.Array,
+                    lo: jax.Array) -> jax.Array:
+    """``jax.random.uniform``'s floats at 64-bit counters ``(hi, lo)``."""
+    bits1, bits2 = threefry2x32_p.bind(data[0], data[1], hi, lo)
+    mant = ((bits1 ^ bits2) >> jnp.uint32(32 - 23)) | jnp.uint32(0x3F800000)
+    floats = jax.lax.bitcast_convert_type(mant, jnp.float32) - 1.0
+    return jnp.maximum(0.0, floats)
+
+
 _LANES = 128
 _TILE = 8 * _LANES
 
@@ -169,10 +224,7 @@ def _uniform_counters(data: jax.Array, start: int, count: int) -> jax.Array:
     lo0 = jnp.uint32(start & 0xFFFFFFFF)
     lo = lo0 + offset
     hi = jnp.uint32(start >> 32) + (lo < lo0).astype(jnp.uint32)  # carry
-    bits1, bits2 = threefry2x32_p.bind(data[0], data[1], hi, lo)
-    mant = ((bits1 ^ bits2) >> jnp.uint32(32 - 23)) | jnp.uint32(0x3F800000)
-    floats = jax.lax.bitcast_convert_type(mant, jnp.float32) - 1.0
-    return jnp.maximum(0.0, floats).reshape(-1)[:count]
+    return _counter_floats(data, hi, lo).reshape(-1)[:count]
 
 
 def stochastic_round(c: jax.Array, uniforms: jax.Array) -> jax.Array:

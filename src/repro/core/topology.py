@@ -138,7 +138,8 @@ class Topology:
         if self.backend == "sharded":
             # the mesh axis must still divide the new worker count;
             # build() re-validates and re-binds the same mesh axes
-            kwargs = {"mesh": self.mesh, "worker_axis": self.axis}
+            kwargs = {"mesh": self.mesh, "worker_axis": self.axis,
+                      "leaf_specs": self.leaf_specs}
         return build(graph, self.backend, use_pallas_mix=self.use_pallas,
                      **kwargs)
 
@@ -230,8 +231,33 @@ class ShardedTopology(DenseTopology):
     mesh: Any = None
     axis: str = "workers"
     rest: Tuple[str, ...] = ()      # non-worker mesh axes (feature split)
+    # PartitionSpecs of a worker-stacked parameter tree whose workers span
+    # several devices each: the mix then runs leaf by leaf in each leaf's
+    # own layout, so no device gathers more than its own share of a row
+    leaf_specs: Any = None
 
     backend = "sharded"
+
+    def mix(self, a: Tree) -> Tree:
+        if self.leaf_specs is None:
+            return super().mix(a)
+        return jax.tree_util.tree_map(self._mix_leaf, a, self.leaf_specs)
+
+    def _mix_leaf(self, x: jax.Array, spec) -> jax.Array:
+        """One leaf in its own layout: each device gathers its share of
+        every worker's leaf over the worker axis alone."""
+        from jax.sharding import PartitionSpec as P
+
+        def local(a_blk, v_blk):
+            v_all = jax.lax.all_gather(v_blk, self.axis, axis=0, tiled=True)
+            out = jnp.tensordot(a_blk.astype(v_all.dtype), v_all, axes=1,
+                                precision=jax.lax.Precision.HIGHEST)
+            return out.astype(v_blk.dtype)
+
+        return jax.shard_map(local, mesh=self.mesh,
+                             in_specs=(P(self.axis, None), spec),
+                             out_specs=spec, check_vma=False)(
+                                 self.adjacency, x)
 
     def _mix_flat(self, flat: jax.Array) -> jax.Array:
         from jax.sharding import PartitionSpec as P
@@ -272,6 +298,16 @@ def worker_mesh(devices):
     return make_mesh((len(devices),), ("workers",), devices=devices)
 
 
+def devices_per_worker(mesh, worker_axis: Optional[str]) -> int:
+    """How many devices each worker's rows span: the product of ``mesh``'s
+    axes other than ``worker_axis`` (1 without a mesh, and where several
+    workers share a device)."""
+    if mesh is None:
+        return 1
+    return int(np.prod([mesh.shape[a] for a in mesh.axis_names
+                        if a != worker_axis], dtype=np.int64))
+
+
 def worker_devices(n_workers: int, devices=None) -> list:
     """The devices ``n_workers`` workers are spread over: the most of
     ``devices`` (default: all local devices) that take equal blocks of
@@ -290,12 +326,15 @@ def _default_worker_mesh(n: int):
 
 def build(graph: WorkerGraph, backend: str = "dense", *,
           use_pallas_mix: bool = False,
-          mesh: Any = None, worker_axis: Optional[str] = None) -> Topology:
+          mesh: Any = None, worker_axis: Optional[str] = None,
+          leaf_specs: Any = None) -> Topology:
     """Build the selected topology backend from a worker graph.
 
     ``mesh``/``worker_axis`` are only consulted by the sharded backend
     (the production bundle passes its mesh; standalone callers get a
-    1-D mesh over the local devices)."""
+    1-D mesh over the local devices), and so are ``leaf_specs``, the
+    PartitionSpecs of the mixed parameter tree where each worker spans
+    several devices (``ShardedTopology.leaf_specs``)."""
     if backend not in BACKENDS:
         raise ValueError(f"unknown mix backend {backend!r}; "
                          f"expected one of {BACKENDS}")
@@ -331,7 +370,8 @@ def build(graph: WorkerGraph, backend: str = "dense", *,
     return ShardedTopology(n=graph.n, degrees=degrees,
                            use_pallas=use_pallas_mix,
                            adjacency=jnp.asarray(graph.adjacency),
-                           mesh=mesh, axis=worker_axis, rest=rest)
+                           mesh=mesh, axis=worker_axis, rest=rest,
+                           leaf_specs=leaf_specs)
 
 
 def mix_dense(adjacency: jax.Array, a: Tree,

@@ -3,7 +3,8 @@
 Policy (v5e-style 2D/3D meshes):
   * Tensor parallelism over ``model``: column-parallel input projections
     (q/k/v, wi_*, up, in_proj, wx), row-parallel output projections
-    (o, wo, down, out_proj); vocab-sharded embedding table.
+    (o, wo, down, out_proj); vocab-sharded embedding table and untied
+    output head (lm_head).
   * MoE: expert-parallel over ``model`` when num_experts divides the axis,
     else ff-dim TP inside each expert.
   * ADMM consensus training adds a leading worker axis on every parameter,
@@ -24,7 +25,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 COL_PARALLEL = ("q", "k", "v", "wi_gate", "wi_up", "up", "wx", "in_proj",
-                "igate", "fgate", "router")
+                "igate", "fgate", "router", "lm_head")
 ROW_PARALLEL = ("o", "wo", "down", "out_proj")
 
 

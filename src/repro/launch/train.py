@@ -4,7 +4,11 @@ CPU-friendly end-to-end entry point: trains a reduced or full architecture
 on the synthetic-but-learnable token stream, with the paper's censoring and
 quantization live, logging loss / consensus error / transmitted bits, and
 checkpointing. On real hardware the same bundle runs against the production
-mesh (see dryrun.py); here the mesh is whatever the host offers.
+mesh (see dryrun.py). Here the workers are placed on the devices the
+process sees (``worker_placement``): several workers to a device, one
+worker per device, or, with more devices than workers, each worker's
+parameters split over several devices by the tensor-parallel rules of
+``launch/sharding.py``.
 
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --smoke --workers 4 --steps 50 --mode admm
@@ -38,11 +42,13 @@ from repro.core.censoring import CensorConfig
 from repro.core.quantization import QuantConfig
 from repro.data.lm import SyntheticLM, SyntheticLMConfig, model_batch
 from repro.launch import compile_cache
+from repro.launch import sharding as SH
 from repro.models import registry
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.ledger import CommLedger
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
+from repro.runtime import partitioning as P
 from repro.runtime import steps as ST
 
 
@@ -141,12 +147,20 @@ def engine_config(cfg, args) -> E.EngineConfig:
 
 
 def worker_placement(n_workers: int):
-    """The ``workers`` mesh over ``topology.worker_devices`` — one block
-    of worker rows per device — or ``None`` when that is one device, where
-    everything stays on the default device. Says so when it leaves local
-    devices idle."""
+    """The mesh the workers' rows are placed on. Where the process sees
+    more devices than workers and the workers divide them, each worker
+    spans ``devices / n_workers`` of them: a ``(workers, model)`` mesh,
+    each worker's parameters split over its ``model`` devices
+    (``AdmmSetup.shardings``). Otherwise the ``workers`` mesh over
+    ``topology.worker_devices`` — one block of worker rows per device — or
+    ``None`` when that is one device, where everything stays on the
+    default device. Says so when it leaves local devices idle."""
+    local = jax.devices()
+    if len(local) > n_workers and len(local) % n_workers == 0:
+        return topo_lib.make_mesh((n_workers, len(local) // n_workers),
+                                  ("workers", "model"), devices=local)
     devices = topo_lib.worker_devices(n_workers)
-    n_local = len(jax.devices())
+    n_local = len(local)
     if len(devices) < n_local:
         print(f"[train] {n_workers} workers take equal row blocks on "
               f"{len(devices)} of the {n_local} local devices")
@@ -170,15 +184,40 @@ class AdmmSetup:
     mesh: object = None
 
     def _sharding(self, x):
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        return NamedSharding(self.mesh, P("workers") if x.ndim else P())
+        """A worker-stacked array's placement by its worker axis alone
+        (a batch; on a mesh without other axes, any state leaf)."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        return NamedSharding(self.mesh, PartitionSpec("workers") if x.ndim
+                             else PartitionSpec())
+
+    @property
+    def split(self) -> bool:
+        """Whether each worker spans several devices (a ``model`` axis)."""
+        return topo_lib.devices_per_worker(self.mesh, "workers") > 1
+
+    def shardings(self, tree):
+        """The placement of every leaf of a worker-stacked tree (parameters,
+        an ``EngineState``), by its path: rows over ``workers`` and, where
+        each worker spans several devices, the tensor-parallel split of
+        ``launch/sharding.py`` over ``model`` (heads, MLP width, vocab)."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        if not self.split:
+            return jax.tree_util.tree_map(self._sharding, tree)
+
+        def leaf(path, x):
+            if not x.ndim:
+                return NamedSharding(self.mesh, PartitionSpec())
+            return NamedSharding(self.mesh, SH.param_spec(
+                jax.tree_util.keystr(path), x.shape, self.mesh, self.cfg,
+                worker_axis="workers"))
+        return jax.tree_util.tree_map_with_path(leaf, tree)
 
     def _jit_placed(self, fn):
         """``fn()`` run under jit, its outputs created directly in their
         worker placement (never first gathered on one device)."""
         if self.mesh is None:
             return jax.jit(fn)()
-        out = jax.tree_util.tree_map(self._sharding, jax.eval_shape(fn))
+        out = self.shardings(jax.eval_shape(fn))
         return jax.jit(fn, out_shardings=out)()
 
     def stacked_params(self):
@@ -205,11 +244,33 @@ class AdmmSetup:
 
     def build_step(self, ecfg: E.EngineConfig | None = None):
         """The jitted round. It donates the state it is given: at LM
-        width the input and output states cannot both be live."""
+        width the input and output states cannot both be live. Where each
+        worker spans several devices, the model runs under the
+        tensor-parallel activation rules (``P.logical_sharding``) and the
+        state leaves the round in the placement it came in."""
+        split = self.split
+        specs = None
+        if split:
+            specs = jax.tree_util.tree_map(
+                lambda s: s.spec,
+                self.shardings(jax.eval_shape(self.stacked_params)))
         step = E.make_step(self.graph, ecfg or self.ecfg, self.solver,
-                           extra_metrics=E.consensus_metrics(self.loss_fn),
+                           extra_metrics=E.consensus_metrics(self.loss_fn,
+                                                             split=split),
                            mesh=self.mesh,
-                           worker_axis="workers" if self.mesh else None)
+                           worker_axis="workers" if self.mesh else None,
+                           leaf_specs=specs)
+        if split:
+            rules = SH.activation_rules(self.mesh, self.cfg, batch_axes=(),
+                                        worker_mode=True,
+                                        worker_axis="workers")
+            inner = step
+
+            def step(state, batch, key):
+                with P.logical_sharding(self.mesh, rules):
+                    state, metrics = inner(state, batch, key)
+                return jax.lax.with_sharding_constraint(
+                    state, self.shardings(state)), metrics
         return jax.jit(step, donate_argnums=0)
 
     def batch(self, i: int):

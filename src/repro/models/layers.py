@@ -42,24 +42,32 @@ def embed_init(key, vocab: int, dim: int):
     return {"table": jax.random.normal(key, (vocab, dim), jnp.float32) * 0.02}
 
 
-def embed(params, tokens):
+def embed(params, tokens, dtype=jnp.bfloat16):
     """Embedding lookup. Under a mesh the table is vocab-sharded (TP): a
     plain gather makes GSPMD replicate the gathered activations ("involuntary
     full rematerialization"); the TPU-idiomatic form is a one-hot matmul —
     each shard contracts its vocab slice on the MXU and the partial results
-    reduce-scatter, so nothing is ever replicated."""
+    reduce-scatter, so nothing is ever replicated. The matmul runs in the
+    model's compute ``dtype``: one nonzero term a row, so it reads the
+    table exactly as the gather does."""
     table = params["table"]
     if P.current_mesh() is None:
         return jnp.take(table, tokens, axis=0)
-    onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=jnp.bfloat16)
+    onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=dtype)
     onehot = P.constrain(onehot, ("batch", "seq", "vocab"))
-    return jnp.einsum("...v,vd->...d", onehot, table.astype(jnp.bfloat16))
+    return jnp.einsum("...v,vd->...d", onehot, table.astype(dtype))
 
 
 def unembed(params, x):
     """Tied unembedding: logits = x @ table^T, sharded over vocab."""
     logits = jnp.einsum("...d,vd->...v", x, params["table"].astype(x.dtype))
     return P.constrain(logits, ("batch", None, "vocab"))
+
+
+def lm_head(params, x):
+    """Untied output head: logits = x @ w, ``w`` (D, V) sharded over
+    vocab."""
+    return P.constrain(dense(params, x), ("batch", None, "vocab"))
 
 
 # ----------------------------------------------------------------- rotary --

@@ -53,6 +53,9 @@ def init_params(cfg, key) -> Dict[str, Any]:
         "embed": layers.embed_init(keys[0], cfg.vocab_size, cfg.d_model),
         "final_norm": layers.rmsnorm_init(cfg.d_model),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = layers.dense_init(keys[5], cfg.d_model,
+                                              cfg.vocab_size)
     stack: Dict[str, Any] = {"units": {}, "rem": {}}
     if "shared_attn" in cfg.block_kinds:
         stack["shared"] = blocks.init("shared_attn", keys[1], cfg)
@@ -202,8 +205,8 @@ def apply_model(params, cfg, batch: Dict[str, jax.Array], *,
     positions = batch.get("positions")
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    x = layers.embed(params["embed"], tokens).astype(
-        jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32)
+    dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+    x = layers.embed(params["embed"], tokens, dtype).astype(dtype)
     x = P.constrain(x, ("batch", "seq", "embed"))
 
     if cfg.vision_tokens and "vision_embeds" in batch:
@@ -228,7 +231,10 @@ def apply_model(params, cfg, batch: Dict[str, jax.Array], *,
         params, cfg, x, positions, caches, causal=True,
         window_override=window_override, cross_kv=cross_kv)
     x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = layers.unembed(params["embed"], x)
+    if cfg.tie_embeddings:
+        logits = layers.unembed(params["embed"], x)
+    else:
+        logits = layers.lm_head(params["lm_head"], x)
     return logits, aux, new_caches
 
 

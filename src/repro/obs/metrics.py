@@ -330,6 +330,18 @@ def engine_phase_rows_counter() -> Counter:
     )
 
 
+def engine_worker_layout_counter() -> Counter:
+    """Traces of the consensus engine's round by worker layout:
+    ``workers`` (N) and ``chips_per_worker``, the devices each worker's
+    rows span (1 where several workers share a device). Bumped in
+    ``core/engine.py`` ``make_step`` once per trace of the round, never
+    inside a compiled program."""
+    return REGISTRY.counter(
+        "engine_worker_layout", labels=("workers", "chips_per_worker"),
+        help="traces of the engine round, by workers and chips per worker",
+    )
+
+
 # jax.monitoring time-span events -> the compile counter's ``stage``.
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",

@@ -155,3 +155,88 @@ def test_slstm_cell_compiles_at_xlstm_width(shape):
     _compile(lambda *a: slstm_cell(*a, interpret=False),
              shape((bsz, seq, h, 4 * dh)), shape((h, dh, 4 * dh)),
              shape((h, dh)), state, state, state, state)
+
+
+def _replica_groups(line):
+    """The device groups of one collective in compiled HLO text, by their
+    logical ids: an explicit list, an iota form, or permute pairs."""
+    import re
+
+    import numpy as np
+    m = re.search(r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\]"
+                  r"(?:T\(([\d,]+)\))?", line)
+    if m:
+        ids = np.arange(int(np.prod([int(d) for d in m.group(3).split(",")])))
+        ids = ids.reshape([int(d) for d in m.group(3).split(",")])
+        if m.group(4):
+            ids = ids.transpose([int(p) for p in m.group(4).split(",")])
+        return {frozenset(g) for g in ids.reshape(int(m.group(1)),
+                                                  int(m.group(2))).tolist()}
+    m = re.search(r"replica_groups=\{((?:\{[\d,]*\},?)*)\}", line)
+    if m:
+        return {frozenset(int(x) for x in g.split(",") if x)
+                for g in re.findall(r"\{([\d,]*)\}", m.group(1))}
+    m = re.search(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}", line)
+    return {frozenset(int(x) for x in p.split(","))
+            for p in re.findall(r"\{(\d+,\d+)\}", m.group(1))} if m else set()
+
+
+def test_split_workers_round_compiles_at_danube_width(topo):
+    """H2O-Danube-1.8B at its published widths, cut to 1 layer, trained as
+    2 CQ-GGADMM workers of 2 chips each on a described v5e:2x2 (the
+    ``danube-train-ici4`` deployment at 2048 positions). Outside the local
+    solve and the loss's forward pass, whose tensor-parallel collectives
+    are the model's, nothing crosses a worker's two chips but scalars:
+    the quantizer's ranges and the censor's norms."""
+    import dataclasses
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core import engine as E
+    from repro.core import topology as T
+    from repro.launch import train
+    cfg = base.get_config("h2o-danube-1.8b").cut_depth(1)
+    args = train.build_parser().parse_args([
+        "--arch", "h2o-danube-1.8b", "--workers", "2", "--batch", "2",
+        "--seq", "2048", "--local-steps", "4", "--lr", "1e-4",
+        "--rho", "0.01", "--bits", "4", "--omega", "0.999",
+        "--mix-backend", "sharded"])
+    setup = train.admm_setup(cfg, args)
+    mesh = T.make_mesh((2, 2), ("workers", "model"), devices=topo.devices)
+    setup = dataclasses.replace(setup, mesh=mesh)
+    abstract = jax.eval_shape(lambda: E.init_state(
+        setup.stacked_params(), setup.ecfg, setup.solver))
+    state = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        abstract, setup.shardings(abstract))
+    tok = jax.ShapeDtypeStruct((2, 1, 2048), jnp.int32, sharding=NamedSharding(
+        mesh, PartitionSpec("workers")))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, PartitionSpec()))
+    compiled = setup.build_step().lower(
+        state, {"tokens": tok, "labels": tok}, key).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+    ids = mesh.devices.shape
+    model_groups = {frozenset(r) for r in
+                    jnp.arange(ids[0] * ids[1]).reshape(ids).tolist()}
+    n_leaves = len(jax.tree_util.tree_leaves(abstract.theta))
+    scalars, found = [], []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"=\s*(\S+)\s+(all-gather|all-reduce|reduce-scatter|"
+                      r"collective-permute|all-to-all)(?:-start)?\(", line)
+        if not m or _replica_groups(line) != model_groups:
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        op = op.group(1) if op else ""
+        if "admm.solve" in op or "admm.metrics/loss" in op:
+            continue
+        dims = re.findall(r"\[([\d,]*)\]", m.group(1))
+        elements = max(int(jnp.prod(jnp.asarray(
+            [int(x) for x in d.split(",") if x] or [1]))) for d in dims)
+        found.append((m.group(2), m.group(1), op))
+        scalars.append(elements <= n_leaves)
+    assert found, "no collective over the worker's chips outside the solve"
+    assert all(scalars), [f for f, ok in zip(found, scalars) if not ok]
