@@ -152,6 +152,16 @@ def paged_attention_decode(q, k_pages, v_pages, block_tables, ctx_lens, *,
               v_scale=v_scale, kv_bits=kv_bits)
 
 
+def flash_attention(q, k, v, *, window=None):
+    """Causal (sliding-window where ``window`` is set) flash attention over
+    canonical positions, forward and backward (``kernels/flash_attention``;
+    oracle ``ref.causal_attention_ref``). ``q`` carries the ``1/sqrt(D)``
+    scale."""
+    from repro.kernels import flash_attention as _flash
+    _count("flash_attention", "window" if window is not None else "causal")
+    return _flash.causal_attention(q, k, v, window=window)
+
+
 def slstm_cell(wx, r_w, fbias, c0, n0, m0, h0):
     from repro.kernels import slstm_cell as _cell
     _count("slstm_cell", "fused")

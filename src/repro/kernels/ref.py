@@ -259,6 +259,27 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array,
     return jnp.stack(outs, axis=0)
 
 
+def causal_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
+                         window=None) -> jax.Array:
+    """Causal (and ``q - k < window``) grouped-query attention over
+    positions ``0..S-1`` in float32, ground truth for the flash kernel.
+    q (B, S, H, D) already scaled; k, v (B, S, KV, D). Query head ``h``
+    reads KV head ``h // (H / KV)``. Returns float32 (B, S, H, D)."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    qf = q.astype(jnp.float32).reshape(b, s, kv, h // kv, d)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qf, k.astype(jnp.float32),
+                        precision="highest")
+    pos = jnp.arange(s)
+    mask = pos[None, :] <= pos[:, None]
+    if window is not None:
+        mask = mask & (pos[:, None] - pos[None, :] < window)
+    probs = jax.nn.softmax(jnp.where(mask, logits, -jnp.inf), axis=-1)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v.astype(jnp.float32),
+                     precision="highest")
+    return out.reshape(b, s, h, d)
+
+
 def bipartite_mix_ref(adjacency: jax.Array, values: jax.Array) -> jax.Array:
     """Neighbor aggregation sum_{m in N_n} v_m  =  A @ V.
 

@@ -247,7 +247,11 @@ class AdmmSetup:
         width the input and output states cannot both be live. Where each
         worker spans several devices, the model runs under the
         tensor-parallel activation rules (``P.logical_sharding``) and the
-        state leaves the round in the placement it came in."""
+        state leaves the round in the placement it came in. Where workers
+        take whole devices, the model sees the ``workers`` mesh with no
+        rule but the worker axis: the flash attention then runs inside
+        ``shard_map``, each device on its own workers (GSPMD cannot
+        partition its custom call)."""
         split = self.split
         specs = None
         if split:
@@ -260,17 +264,20 @@ class AdmmSetup:
                            mesh=self.mesh,
                            worker_axis="workers" if self.mesh else None,
                            leaf_specs=specs)
-        if split:
-            rules = SH.activation_rules(self.mesh, self.cfg, batch_axes=(),
-                                        worker_mode=True,
-                                        worker_axis="workers")
+        if self.mesh is not None:
+            rules = (SH.activation_rules(self.mesh, self.cfg, batch_axes=(),
+                                         worker_mode=True,
+                                         worker_axis="workers")
+                     if split else {"worker": "workers"})
             inner = step
 
             def step(state, batch, key):
                 with P.logical_sharding(self.mesh, rules):
                     state, metrics = inner(state, batch, key)
-                return jax.lax.with_sharding_constraint(
-                    state, self.shardings(state)), metrics
+                if split:
+                    state = jax.lax.with_sharding_constraint(
+                        state, self.shardings(state))
+                return state, metrics
         return jax.jit(step, donate_argnums=0)
 
     def batch(self, i: int):
@@ -294,15 +301,29 @@ class AdmmSetup:
         return jax.random.PRNGKey(1000 + i)
 
 
+def _worker_vmap(fn):
+    """``jax.vmap`` over the worker axis. Under a mesh with a ``workers``
+    axis (``AdmmSetup.build_step``), the mapped axis is that mesh axis
+    (``spmd_axis_name``): a ``shard_map`` inside the model, the flash
+    attention's, then keeps each worker on its own devices instead of
+    replicating every worker on every device."""
+    def mapped(*args):
+        mesh = P.current_mesh()
+        axis = ("workers" if mesh is not None
+                and "workers" in mesh.axis_names else None)
+        return jax.vmap(fn, spmd_axis_name=axis)(*args)
+    return mapped
+
+
 def admm_setup(cfg, args) -> AdmmSetup:
     obs_metrics.install_compile_listener()
 
     def grad_fn(theta, batch):
-        return jax.vmap(lambda p, b: jax.grad(
+        return _worker_vmap(lambda p, b: jax.grad(
             lambda pp: registry.lm_loss(pp, cfg, b)[0])(p))(theta, batch)
 
     def loss_fn(theta, batch):
-        return jnp.mean(jax.vmap(
+        return jnp.mean(_worker_vmap(
             lambda p, b: registry.lm_loss(p, cfg, b)[0])(theta, batch))
 
     return AdmmSetup(

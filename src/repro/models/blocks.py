@@ -26,6 +26,8 @@ class BlockCtx:
     causal: bool = True
     window_override: Optional[int] = None  # long_500k SWA variant
     cross_kv: Optional[tuple] = None       # (k, v) for decoder cross-attn
+    # positions are 0..S-1 in every row, built by the model (static)
+    canonical_positions: bool = False
 
 
 def _attn_dims(cfg):
@@ -95,7 +97,8 @@ def apply(kind: str, params, cfg, x, ctx: BlockCtx):
             causal=ctx.causal, window=_window_for(kind, cfg, ctx),
             rope_theta=cfg.rope_theta,
             mrope_sections=(cfg.mrope_sections if use_rope else None),
-            use_rope=use_rope, cache=ctx.cache)
+            use_rope=use_rope, cache=ctx.cache,
+            canonical_positions=ctx.canonical_positions)
         x = x + h
         if kind == "moe":
             y, aux = moe.moe_apply(
@@ -114,7 +117,8 @@ def apply(kind: str, params, cfg, x, ctx: BlockCtx):
         h, new_self = layers.attention_apply(
             params["attn"], dims,
             layers.rmsnorm(params["ln1"], x, cfg.norm_eps), ctx.positions,
-            causal=True, use_rope=use_rope, cache=self_cache)
+            causal=True, use_rope=use_rope, cache=self_cache,
+            canonical_positions=ctx.canonical_positions)
         x = x + h
         # cross-attention: project encoder output (train/prefill) or reuse
         # the cached per-layer cross KVs (decode).

@@ -9,11 +9,15 @@ activation constraints — model code stays mesh-agnostic.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import PartitionSpec
 
+from repro.obs.metrics import attention_path_counter
 from repro.runtime import partitioning as P
 
 
@@ -43,15 +47,15 @@ def embed_init(key, vocab: int, dim: int):
 
 
 def embed(params, tokens, dtype=jnp.bfloat16):
-    """Embedding lookup. Under a mesh the table is vocab-sharded (TP): a
-    plain gather makes GSPMD replicate the gathered activations ("involuntary
-    full rematerialization"); the TPU-idiomatic form is a one-hot matmul —
-    each shard contracts its vocab slice on the MXU and the partial results
-    reduce-scatter, so nothing is ever replicated. The matmul runs in the
-    model's compute ``dtype``: one nonzero term a row, so it reads the
-    table exactly as the gather does."""
+    """Embedding lookup. Where the rules shard the table over vocab (TP),
+    a plain gather makes GSPMD replicate the gathered activations
+    ("involuntary full rematerialization"); the TPU-idiomatic form is a
+    one-hot matmul — each shard contracts its vocab slice on the MXU and
+    the partial results reduce-scatter, so nothing is ever replicated. The
+    matmul runs in the model's compute ``dtype``: one nonzero term a row,
+    so it reads the table exactly as the gather does."""
     table = params["table"]
-    if P.current_mesh() is None:
+    if P.current_mesh() is None or P.current_rules().get("vocab") is None:
         return jnp.take(table, tokens, axis=0)
     onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=dtype)
     onehot = P.constrain(onehot, ("batch", "seq", "vocab"))
@@ -79,11 +83,13 @@ def _rope_angles(positions, head_dim: int, theta: float):
 
 
 def apply_rope(x, positions, theta: float,
-               mrope_sections: Optional[Tuple[int, int, int]] = None):
+               mrope_sections: Optional[Tuple[int, int, int]] = None,
+               scale: Optional[float] = None):
     """Rotary embedding. x: (B, S, H, D). positions: (B, S) or (B, S, 3)
     for M-RoPE, where the three planes are (temporal, height, width) and
     `mrope_sections` splits the D/2 frequency bands among them
-    (qwen2-vl, arXiv:2409.12191)."""
+    (qwen2-vl, arXiv:2409.12191). A `scale` multiplies the rotated
+    float32 values before their one cast back to x's dtype."""
     head_dim = x.shape[-1]
     if mrope_sections is not None:
         assert positions.ndim == 3 and positions.shape[-1] == 3
@@ -103,6 +109,8 @@ def apply_rope(x, positions, theta: float,
     sin = jnp.sin(angles)[:, :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+    if scale is not None:
+        out = out * scale
     return out.astype(x.dtype)
 
 
@@ -199,6 +207,61 @@ def mha_blockwise(q, k, v, q_positions, kv_positions, causal, window,
     _, outs = jax.lax.scan(chunk, None, (q_c, qp_c))
     out = outs.swapaxes(0, 1).reshape(b, nc * block_q, h, d)
     return out[:, :sq]
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_spec(mesh, h: int, kv: int, b: int) -> Optional[PartitionSpec]:
+    """The shard_map spec of q, k and v (B, S, heads, D) under ``mesh``:
+    batch and heads over the axes their rules name, so the KV heads a chip
+    holds are the ones its query heads read; ``None`` where a rule's axes
+    do not divide the dimension."""
+    rules = P.current_rules()
+
+    def size(axis):
+        names = axis if isinstance(axis, tuple) else (axis,)
+        return int(np.prod([mesh.shape[n] for n in names if n]))
+    batch, heads = rules.get("batch"), rules.get("heads")
+    if b % size(batch) or h % size(heads) or kv % size(heads):
+        return None
+    return PartitionSpec(batch, None, heads, None)
+
+
+def attention_path(s: int, head_dim: int, heads: int, kv_heads: int, *,
+                   canonical: bool, causal: bool, cached: bool,
+                   cross: bool, batch: int = 1) -> str:
+    """Which attention runs: ``flash`` (``kernels/flash_attention``) for
+    causal self-attention over canonical positions ``0..s-1`` with no
+    cache, on a TPU, past ``MHA_BLOCKWISE_THRESHOLD`` and at a sequence a
+    flash block divides (and, under a mesh, whose heads split evenly);
+    else ``blockwise`` past the threshold and ``dense`` below it."""
+    if s <= MHA_BLOCKWISE_THRESHOLD:
+        return "dense"
+    from repro.kernels import flash_attention
+    mesh = P.current_mesh()
+    if (canonical and causal and not cached and not cross and _on_tpu()
+            and flash_attention.block_sizes(s, head_dim) is not None
+            and (mesh is None or _flash_spec(mesh, heads, kv_heads, batch)
+                 is not None)):
+        return "flash"
+    return "blockwise"
+
+
+def mha_flash(q, k, v, window: Optional[int]):
+    """The flash kernel over canonical positions; ``q`` carries the
+    ``1/sqrt(D)`` scale. Under a mesh it runs inside ``shard_map``, each
+    chip on its own batch rows and heads (GSPMD cannot partition the
+    kernel's custom call)."""
+    from repro.kernels import ops as kernel_ops
+    fn = functools.partial(kernel_ops.flash_attention, window=window)
+    mesh = P.current_mesh()
+    if mesh is None:
+        return fn(q, k, v)
+    spec = _flash_spec(mesh, q.shape[2], k.shape[2], q.shape[0])
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def init_kv_cache(batch: int, cache_len: int, num_kv: int, head_dim: int,
@@ -435,15 +498,28 @@ def attention_apply(params, dims: AttnDims, x, positions, *,
                     rope_theta: float = 10000.0,
                     mrope_sections=None, use_rope: bool = True,
                     cache: Optional[dict] = None,
-                    kv_override: Optional[Tuple[jax.Array, jax.Array]] = None):
+                    kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,
+                    canonical_positions: bool = False):
     """Self- (or cross-, via kv_override) attention with optional ring cache.
 
     Returns (out, new_cache). `positions` is (B, S) absolute (or (B, S, 3)
     for M-RoPE; plane 0 = temporal is used for masking).
+    `canonical_positions` says they are ``0..S-1`` in every row (the model
+    built them), which lets `attention_path` pick the flash kernel.
     """
     b, s, _ = x.shape
     h, kv, hd = dims.num_heads, dims.num_kv_heads, dims.head_dim
     q_positions = positions if positions.ndim == 2 else positions[..., 0]
+    cross = kv_override is not None
+    path = attention_path(s, hd, h, kv, canonical=canonical_positions,
+                          causal=causal, cached=cache is not None,
+                          cross=cross, batch=b)
+    attention_path_counter().inc(
+        1, path=path, masked="none" if cross or not causal
+        else "window" if window is not None else "causal")
+    # the flash kernel takes q scaled: 1/sqrt(D) folded into RoPE's float32
+    # arithmetic, before q's one cast back to the compute dtype
+    q_scale = float(hd) ** -0.5 if path == "flash" else None
     x = P.gather_tokens(x)       # sequence-parallel boundary (no-op unless
     #                              the res_seq rule is active)
     q = dense(params["q"], x).reshape(b, s, h, hd)
@@ -459,8 +535,11 @@ def attention_apply(params, dims: AttnDims, x, positions, *,
         v = P.gather_tokens(dense(params["v"], x).reshape(b, s, kv, hd),
                             dim=1)
         if use_rope:
-            q = apply_rope(q, positions, rope_theta, mrope_sections)
+            q = apply_rope(q, positions, rope_theta, mrope_sections,
+                           scale=q_scale)
             k = apply_rope(k, positions, rope_theta, mrope_sections)
+        elif q_scale is not None:
+            q = (q.astype(jnp.float32) * q_scale).astype(q.dtype)
         if cache is not None:
             if is_paged_cache(cache):
                 new_cache = _paged_cache_write(cache, k, v, q_positions)
@@ -480,7 +559,9 @@ def attention_apply(params, dims: AttnDims, x, positions, *,
 
     q = P.constrain(q, ("batch", "seq", "heads", None))
     k, v = k.astype(q.dtype), v.astype(q.dtype)
-    if s > MHA_BLOCKWISE_THRESHOLD:
+    if path == "flash":
+        out = mha_flash(q, k, v, eff_window)
+    elif path == "blockwise":
         out = mha_blockwise(q, k, v, q_positions, kv_positions,
                             eff_causal, eff_window)
     else:

@@ -129,7 +129,8 @@ def init_cache(cfg, batch: int, cache_len: int,
 
 # ----------------------------------------------------------------- apply --
 def _run_stack(params, cfg, x, positions, caches, *, causal=True,
-               window_override=None, cross_kv=None):
+               window_override=None, cross_kv=None,
+               canonical_positions=False):
     unit, n_full, rem = segments(cfg)
     if cfg.is_encoder_decoder:
         unit, n_full, rem = ("xattn",), cfg.num_layers, ()
@@ -139,7 +140,8 @@ def _run_stack(params, cfg, x, positions, caches, *, causal=True,
 
     def make_ctx(cache):
         return BlockCtx(positions=positions, cache=cache, causal=causal,
-                        window_override=window_override, cross_kv=cross_kv)
+                        window_override=window_override, cross_kv=cross_kv,
+                        canonical_positions=canonical_positions)
 
     new_caches: Dict[str, Any] = {"units": {}, "rem": {}}
     if n_full > 0:
@@ -203,7 +205,8 @@ def apply_model(params, cfg, batch: Dict[str, jax.Array], *,
     tokens = batch["tokens"]
     b, s = tokens.shape
     positions = batch.get("positions")
-    if positions is None:
+    canonical = positions is None
+    if canonical:
         positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
     x = layers.embed(params["embed"], tokens, dtype).astype(dtype)
@@ -229,7 +232,8 @@ def apply_model(params, cfg, batch: Dict[str, jax.Array], *,
 
     x, aux, new_caches = _run_stack(
         params, cfg, x, positions, caches, causal=True,
-        window_override=window_override, cross_kv=cross_kv)
+        window_override=window_override, cross_kv=cross_kv,
+        canonical_positions=canonical)
     x = layers.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = layers.unembed(params["embed"], x)

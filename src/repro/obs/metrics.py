@@ -342,6 +342,18 @@ def engine_worker_layout_counter() -> Counter:
     )
 
 
+def attention_path_counter() -> Counter:
+    """Traces of ``models/layers.attention_apply`` by the attention that
+    ran: ``path`` (``flash``, ``blockwise``, ``dense``) and ``masked``
+    (``window``: causal within a sliding window; ``causal``; ``none``:
+    cross-attention or a bidirectional encoder). Bumped once per trace of a
+    layer, never inside a compiled program."""
+    return REGISTRY.counter(
+        "attention_path", labels=("path", "masked"),
+        help="traced attention layers, by path and mask",
+    )
+
+
 # jax.monitoring time-span events -> the compile counter's ``stage``.
 COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",

@@ -181,41 +181,93 @@ def _replica_groups(line):
             for p in re.findall(r"\{(\d+,\d+)\}", m.group(1))} if m else set()
 
 
-def test_split_workers_round_compiles_at_danube_width(topo):
-    """H2O-Danube-1.8B at its published widths, cut to 1 layer, trained as
-    2 CQ-GGADMM workers of 2 chips each on a described v5e:2x2 (the
-    ``danube-train-ici4`` deployment at 2048 positions). Outside the local
-    solve and the loss's forward pass, whose tensor-parallel collectives
-    are the model's, nothing crosses a worker's two chips but scalars:
-    the quantizer's ranges and the censor's norms."""
+def _round(arch, workers, mesh, seq):
+    """``arch`` at its published widths, cut to 1 layer, its round
+    compiled as ``workers`` CQ-GGADMM workers (one sequence of ``seq``
+    each) placed on ``mesh``. Returns the compiled round and the abstract
+    engine state."""
     import dataclasses
-    import re
 
     from jax.sharding import NamedSharding, PartitionSpec
 
     from repro.core import engine as E
-    from repro.core import topology as T
     from repro.launch import train
-    cfg = base.get_config("h2o-danube-1.8b").cut_depth(1)
+    cfg = base.get_config(arch).cut_depth(1)
     args = train.build_parser().parse_args([
-        "--arch", "h2o-danube-1.8b", "--workers", "2", "--batch", "2",
-        "--seq", "2048", "--local-steps", "4", "--lr", "1e-4",
+        "--arch", arch, "--workers", str(workers), "--batch", str(workers),
+        "--seq", str(seq), "--local-steps", "4", "--lr", "1e-4",
         "--rho", "0.01", "--bits", "4", "--omega", "0.999",
         "--mix-backend", "sharded"])
     setup = train.admm_setup(cfg, args)
-    mesh = T.make_mesh((2, 2), ("workers", "model"), devices=topo.devices)
     setup = dataclasses.replace(setup, mesh=mesh)
     abstract = jax.eval_shape(lambda: E.init_state(
         setup.stacked_params(), setup.ecfg, setup.solver))
     state = jax.tree_util.tree_map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         abstract, setup.shardings(abstract))
-    tok = jax.ShapeDtypeStruct((2, 1, 2048), jnp.int32, sharding=NamedSharding(
-        mesh, PartitionSpec("workers")))
+    tok = jax.ShapeDtypeStruct((workers, 1, seq), jnp.int32,
+                               sharding=NamedSharding(
+                                   mesh, PartitionSpec("workers")))
     key = jax.ShapeDtypeStruct((2,), jnp.uint32,
                                sharding=NamedSharding(mesh, PartitionSpec()))
     compiled = setup.build_step().lower(
         state, {"tokens": tok, "labels": tok}, key).compile()
+    return compiled, abstract
+
+
+def _danube_round(topo, seq):
+    """H2O-Danube-1.8B at its published widths, cut to 1 layer, its round
+    compiled as 2 CQ-GGADMM workers of 2 chips each on a described
+    v5e:2x2 (the ``danube-train-ici4`` deployment) at ``seq`` positions.
+    Returns the compiled round, its mesh and the abstract engine state."""
+    from repro.core import topology as T
+    mesh = T.make_mesh((2, 2), ("workers", "model"), devices=topo.devices)
+    compiled, abstract = _round("h2o-danube-1.8b", 2, mesh, seq)
+    return compiled, mesh, abstract
+
+
+def _solve_lines(compiled):
+    """The compiled round's instructions inside ``admm.solve``: the flash
+    kernel's custom calls (op names) and the collectives (kind, result
+    type, replica groups)."""
+    import re
+    kernels_in, collectives = [], []
+    for line in compiled.as_text().splitlines():
+        op = re.search(r'op_name="([^"]*)"', line)
+        op = op.group(1) if op else ""
+        if "admm.solve" not in op:
+            continue
+        if "splash_mha" in op and "pallas_call" in op:
+            kernels_in.append(op)
+        m = re.search(_COLLECTIVE, line)
+        if m:
+            collectives.append((m.group(2), m.group(1),
+                                _replica_groups(line)))
+    return kernels_in, collectives
+
+
+@pytest.fixture
+def flash_on_chip(monkeypatch):
+    """The platform taken for a TPU (the described chip is not the
+    process's backend) and the kernels compiled, not interpreted."""
+    from repro import kernels
+    from repro.models import layers
+    monkeypatch.setattr(layers, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kernels, "interpret_mode", lambda interpret=None:
+                        False)
+
+
+_COLLECTIVE = (r"=\s*(\S+)\s+(all-gather|all-reduce|reduce-scatter|"
+               r"collective-permute|all-to-all)(?:-start)?\(")
+
+
+def test_split_workers_round_compiles_at_danube_width(topo):
+    """The ``danube-train-ici4`` round at 2048 positions. Outside the local
+    solve and the loss's forward pass, whose tensor-parallel collectives
+    are the model's, nothing crosses a worker's two chips but scalars:
+    the quantizer's ranges and the censor's norms."""
+    import re
+    compiled, mesh, abstract = _danube_round(topo, 2048)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
@@ -225,8 +277,7 @@ def test_split_workers_round_compiles_at_danube_width(topo):
     n_leaves = len(jax.tree_util.tree_leaves(abstract.theta))
     scalars, found = [], []
     for line in compiled.as_text().splitlines():
-        m = re.search(r"=\s*(\S+)\s+(all-gather|all-reduce|reduce-scatter|"
-                      r"collective-permute|all-to-all)(?:-start)?\(", line)
+        m = re.search(_COLLECTIVE, line)
         if not m or _replica_groups(line) != model_groups:
             continue
         op = re.search(r'op_name="([^"]*)"', line)
@@ -240,3 +291,57 @@ def test_split_workers_round_compiles_at_danube_width(topo):
         scalars.append(elements <= n_leaves)
     assert found, "no collective over the worker's chips outside the solve"
     assert all(scalars), [f for f, ok in zip(found, scalars) if not ok]
+
+
+# Arguments plus temp of the round below, per chip, compiled here: 6,813,
+# 066,240 bytes (2,799,950,848 + 4,013,115,392) before the flash kernel,
+# when attention ran in 512-query blocks over all 8192 keys; 6,799,474,176
+# (2,799,950,848 + 3,999,523,328) with it.
+BLOCKWISE_ROUND_BYTES = 6_813_066_240
+
+
+def test_split_workers_round_runs_flash_attention_at_danube_length(
+        topo, flash_on_chip):
+    """The ``danube-train-ici4`` round at its 8192 positions, cut to 1
+    layer, on a TPU: self-attention runs as the flash kernel's custom
+    calls inside ``admm.solve``, each chip on its own worker's heads. No
+    collective inside the solve crosses the ``workers`` axis, none is an
+    all-gather (of q, k or v heads or anything else: the solve's
+    collectives are the tensor-parallel all-reduces), and the round needs
+    no more memory than the blockwise attention it replaces."""
+    compiled, mesh, _ = _danube_round(topo, 8192)
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total < 15.75e9 and total <= BLOCKWISE_ROUND_BYTES
+
+    ids = mesh.devices.shape
+    workers = [frozenset(r) for r in
+               jnp.arange(ids[0] * ids[1]).reshape(ids).tolist()]
+    kernels_in, solve_collectives = _solve_lines(compiled)
+    assert any("fwd" in k for k in kernels_in)
+    assert any("dkv" in k for k in kernels_in)
+    assert any("dq" in k for k in kernels_in)
+    assert solve_collectives
+    for kind, shape, groups in solve_collectives:
+        assert all(any(g <= w for w in workers) for g in groups), \
+            (kind, shape, groups)
+        assert kind != "all-gather", (kind, shape)
+
+
+def test_worker_per_chip_round_runs_flash_attention(topo, flash_on_chip):
+    """TinyLlama-1.1B at its published widths, cut to 1 layer, as 4
+    workers of one chip each on a described v5e:2x2 (``chip_smoke.py
+    --chips 4``'s layout) at 4096 positions, on a TPU. The model sees the
+    ``workers`` mesh, so the flash kernel runs inside ``shard_map``, each
+    chip on its own worker, and compiles (GSPMD cannot partition its
+    custom call); the solve moves nothing between chips."""
+    from repro.core import topology as T
+    compiled, _ = _round("tinyllama-1.1b", 4, T.worker_mesh(topo.devices),
+                         4096)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    kernels_in, solve_collectives = _solve_lines(compiled)
+    assert any("fwd" in k for k in kernels_in)
+    assert any("dkv" in k for k in kernels_in)
+    assert any("dq" in k for k in kernels_in)
+    assert not solve_collectives, solve_collectives

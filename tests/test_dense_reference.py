@@ -15,7 +15,8 @@ import numpy as np
 import pytest
 
 from repro.configs import base
-from repro.models import registry
+from repro.models import layers, registry
+from repro.obs import metrics as obs_metrics
 
 BENCH = Path(__file__).resolve().parent.parent / "chipbench"
 SEQ = 160                         # the smoke window is 64
@@ -38,7 +39,7 @@ def dense():
     return mod
 
 
-def _case(tied):
+def _case(tied, seq=SEQ):
     mc = base.get_smoke_config("h2o-danube-1.8b").with_overrides(
         dtype="float32", tie_embeddings=tied)
     cfg = {"num_hidden_layers": mc.num_layers, "hidden_size": mc.d_model,
@@ -52,7 +53,7 @@ def _case(tied):
         lambda p, x: x + 0.1 * jax.random.normal(
             jax.random.PRNGKey(len(jax.tree_util.keystr(p))), x.shape)
         if "scale" in jax.tree_util.keystr(p) else x, params)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, SEQ), 0,
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, seq), 0,
                                 mc.vocab_size)
     labels = jnp.roll(tokens, -1, axis=1)
     return mc, cfg, params, tokens, labels
@@ -71,6 +72,33 @@ def test_reference_matches_program_logits_and_loss(dense, monkeypatch,
         ref_loss = dense.lm_loss(params, tokens, labels, cfg, mm)
         loss, _ = registry.lm_loss(params, mc, {"tokens": tokens,
                                                 "labels": labels})
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+    assert abs(float(loss) - float(ref_loss)) <= 1e-5 * abs(float(ref_loss))
+
+
+def test_reference_matches_program_on_the_flash_path(dense, monkeypatch):
+    """The registry's forward through the flash kernel
+    (``kernels/flash_attention``, interpret mode here), reached by taking
+    the platform for a TPU and lowering the blockwise threshold below the
+    sequence: 256 positions, two flash blocks of 128 against the smoke
+    window of 64."""
+    monkeypatch.setattr(dense, "BLOCK_Q", 48)
+    monkeypatch.setattr(layers, "_on_tpu", lambda: True)
+    monkeypatch.setattr(layers, "MHA_BLOCKWISE_THRESHOLD", 128)
+    mc, cfg, params, tokens, labels = _case(False, seq=256)
+    assert mc.sliding_window < tokens.shape[1]
+    mm = dense.Matmul("f32")
+    paths = obs_metrics.attention_path_counter()
+    before = paths.value(path="flash", masked="window")
+    with jax.default_matmul_precision("highest"):
+        ref = dense.forward(params, tokens, cfg, mm)
+        got, _, _ = registry.apply_model(params, mc, {"tokens": tokens})
+        ref_loss = dense.lm_loss(params, tokens, labels, cfg, mm)
+        loss, _ = registry.lm_loss(params, mc, {"tokens": tokens,
+                                                "labels": labels})
+    # one trace of the scanned layer in each of the two forward passes
+    assert paths.value(path="flash", masked="window") - before == 2
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
     assert abs(float(loss) - float(ref_loss)) <= 1e-5 * abs(float(ref_loss))
