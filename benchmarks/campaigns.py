@@ -15,8 +15,7 @@ One place declares what the benchmark layer runs (DESIGN.md §Campaign):
 * ``fleet-sweep``   — FleetSim bits-to-loss grid (participation x
   staleness x iid/dirichlet), gated on fault-free bit-identity to the
   synchronous engine and zero-bit censored accounting;
-* ``all``           — everything above plus the kernel-parity shape sweep
-  and the roofline table.
+* ``all``           — everything above plus the kernel-parity shape sweep.
 
 Stage functions are referenced lazily (``"module:function"``) so building
 or listing a campaign imports none of the heavy benchmark modules; run
@@ -119,22 +118,18 @@ FLEET_STAGE = stage(
 fleet_sweep = register_campaign(
     Campaign(name="fleet-sweep", stages=(FLEET_STAGE,)))
 
-# ------------------------------------------------------ kernels/roofline --
+# --------------------------------------------------------------- kernels --
 KERNELS_STAGE = stage(
     "kernels", "benchmarks.bench_kernels:stage_shape",
     configs=[{"n": n, "d": d} for n, d in ((8, 512), (16, 4096),
                                            (24, 16384))],
     names=["8x512", "16x4096", "24x16384"])
 
-ROOFLINE_STAGE = stage(
-    "roofline", "benchmarks.bench_roofline:stage_roofline",
-    names=["roofline"])
-
 # ------------------------------------------------------------------- all --
 everything = register_campaign(
     Campaign(name="all",
              stages=(ENGINE_STAGE,) + SERVING_STAGES
-             + (FIGURES_STAGE, FLEET_STAGE, KERNELS_STAGE, ROOFLINE_STAGE,
+             + (FIGURES_STAGE, FLEET_STAGE, KERNELS_STAGE,
                 LM_BASELINE_STAGE, LM_GRID_STAGE)))
 
 

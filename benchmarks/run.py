@@ -12,7 +12,7 @@ this module only selects a campaign and hands it to ``repro.campaign.runner``:
 ``campaigns/<name>/<run_key>/`` and re-merges their persisted records, so a
 killed campaign picks up where it stopped and the merged document is
 byte-identical to an uninterrupted one. Legacy flags (``--engine-smoke``,
-``--serve-smoke``, ``--skip-lm``, ``--skip-roofline``) map onto campaigns.
+``--serve-smoke``, ``--skip-lm``) map onto campaigns.
 """
 from __future__ import annotations
 
@@ -47,8 +47,6 @@ def main(argv=None) -> int:
     ap.add_argument("--serve-smoke", action="store_true",
                     help=argparse.SUPPRESS)
     ap.add_argument("--skip-lm", action="store_true", help=argparse.SUPPRESS)
-    ap.add_argument("--skip-roofline", action="store_true",
-                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
     from repro.launch import compile_cache
@@ -77,12 +75,8 @@ def main(argv=None) -> int:
             name = "all"
 
     campaign = campaigns.get(name)
-    if name == "all" and (args.skip_lm or args.skip_roofline):
-        drop = set()
-        if args.skip_lm:
-            drop |= {"lm-baseline", "lm-grid"}
-        if args.skip_roofline:
-            drop |= {"roofline"}
+    if name == "all" and args.skip_lm:
+        drop = {"lm-baseline", "lm-grid"}
         campaign = campaign.subset(
             [s.name for s in campaign.stages if s.name not in drop])
 

@@ -1,9 +1,8 @@
-"""Config system: model / shape / ADMM / run configuration + registry.
+"""Config system: model configuration + registry.
 
 Every assigned architecture registers a `ModelConfig` (exact paper/model-card
 hyperparameters) plus a reduced smoke variant (<=2 layers, d_model <= 512,
-<= 4 experts) used by CPU tests. Input shapes are the four assigned
-(seq_len, global_batch, kind) tuples.
+<= 4 experts) used by CPU tests.
 """
 from __future__ import annotations
 
@@ -64,10 +63,6 @@ class ModelConfig:
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
     source: str = ""                    # provenance citation
-    # long-context policy: "native" (sub-quadratic already), "swa_variant"
-    # (run long_500k with sliding_window override), "skip"
-    long_context: str = "swa_variant"
-    long_context_window: int = 4096
 
     @property
     def resolved_head_dim(self) -> int:
@@ -100,11 +95,6 @@ class ModelConfig:
                 f"published {self.num_layers}")
         return dataclasses.replace(self, num_layers=layers)
 
-    def for_long_context(self) -> "ModelConfig":
-        if self.long_context == "swa_variant":
-            return self.with_overrides(sliding_window=self.long_context_window)
-        return self
-
     # ---- analytic parameter / FLOP counts (roofline §) -------------------
     def param_count(self) -> int:
         from repro.models import registry
@@ -113,22 +103,6 @@ class ModelConfig:
     def active_param_count(self) -> int:
         from repro.models import registry
         return registry.count_params(self, active_only=True)
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                           # "train" | "prefill" | "decode"
-
-
-INPUT_SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
 
 
 _REGISTRY: Dict[str, Callable[[], ModelConfig]] = {}

@@ -16,8 +16,6 @@ def config() -> ModelConfig:
         d_ff=10240, vocab_size=262144, block_unit=_UNIT,
         head_dim=256, sliding_window=1024, rope_theta=1_000_000.0,
         source="hf:google/gemma-3-1b-pt",
-        # global layers get the window override under long_500k
-        long_context="swa_variant", long_context_window=4096,
     )
 
 

@@ -13,7 +13,6 @@ def config() -> ModelConfig:
         d_ff=32768, vocab_size=131072, block_unit=("moe",),
         num_experts=8, experts_per_token=2,
         source="hf:xai-org/grok-1",
-        long_context="swa_variant", long_context_window=4096,
     )
 
 

@@ -15,7 +15,6 @@ def config() -> ModelConfig:
         sliding_window=4096, rope_theta=10000.0, norm_eps=1e-5,
         tie_embeddings=False,
         source="arXiv:2401.16818",
-        long_context="native",   # base config is already windowed
     )
 
 
@@ -25,7 +24,6 @@ def smoke_config() -> ModelConfig:
         num_layers=2, d_model=256, num_heads=8, num_kv_heads=2,
         d_ff=512, vocab_size=512, block_unit=("swa",), sliding_window=64,
         norm_eps=1e-5, tie_embeddings=False, source="arXiv:2401.16818",
-        long_context="native",
     )
 
 

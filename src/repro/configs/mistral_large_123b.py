@@ -13,7 +13,6 @@ def config() -> ModelConfig:
         d_ff=28672, vocab_size=32768, block_unit=("attn",),
         head_dim=128,
         source="hf:mistralai/Mistral-Large-Instruct-2407",
-        long_context="swa_variant", long_context_window=4096,
     )
 
 

@@ -13,7 +13,6 @@ def config() -> ModelConfig:
         d_ff=1024, vocab_size=50304, block_unit=("moe",),
         num_experts=64, experts_per_token=8,
         source="arXiv:2409.02060",
-        long_context="swa_variant", long_context_window=4096,
     )
 
 

@@ -2,7 +2,7 @@
 
 28 layers, d_model=3584, 28 heads (GQA kv=4), d_ff=18944, vocab=152064.
 M-RoPE splits each head's rotary half-dim (64) into (temporal=16, height=24,
-width=24) sections. The ViT/merger frontend is a stub: ``input_specs()``
+width=24) sections. The ViT/merger frontend is a stub: ``data.lm.model_batch``
 provides pre-projected patch embeddings. [arXiv:2409.12191]
 """
 from repro.configs.base import ModelConfig, register
@@ -16,7 +16,6 @@ def config() -> ModelConfig:
         head_dim=128, mrope_sections=(16, 24, 24), rope_theta=1_000_000.0,
         vision_tokens=256,
         source="arXiv:2409.12191",
-        long_context="swa_variant", long_context_window=4096,
     )
 
 

@@ -12,7 +12,6 @@ def config() -> ModelConfig:
         num_layers=22, d_model=2048, num_heads=32, num_kv_heads=4,
         d_ff=5632, vocab_size=32000, block_unit=("attn",),
         source="arXiv:2401.02385",
-        long_context="swa_variant", long_context_window=4096,
     )
 
 

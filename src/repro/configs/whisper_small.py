@@ -2,12 +2,12 @@
 
 12 encoder + 12 decoder layers, d_model=768, 12 heads (MHA), d_ff=3072,
 vocab=51865, 1500 encoder frames (30 s of audio after the conv stack, which
-is stubbed — ``input_specs()`` provides post-conv frame embeddings).
+is stubbed — ``data.lm.model_batch`` provides post-conv frame embeddings).
 [arXiv:2212.04356]
 
-Decode shapes exercise the decoder with a self-attention KV cache plus
-precomputed cross-attention KVs. ``long_500k`` is skipped (Whisper's decoder
-is bounded at 448 learned positions; see DESIGN.md §Arch-applicability).
+Decode exercises the decoder with a self-attention KV cache plus
+precomputed cross-attention KVs (the decoder is bounded at 448 learned
+positions).
 """
 from repro.configs.base import ModelConfig, register
 
@@ -20,7 +20,6 @@ def config() -> ModelConfig:
         encoder_layers=12, source_positions=1500,
         pos_embedding="sinusoidal", tie_embeddings=True,
         source="arXiv:2212.04356",
-        long_context="skip",
     )
 
 
@@ -31,7 +30,7 @@ def smoke_config() -> ModelConfig:
         d_ff=512, vocab_size=512, block_unit=("attn",),
         encoder_layers=2, source_positions=64,
         pos_embedding="sinusoidal",
-        source="arXiv:2212.04356", long_context="skip",
+        source="arXiv:2212.04356",
     )
 
 

@@ -13,7 +13,6 @@ def config() -> ModelConfig:
         d_ff=0, vocab_size=50304, block_unit=("mlstm", "slstm"),
         lstm_heads=4,
         source="arXiv:2405.04517",
-        long_context="native",
     )
 
 
@@ -23,7 +22,7 @@ def smoke_config() -> ModelConfig:
         num_layers=2, d_model=256, num_heads=4, num_kv_heads=4,
         d_ff=0, vocab_size=512, block_unit=("mlstm", "slstm"),
         lstm_heads=4,
-        source="arXiv:2405.04517", long_context="native",
+        source="arXiv:2405.04517",
     )
 
 

@@ -17,8 +17,6 @@ def config() -> ModelConfig:
         d_ff=14336, vocab_size=32000, block_unit=_UNIT,
         ssm_state=64, ssm_expand=2, ssm_head_dim=64,
         source="arXiv:2411.15242",
-        long_context="native",   # Mamba2 dominates; shared attn gets a window
-        long_context_window=4096,
     )
 
 
@@ -28,7 +26,7 @@ def smoke_config() -> ModelConfig:
         num_layers=2, d_model=256, num_heads=4, num_kv_heads=4,
         d_ff=512, vocab_size=512, block_unit=("mamba2", "shared_attn"),
         ssm_state=16, ssm_expand=2, ssm_head_dim=32,
-        source="arXiv:2411.15242", long_context="native",
+        source="arXiv:2411.15242",
     )
 
 

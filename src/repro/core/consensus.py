@@ -100,8 +100,7 @@ class ConsensusConfig:
     local_steps: int = 4          # inexact-argmin Adam iterations
     local_lr: float = 1e-3
     use_adam: bool = True         # False: plain SGD (no moments; saves 2
-    #                               param-sized buffers — used for the 314B
-    #                               multi-pod dry-run)
+    #                               param-sized buffers)
     hat_dtype: Optional[str] = None  # "bfloat16" stores theta_hat / q_hat
     #                               replicas at half width (paper accounting
     #                               is unchanged; only the SPMD replica

@@ -299,6 +299,17 @@ def pod_pair_graph() -> WorkerGraph:
     return complete_bipartite_graph(1, 1)
 
 
+def worker_graph(n_workers: int, topology: str = "random") -> WorkerGraph:
+    if n_workers == 2:
+        return pod_pair_graph()
+    if topology == "chain":
+        return chain_graph(n_workers)
+    if topology == "complete":
+        return complete_bipartite_graph(n_workers // 2,
+                                        n_workers - n_workers // 2)
+    return random_bipartite_graph(n_workers, p=0.4, seed=0)
+
+
 def membership_graph(n: int, p: float, seed: int = 0,
                      epoch: int = 0) -> WorkerGraph:
     """Redraw the fleet's communication graph for its current membership.

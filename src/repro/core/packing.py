@@ -268,7 +268,7 @@ def parse_auto_spec(spec: str) -> int:
 def validate_spec_syntax(spec: str) -> None:
     """Tree-independent syntax check of a string group spec; raises
     :class:`GroupSpecError` on anything unrecognized (so a typo'd
-    ``EngineConfig.groups`` / ``REPRO_ADMM_GROUPS`` fails loudly at config
+    ``EngineConfig.groups`` / ``--groups`` fails loudly at config
     construction instead of silently misresolving later)."""
     if spec in ("model", "leaf"):
         return

@@ -22,9 +22,8 @@ backends (selected by ``EngineConfig.mix_backend``):
 * **sharded** — SPMD neighbor mixing: ``shard_map`` over the worker mesh
   axis with *explicit* input/output shardings. Each worker shard holds its
   adjacency row block, all-gathers the peer rows once, and emits its own
-  output block — one explicit collective instead of the XLA-chosen
-  collective-permute chain that triggered the involuntary-remat warning in
-  the multi-pod ADMM train bundle (ROADMAP item).
+  output block — one explicit collective instead of an XLA-chosen
+  collective-permute chain.
 
 All three agree to fp tolerance (``tests/test_topology.py``); dense is
 exactly the old ``engine.tree_mix`` math so the G=1 flat path stays
@@ -219,14 +218,13 @@ class ShardedTopology(DenseTopology):
     value rows, all-gathers the peer rows once (tiled, one explicit
     collective over exactly the worker axis), and writes only its own
     output block — in_specs/out_specs pin every operand's layout so XLA
-    never has to invent the collective-permute schedule that caused the
-    involuntary-remat warning in the multi-pod ADMM bundle. The program
+    never has to invent a collective-permute schedule. The program
     is fully manual over the whole mesh: the feature axis additionally
-    splits over the non-worker axes (TP/FSDP) whenever it divides, so
-    each device mixes only its (N/w, d/rest) tile and no cross-replica
-    resharding is introduced. ``use_pallas`` runs each shard's local
-    row-block matmul on the ``bipartite_mix`` MXU kernel; the residual
-    reduction is inherited from the dense backend."""
+    splits over the non-worker axes (tensor parallel) whenever it
+    divides, so each device mixes only its (N/w, d/rest) tile and no
+    cross-replica resharding is introduced. ``use_pallas`` runs each
+    shard's local row-block matmul on the ``bipartite_mix`` MXU kernel;
+    the residual reduction is inherited from the dense backend."""
 
     mesh: Any = None
     axis: str = "workers"

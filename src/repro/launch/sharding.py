@@ -1,6 +1,6 @@
 """Sharding policy: param-tree PartitionSpecs and activation rules.
 
-Policy (v5e-style 2D/3D meshes):
+Policy (2D meshes: a worker or batch axis and ``model``):
   * Tensor parallelism over ``model``: column-parallel input projections
     (q/k/v, wi_*, up, in_proj, wx), row-parallel output projections
     (o, wo, down, out_proj); vocab-sharded embedding table and untied
@@ -8,10 +8,7 @@ Policy (v5e-style 2D/3D meshes):
   * MoE: expert-parallel over ``model`` when num_experts divides the axis,
     else ff-dim TP inside each expert.
   * ADMM consensus training adds a leading worker axis on every parameter,
-    sharded over the worker mesh axis ("data" single-pod, "pod" multi-pod).
-  * Multi-pod FSDP: the non-TP dimension of 2D weights is additionally
-    sharded over ``data`` inside each pod (grok/mistral-scale replicas
-    cannot live on 16 chips).
+    sharded over the worker mesh axis (``workers`` in ``launch/train.py``).
 
 Every proposed axis is divisibility-checked against the actual leaf shape —
 a spec never over-shards a dimension.
@@ -20,9 +17,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
 
-import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import PartitionSpec
 
 COL_PARALLEL = ("q", "k", "v", "wi_gate", "wi_up", "up", "wx", "in_proj",
                 "igate", "fgate", "router", "lm_head")
@@ -69,8 +65,7 @@ def tp_axes(mesh):
 
 
 def param_spec(path: str, shape: Tuple[int, ...], mesh, cfg, *,
-               worker_axis: Optional[str] = None,
-               fsdp_axis: Optional[str] = None) -> PartitionSpec:
+               worker_axis: Optional[str] = None) -> PartitionSpec:
     """PartitionSpec for one parameter leaf.
 
     path: jax keystr of the leaf (e.g. "['stack']['units']['p0']['mlp']
@@ -97,15 +92,15 @@ def param_spec(path: str, shape: Tuple[int, ...], mesh, cfg, *,
             ff_tp = tp
         if rank == 4:      # (n_units, E, in, out)
             if ep:
-                return named(None, ep, fsdp_axis, ff_tp)
-            return named(None, None, fsdp_axis, tp)
+                return named(None, ep, None, ff_tp)
+            return named(None, None, None, tp)
         if rank == 3:
             if ep:
-                return named(ep, fsdp_axis, ff_tp)
-            return named(None, fsdp_axis, tp)
+                return named(ep, None, ff_tp)
+            return named(None, None, tp)
 
     if path.endswith("['table']"):      # embedding (V, D)
-        return named(tp, fsdp_axis)
+        return named(tp, None)
 
     is_col = any(f"'{n}'" in path for n in COL_PARALLEL)
     is_row = any(f"'{n}'" in path for n in ROW_PARALLEL)
@@ -113,23 +108,12 @@ def param_spec(path: str, shape: Tuple[int, ...], mesh, cfg, *,
         axes = [None] * rank
         # last two dims are (in, out); leading dims are scan/stack axes
         if is_col and not is_row:
-            axes[-1], axes[-2] = tp, fsdp_axis
+            axes[-1] = tp
         else:
-            axes[-1], axes[-2] = fsdp_axis, tp
+            axes[-2] = tp
         return named(*axes)
     # conv weights, scales, biases, gates: replicate (modulo worker axis)
     return named(*([None] * rank))
-
-
-def params_shardings(param_shapes, mesh, cfg, *, worker_axis=None,
-                     fsdp_axis=None):
-    """Map a pytree of ShapeDtypeStructs to NamedShardings."""
-    def leaf(path, x):
-        spec = param_spec(jax.tree_util.keystr(path), x.shape, mesh, cfg,
-                          worker_axis=worker_axis, fsdp_axis=fsdp_axis)
-        return NamedSharding(mesh, spec)
-
-    return jax.tree_util.tree_map_with_path(leaf, param_shapes)
 
 
 # ------------------------------------------------------ activation rules --
@@ -139,9 +123,8 @@ def activation_rules(mesh, cfg, *, batch_axes=("data",),
     """Logical-name -> mesh-axis map for with_sharding_constraint calls.
 
     batch_axes: axes carrying the (global or per-worker) batch dimension.
-    worker_mode: under ADMM a mesh axis carries workers (``worker_axis``:
-    'data' on the single pod, 'pod' across pods); the per-worker batch
-    stays unsharded inside each worker slice.
+    worker_mode: under ADMM a mesh axis carries workers (``worker_axis``);
+    the per-worker batch stays unsharded inside each worker slice.
     """
     tp = tp_axes(mesh)
     batch = tuple(a for a in batch_axes if a in mesh.shape) or None
@@ -151,16 +134,10 @@ def activation_rules(mesh, cfg, *, batch_axes=("data",),
     # inside each expert may use the model axis only when the expert dim
     # does not (a PartitionSpec may use each mesh axis once).
     ep = expert_axis(mesh, cfg)
-    expert_parallel = ep == tp
-    import os
     rules: Dict[str, Any] = {
         "batch": None if worker_mode else batch,
         "worker": worker_axis if worker_axis in mesh.shape else None,
         "seq": None,
-        # sequence-parallel residual (Megatron-SP analog): shard the
-        # residual stream's S over the model axis so TP all-reduces lower
-        # to reduce-scatter + all-gather pairs. Opt-in via env for §Perf.
-        "res_seq": tp if os.environ.get("REPRO_SEQ_PARALLEL") else None,
         "embed": None,
         # MoE expert ff: "model" only on the EP mesh; dense archs use full TP
         "ff": ("model" if ep == "expert" else
@@ -175,71 +152,5 @@ def activation_rules(mesh, cfg, *, batch_axes=("data",),
         # of their own, shard the capacity dim instead (memory relief for
         # the (E*C, D) buffer at 1M-token prefill).
         "expert_cap": None if ep else tp,
-        "kv_seq": None,
     }
     return rules
-
-
-def cache_spec(mesh, cfg, batch: int, *, batch_axes=("data",),
-               shard_kv_seq: bool = False) -> Dict[str, Any]:
-    """Logical rules for serve caches (used by steps.serve_step)."""
-    rules = activation_rules(mesh, cfg, batch_axes=batch_axes)
-    total_batch_shards = _mesh_axis_size(mesh, rules["batch"])
-    if batch % max(total_batch_shards, 1) != 0:
-        rules["batch"] = None
-    if shard_kv_seq:
-        rules["kv_seq"] = "data"
-    return rules
-
-
-# ----------------------------------------------------- serve-cache specs --
-def cache_leaf_spec(path: str, shape: Tuple[int, ...], mesh, cfg, *,
-                    batch_axis) -> PartitionSpec:
-    """PartitionSpec for one decode-cache leaf.
-
-    Leaves under ['units'] carry a leading stacked-layer axis (kept
-    unsharded); the next axis is batch. Per leaf kind we pick ONE model-axis
-    dimension in preference order (kv-heads > head-dim > kv-seq) so the big
-    KV buffers divide across the whole mesh: e.g. mistral-large decode_32k is
-    ~3 TB of cache; batch x head-dim sharding brings it to ~12 GB/chip.
-    """
-    tp = "model"
-    tp_size = _mesh_axis_size(mesh, tp)
-    stacked = "'units'" in path
-    rest = shape[1:] if stacked else shape
-    name = path.rsplit("'", 3)[-2] if "'" in path else path
-    axes = [None] * len(rest)
-    if rest and rest[0] % max(_mesh_axis_size(mesh, batch_axis), 1) == 0:
-        axes[0] = batch_axis
-
-    def try_axis(i):
-        if 0 < i < len(rest) and rest[i] % tp_size == 0 and axes[i] is None:
-            axes[i] = tp
-            return True
-        return False
-
-    if name in ("k", "v", "cross_k", "cross_v") and len(rest) == 4:
-        _ = try_axis(2) or try_axis(3) or try_axis(1)   # KV > HD > seq
-    elif name in ("k_pages", "v_pages") and len(rest) == 4:
-        # paged pools (num_pages, page_size, KV, hd): pages ride the batch
-        # axis (axes[0] above), heads/head-dim the model axis — pages
-        # never shard over page_size (a page is the DMA unit)
-        _ = try_axis(2) or try_axis(3)
-    elif name == "state" and len(rest) == 4:            # mamba (B,H,P,N)
-        _ = try_axis(1) or try_axis(2)
-    elif name == "conv" and len(rest) == 3:             # (B,K-1,C)
-        try_axis(2)
-    elif name in ("c", "n", "m", "h") and len(rest) >= 2:  # xlstm (B,H,...)
-        try_axis(1)
-    lead = (None,) if stacked else ()
-    return PartitionSpec(*(lead + tuple(axes)))
-
-
-def cache_shardings(cache_shapes, mesh, cfg, *, batch_axis="data"):
-    """Map a decode-cache pytree of ShapeDtypeStructs to NamedShardings."""
-    def leaf(path, x):
-        spec = cache_leaf_spec(jax.tree_util.keystr(path), x.shape, mesh,
-                               cfg, batch_axis=batch_axis)
-        return NamedSharding(mesh, spec)
-
-    return jax.tree_util.tree_map_with_path(leaf, cache_shapes)

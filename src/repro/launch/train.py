@@ -3,11 +3,10 @@
 CPU-friendly end-to-end entry point: trains a reduced or full architecture
 on the synthetic-but-learnable token stream, with the paper's censoring and
 quantization live, logging loss / consensus error / transmitted bits, and
-checkpointing. On real hardware the same bundle runs against the production
-mesh (see dryrun.py). Here the workers are placed on the devices the
-process sees (``worker_placement``): several workers to a device, one
-worker per device, or, with more devices than workers, each worker's
-parameters split over several devices by the tensor-parallel rules of
+checkpointing. The workers are placed on the devices the process sees
+(``worker_placement``): several workers to a device, one worker per
+device, or, with more devices than workers, each worker's parameters split
+over several devices by the tensor-parallel rules of
 ``launch/sharding.py``.
 
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
@@ -37,6 +36,7 @@ import numpy as np
 from repro.checkpoint import npz as ckpt
 from repro.configs import base
 from repro.core import engine as E
+from repro.core import graph as G
 from repro.core import topology as topo_lib
 from repro.core.censoring import CensorConfig
 from repro.core.quantization import QuantConfig
@@ -49,7 +49,6 @@ from repro.obs import trace as obs_trace
 from repro.obs.ledger import CommLedger
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.runtime import partitioning as P
-from repro.runtime import steps as ST
 
 
 def parse_churn(spec):
@@ -328,7 +327,7 @@ def admm_setup(cfg, args) -> AdmmSetup:
 
     return AdmmSetup(
         cfg=cfg, args=args,
-        graph=ST.worker_graph(args.workers, args.topology),
+        graph=G.worker_graph(args.workers, args.topology),
         ecfg=engine_config(cfg, args),
         solver=E.InexactSolver(grad_fn=grad_fn,
                                local_steps=args.local_steps,
@@ -473,7 +472,7 @@ def campaign_lm_run(section, quantize=True, groups="model",
     from repro.campaign.store import Claim, Record
 
     cfg = base.get_smoke_config(arch)
-    graph = ST.worker_graph(workers, "random")
+    graph = G.worker_graph(workers, "random")
     ecfg = E.EngineConfig(
         rho=rho, censor=CensorConfig(tau0=tau0, xi=xi),
         quantize=QuantConfig(b0=bits, omega=omega) if quantize else None,

@@ -520,8 +520,6 @@ def attention_apply(params, dims: AttnDims, x, positions, *,
     # the flash kernel takes q scaled: 1/sqrt(D) folded into RoPE's float32
     # arithmetic, before q's one cast back to the compute dtype
     q_scale = float(hd) ** -0.5 if path == "flash" else None
-    x = P.gather_tokens(x)       # sequence-parallel boundary (no-op unless
-    #                              the res_seq rule is active)
     q = dense(params["q"], x).reshape(b, s, h, hd)
 
     new_cache = None
@@ -530,10 +528,8 @@ def attention_apply(params, dims: AttnDims, x, positions, *,
         kv_positions = jnp.zeros((b, k.shape[1]), jnp.int32)  # all visible
         eff_causal, eff_window = False, None
     else:
-        k = P.gather_tokens(dense(params["k"], x).reshape(b, s, kv, hd),
-                            dim=1)
-        v = P.gather_tokens(dense(params["v"], x).reshape(b, s, kv, hd),
-                            dim=1)
+        k = dense(params["k"], x).reshape(b, s, kv, hd)
+        v = dense(params["v"], x).reshape(b, s, kv, hd)
         if use_rope:
             q = apply_rope(q, positions, rope_theta, mrope_sections,
                            scale=q_scale)
@@ -546,7 +542,7 @@ def attention_apply(params, dims: AttnDims, x, positions, *,
                 if _use_paged_kernel(s, window):
                     out = _paged_attn_kernel_out(new_cache, q, q_positions)
                     out = dense(params["o"], out.reshape(b, s, h * hd))
-                    return P.constrain(out, ("batch", "res_seq", "embed")), \
+                    return P.constrain(out, ("batch", "seq", "embed")), \
                         new_cache
                 k, v, kv_positions = paged_gather(new_cache, head_dim=hd)
             else:
@@ -568,11 +564,7 @@ def attention_apply(params, dims: AttnDims, x, positions, *,
         mask = _attn_mask(q_positions, kv_positions, eff_causal, eff_window)
         out = mha(q, k, v, mask)
     out = dense(params["o"], out.reshape(b, s, h * hd))
-    # "res_seq" is the sequence-parallel residual point: after the
-    # row-parallel o-proj the runtime may shard S over the model axis, so
-    # the TP all-reduce lowers to a reduce-scatter (half the wire bytes)
-    # and the norms between blocks run on S/TP tokens per chip.
-    return P.constrain(out, ("batch", "res_seq", "embed")), new_cache
+    return P.constrain(out, ("batch", "seq", "embed")), new_cache
 
 
 # -------------------------------------------------------------------- MLP --
@@ -586,10 +578,8 @@ def mlp_init(key, d_model: int, d_ff: int):
 
 
 def mlp_apply(params, x, activation: str = "silu"):
-    x = P.gather_tokens(x)       # sequence-parallel boundary
     gate = dense(params["wi_gate"], x)
     up = dense(params["wi_up"], x)
     act = jax.nn.silu(gate) if activation == "silu" else jax.nn.gelu(gate)
     h = P.constrain(act * up, ("batch", "seq", "ff"))
-    return P.constrain(dense(params["wo"], h),
-                       ("batch", "res_seq", "embed"))
+    return P.constrain(dense(params["wo"], h), ("batch", "seq", "embed"))

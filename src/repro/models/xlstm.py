@@ -150,8 +150,7 @@ def mlstm_apply(params, cfg, x, *, cache: Optional[dict] = None,
                  jnp.zeros((b, heads, dh), jnp.float32),
                  jnp.full((b, heads), -1e30, jnp.float32))
 
-    import os
-    if use_chunked and s > 1 and not os.environ.get("REPRO_MLSTM_SCAN"):
+    if use_chunked and s > 1:
         hmat, state = _mlstm_chunked(q, k, v, i_pre, f_pre, state)
         hflat = hmat.astype(x.dtype).reshape(b, s, d_inner)
         out = layers.dense(params["down"], hflat * jax.nn.silu(z))
@@ -256,11 +255,7 @@ def slstm_apply(params, cfg, x, *, cache: Optional[dict] = None,
     # remat the cell: without it the scan stacks per-step gate residuals
     # (S x B x H x 4dh) for backward — the dominant HBM term at 4k train.
     # Recomputing the gates from the (small) carry is far cheaper.
-    # (REPRO_SLSTM_NO_REMAT reproduces the §Perf baseline.)
-    import os
-    step_fn = step if os.environ.get("REPRO_SLSTM_NO_REMAT") \
-        else jax.checkpoint(step)
-    state, hs = jax.lax.scan(step_fn, state, wx.swapaxes(0, 1))
+    state, hs = jax.lax.scan(jax.checkpoint(step), state, wx.swapaxes(0, 1))
     h = hs.swapaxes(0, 1).reshape(b, s, d).astype(x.dtype)
     new_cache = ({"c": state[0], "n": state[1], "m": state[2],
                   "h": state[3]} if cache is not None else None)

@@ -1,14 +1,13 @@
 """Logical-axis sharding: model code names axes, the runtime maps them.
 
 Model code calls ``constrain(x, ("batch", "seq", "embed"))``; if a mesh
-context is active (set by the launcher / dry-run), the logical names are
+context is active (set by the launcher), the logical names are
 translated to mesh axes through the current rule set and a
 ``with_sharding_constraint`` is applied. Without a context (unit tests,
 single-device smoke runs) it is the identity, so models stay mesh-agnostic.
 
 Rule sets are plain dicts  logical name -> mesh axis (or None / tuple).
-The standard rules for the production meshes live in
-``repro.launch.sharding``.
+The tensor-parallel rules live in ``repro.launch.sharding``.
 """
 from __future__ import annotations
 
@@ -66,19 +65,3 @@ def constrain(x: jax.Array, logical_axes: Tuple[Optional[str], ...]):
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, spec))
 
-
-def gather_tokens(x: jax.Array, dim: int = -2):
-    """Sequence-parallel gather boundary (Megatron-SP): force dim `dim`
-    (the token axis) replicated, leaving every other dim unconstrained.
-    Active only when the 'res_seq' rule shards the residual stream —
-    GSPMD then lowers the preceding TP all-reduce to reduce-scatter and
-    inserts the matching all-gather exactly here (before qkv / wi), instead
-    of leaking seq-sharding into attention."""
-    mesh = current_mesh()
-    if mesh is None or current_rules().get("res_seq") is None:
-        return x
-    spec = [PartitionSpec.UNCONSTRAINED] * x.ndim
-    dim = dim % x.ndim
-    spec[dim] = None
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, PartitionSpec(*spec)))

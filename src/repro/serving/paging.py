@@ -294,8 +294,7 @@ def init_paged_cache(cfg, max_seqs: int, num_pages: int, page_size: int,
 # -------------------------------------------------------- leaf taxonomy --
 def _leaf_info(path):
     """(name, stacked) for one cache leaf — stacked leaves carry the
-    leading scanned-unit axis (same convention as
-    ``launch/sharding.cache_leaf_spec``)."""
+    leading scanned-unit axis."""
     s = jax.tree_util.keystr(path)
     name = s.rsplit("'", 3)[-2] if "'" in s else s
     return name, "'units'" in s

@@ -1,6 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS device-count override here — tests
-run single-device by design (the 512-device mesh is exclusively the
-dry-run's, spawned in its own process)."""
+run single-device by design; a test that needs several virtual devices
+spawns its own process with them."""
 import numpy as np
 import pytest
 

@@ -36,6 +36,19 @@ def test_pod_pair():
     assert g.n == 2 and g.num_edges == 1
 
 
+@pytest.mark.parametrize("n,topology", [(2, "random"), (6, "chain"),
+                                        (6, "complete"), (8, "random")])
+def test_worker_graph_from_cli(n, topology):
+    """The trainer's ``--workers``/``--topology`` graphs: n workers,
+    bipartite and connected, with at least one head and one tail."""
+    g = G.worker_graph(n, topology)
+    g.validate()          # asserts bipartite + connected + identities
+    assert g.n == n
+    assert g.head_mask.any() and g.tail_mask.any()
+    assert g.head_mask[g.edges[:, 0]].all()
+    assert (~g.head_mask[g.edges[:, 1]]).all()
+
+
 @settings(max_examples=25, deadline=None)
 @given(n=st.integers(4, 40), p=st.floats(0.05, 0.9),
        seed=st.integers(0, 10_000))

@@ -1,6 +1,4 @@
-"""Sharding policy logic on an abstract 16x16 (and 2x16x16) mesh."""
-import jax
-import numpy as np
+"""Sharding policy logic on an abstract 16x16 mesh."""
 import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
@@ -11,10 +9,6 @@ from repro.launch import sharding as SH
 @pytest.fixture(scope="module")
 def mesh():
     return AbstractMesh((16, 16), ("data", "model"))
-
-
-def multi_mesh():
-    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_col_parallel(mesh):
@@ -29,13 +23,6 @@ def test_row_parallel(mesh):
     spec = SH.param_spec("['stack']['units']['p0']['attn']['o']['w']",
                          (22, 2048, 2048), mesh, cfg)
     assert spec == P(None, "model", None)
-
-
-def test_fsdp_axis_added(mesh):
-    cfg = base.get_config("mistral-large-123b")
-    spec = SH.param_spec("['stack']['units']['p0']['mlp']['wi_gate']['w']",
-                         (88, 12288, 28672), mesh, cfg, fsdp_axis="data")
-    assert spec == P(None, "data", "model")
 
 
 def test_worker_axis_prepended(mesh):
@@ -89,31 +76,3 @@ def test_activation_rules_expert_exclusive(mesh):
     rules2 = SH.activation_rules(mesh, cfg2)
     assert rules2["expert"] is None
     assert rules2["ff"] == "model"
-
-
-def test_cache_leaf_specs(mesh):
-    cfg = base.get_config("mistral-large-123b")  # kv=8, hd=128
-    # stacked attn kv cache (L, B, W, KV, HD): kv=8 not divisible, hd=128 is
-    spec = SH.cache_leaf_spec("['units']['p0']['k']",
-                              (88, 128, 32768, 8, 128), mesh, cfg,
-                              batch_axis="data")
-    assert spec == P(None, "data", None, None, "model")
-    cfg2 = base.get_config("zamba2-7b")          # kv=32 divisible
-    spec2 = SH.cache_leaf_spec("['units']['p0']['k']",
-                               (13, 128, 32768, 32, 112), mesh, cfg2,
-                               batch_axis="data")
-    assert spec2 == P(None, "data", None, "model", None)
-    # mamba state (L, B, H, P, N)
-    spec3 = SH.cache_leaf_spec("['units']['p0']['state']",
-                               (67, 128, 112, 64, 64), mesh, cfg2,
-                               batch_axis="data")
-    assert spec3[1] == "data"
-
-
-def test_multi_pod_tuple_axis():
-    mesh = multi_mesh()
-    cfg = base.get_config("grok-1-314b")
-    spec = SH.param_spec("['stack']['units']['p0']['attn']['q']['w']",
-                         (64, 6144, 6144), mesh, cfg,
-                         fsdp_axis=("pod", "data"))
-    assert spec == P(None, ("pod", "data"), "model")
