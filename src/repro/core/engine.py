@@ -36,49 +36,58 @@ Newton ``PrimalSolver`` objects of ``core/solvers.py`` (convex experiments);
 :class:`InexactSolver` runs K Adam/SGD steps on the augmented Lagrangian
 (neural workloads; the inexact-ADMM deviation recorded in DESIGN.md §5).
 
-**Packed fast path** (DESIGN.md §Packing): multi-leaf trees do NOT loop
-over leaves. The whole tree is flattened into one contiguous ``(N, D)``
-buffer (``core/packing.py``), the per-group ranges come from one
-segment-reduced max, the stochastic-rounding uniforms are drawn once for
-the whole buffer with the phase key, and the quantize/reconstruct chain
-runs as ONE call — the fused Pallas kernel
-(``kernels.ops.stoch_quantize_grouped``) when ``use_pallas_quant=True``,
-its bit-identical jnp oracle otherwise. The group-censor norm reduction and
-``tree_mix`` ride the same packed view. The relevant
+**Exchange layout** (DESIGN.md §Packing): quantize, censor, the phase
+mixes and the dual work on each leaf of the tree in its own shape; no
+``(N, D)`` row is built on the default path. On a TPU a leaf lies in
+whole (8, 128) tiles, while a packed row of a few workers fills a
+fraction of each tile's sublanes, so packing costs a relayout copy and
+every pass over the row runs at a fraction of the vector unit. The
+quantizer reduces per-leaf ranges to ``(N, G)``, runs the Eq. (18)
+schedule once, and rounds each leaf with its own columns of the packed
+draw (:func:`quantization.uniform_columns`), so it gives the packed
+round's numbers bit for bit; the censor sums per-leaf squared norms to
+``(N, G)``. One body of each serves workers on one device and workers
+split over devices (inside ``shard_map``). The packed ``(N, D)`` buffer
+(``core/packing.py``) remains where a Pallas kernel takes one:
+``use_pallas_quant`` runs the fused ``kernels.ops.
+stoch_quantize_grouped_fused`` on it (its jnp oracle is the leaf path's
+parity reference), and ``use_pallas_mix`` the mix kernels. The relevant
 :class:`EngineConfig` knobs:
 
 * ``groups``: ``"model"`` (G=1), ``"leaf"``, a named block spec
   (``"block:attn,mlp,embed"``), ``"auto:K"``, an explicit leaf->group
   tuple, or index buckets ``((0, 1), (2,))`` — every spec compiles to the
-  same per-leaf id map (:func:`resolve_groups`, DESIGN.md §Groups) and
-  runs as one fused call on the packed buffer; the fused call computes
-  the grouped range reduction *inside* the quantize kernel/oracle (no
-  separate side-information pass over the (N, D) buffer);
-* ``use_pallas_quant`` / ``use_pallas_mix``: route the packed buffer
-  through the Pallas kernels instead of the jnp oracles;
-* ``censor_mode="group"``: the per-group norm test reduces over the packed
-  buffer with one segment-sum.
+  same per-leaf id map (:func:`resolve_groups`, DESIGN.md §Groups);
+* ``use_pallas_quant`` / ``use_pallas_mix``: route the exchange through
+  the Pallas kernels over the packed buffer instead;
+* ``censor_mode="group"``: the per-group norm test on the ``(N, G)``
+  sums.
 
-PRNG compatibility note: the stochastic-rounding uniforms are drawn with
-the phase key directly on the full model width — for a one-leaf tree this
-is exactly the seed draw, so the G=1 flat path reproduces the seed
+``obs.metrics.engine_exchange_layout`` counts, at trace time, the layout
+each part took.
+
+PRNG compatibility note: the stochastic-rounding uniforms are the phase
+key's draw over the full model width — for a one-leaf tree this is
+exactly the seed draw, so the G=1 flat path reproduces the seed
 ``cq_ggadmm`` trajectories bit-for-bit (golden tests in
-``tests/test_engine.py``); for multi-leaf trees the packed draw replaces
-the per-leaf key split of the old unfused loop (kept as
+``tests/test_engine.py``); for multi-leaf trees each leaf draws its own
+columns of the draw over the packed width, which replaces the per-leaf
+key split of the old unfused loop (kept as
 ``grouped_quantize_step_unfused`` for parity benchmarks). A phase that
-quantizes only its acting workers' rows draws only those rows
-(``quantization.uniform_rows``): bit for bit the same rows of the draw
-over all N workers, so the row path changes no number.
+quantizes only its acting workers' rows draws only those rows: bit for
+bit the same rows of the draw over all N workers, so the row path
+changes no number.
 
 **Acting rows** (DESIGN.md §Engine): in an alternating step each phase
-solves and quantizes only its own group's workers — their rows are
-static per graph — and writes them back into the N-row state. The
-masked path (compute all N rows, keep the acting ones) remains where rows
-cannot be split off: the Jacobian step (every worker acts), workers
-placed across devices (a mesh or the sharded backend), and, for the
-solve only, an :class:`ExactSolver` or a call without a batch, whose
-problem data is not per-row input. ``obs.metrics.engine_phase_rows``
-counts, at trace time, the rows each part computes on each path.
+mixes for and solves, quantizes and censors only its own group's
+workers — their rows are static per graph — and writes them back into
+the N-row state. The masked path (compute all N rows, keep the acting
+ones) remains where rows cannot be split off: the Jacobian step (every
+worker acts), workers placed across devices (a mesh or the sharded
+backend), and, for the mix and the solve only, an :class:`ExactSolver`
+or a call without a batch, whose problem data is not per-row input.
+``obs.metrics.engine_phase_rows`` counts, at trace time, the rows each
+part computes on each path.
 """
 from __future__ import annotations
 
@@ -97,7 +106,8 @@ from repro.core import topology as topo_lib
 from repro.core.censoring import CensorConfig, threshold
 from repro.core.graph import WorkerGraph
 from repro.core.quantization import QuantConfig
-from repro.obs.metrics import (engine_phase_rows_counter,
+from repro.obs.metrics import (engine_exchange_layout_counter,
+                               engine_phase_rows_counter,
                                engine_worker_layout_counter)
 
 _EPS = 1e-12
@@ -106,11 +116,17 @@ Tree = Any
 
 
 # ------------------------------------------------------------- tree utils --
+def _over_worker(reduce_fn, x: jax.Array) -> jax.Array:
+    """``reduce_fn`` over every axis of ``x`` but the leading worker axis,
+    in ``x``'s own shape: (N,)."""
+    return reduce_fn(x, axis=tuple(range(1, x.ndim)))
+
+
 def tree_worker_dot(a: Tree, b: Tree) -> jax.Array:
     """Per-worker inner product over all leaves: (N,)."""
     parts = jax.tree_util.tree_map(
-        lambda x, y: jnp.sum((x.astype(jnp.float32) * y.astype(jnp.float32))
-                             .reshape(x.shape[0], -1), axis=-1), a, b)
+        lambda x, y: _over_worker(
+            jnp.sum, x.astype(jnp.float32) * y.astype(jnp.float32)), a, b)
     return sum(jax.tree_util.tree_leaves(parts))
 
 
@@ -121,8 +137,7 @@ def tree_worker_sqnorm(a: Tree) -> jax.Array:
 def tree_worker_maxabs(a: Tree) -> jax.Array:
     """Per-worker max |.| over all leaves: (N,)."""
     parts = jax.tree_util.tree_map(
-        lambda x: jnp.max(jnp.abs(x.astype(jnp.float32))
-                          .reshape(x.shape[0], -1), axis=-1), a)
+        lambda x: _over_worker(jnp.max, jnp.abs(x.astype(jnp.float32))), a)
     leaves = jax.tree_util.tree_leaves(parts)
     return jnp.max(jnp.stack(leaves, axis=0), axis=0)
 
@@ -173,12 +188,9 @@ def tree_rel_err(a: Tree, b: Tree) -> float:
 def tree_mix(adjacency: jax.Array, a: Tree, use_kernel: bool = False) -> Tree:
     """Neighbor sum: out_n = sum_m A[n, m] leaf_m (dense backend on a bare
     adjacency array; the engine itself mixes through the pluggable
-    :mod:`~repro.core.topology` backends).
-
-    Multi-leaf trees with a uniform leaf dtype mix through the packed
-    ``(N, D)`` view — one matmul (or one Pallas ``bipartite_mix`` call)
-    for the whole tree instead of one per leaf. Mixed-dtype trees and
-    single leaves keep the leaf-wise path (identical semantics)."""
+    :mod:`~repro.core.topology` backends), each leaf in its own shape;
+    ``use_kernel`` mixes the packed ``(N, D)`` view with one Pallas
+    ``bipartite_mix`` call for the whole tree."""
     return topo_lib.mix_dense(adjacency, a, use_kernel=use_kernel)
 
 
@@ -343,13 +355,16 @@ def grouped_quantize_step(
     """One grouped stochastic-quantization round (Eqs. 14-20, group-wise).
 
     Single-leaf trees run the direct (seed-bit-compatible) path; multi-leaf
-    trees run the fused packed-buffer path — one segment-reduced range, one
-    uniform draw, one quantize call for the whole tree.
+    trees run leaf by leaf, each leaf in its own shape
+    (:func:`grouped_quantize_step_leaves`), or, with ``use_kernel``, the
+    fused Pallas kernel over the packed ``(N, D)`` buffer
+    (:func:`_grouped_quantize_step_packed`). All draw each parameter's
+    uniform from the same place of the packed draw.
 
     With ``rows``, ``state`` and ``theta`` hold only those rows of an
     ``n_workers``-row state, and each draws exactly its own rows of the
-    uniforms the full state would draw (:func:`_uniforms`), so the result
-    equals those rows of the full round.
+    uniforms the full state would draw, so the result equals those rows of
+    the full round.
 
     Returns ``(new_state, candidate_tree, bits (N, G), payload (N,))`` where
     payload = sum_g b_g d_g + G * overhead — each group ships its own
@@ -359,8 +374,12 @@ def grouped_quantize_step(
         return grouped_quantize_step_unfused(state, theta, key, cfg,
                                              group_ids, use_kernel,
                                              rows, n_workers)
-    return _grouped_quantize_step_packed(state, theta, key, cfg, group_ids,
-                                         use_kernel, rows, n_workers)
+    if use_kernel:
+        return _grouped_quantize_step_packed(state, theta, key, cfg,
+                                             group_ids, use_kernel, rows,
+                                             n_workers)
+    return grouped_quantize_step_leaves(state, theta, key, cfg, group_ids,
+                                        rows, n_workers)
 
 
 def _uniforms(key: jax.Array, shape: Tuple[int, ...],
@@ -408,11 +427,11 @@ def _grouped_quantize_step_packed(
 ) -> Tuple[GroupQuantState, Tree, jax.Array, jax.Array]:
     """Fused path: the whole grouped round — range reduction, Eq. (18) bit
     schedule, quantize, degenerate passthrough — in one call over the
-    packed buffer. ``use_kernel=True`` routes it through the single
-    ``pallas_call`` of ``kernels.stoch_quantize_grouped_fused`` (the range
-    min/max happens *inside* the kernel; no separate side-information pass
-    appears in the traced program); ``use_kernel=False`` runs the
-    bit-identical jnp oracle."""
+    packed buffer, for the Pallas kernel's sake. ``use_kernel=True`` routes
+    it through the single ``pallas_call`` of
+    ``kernels.stoch_quantize_grouped_fused`` (the range min/max happens
+    *inside* the kernel); ``use_kernel=False`` runs its bit-identical jnp
+    oracle, the parity reference of :func:`grouped_quantize_step_leaves`."""
     pk = packing.make_packing(theta, group_ids)
     theta_p = packing.pack(pk, theta)                     # (N, D) f32
     qprev_p = packing.pack(pk, state.q_hat)               # (N, D) f32
@@ -432,12 +451,6 @@ def _grouped_quantize_step_packed(
         state.initialized, gid_cols, group_runs=pk.group_runs,
         omega=cfg.omega, b0=cfg.b0, b_max=cfg.b_max)
     return _finish_packed_step(state, pk, out, range_new, bits, delta, cfg)
-
-
-def _over_worker(reduce_fn, x: jax.Array) -> jax.Array:
-    """``reduce_fn`` over every axis of ``x`` but the leading worker axis:
-    (N,)."""
-    return reduce_fn(x, axis=tuple(range(1, x.ndim)))
 
 
 def _on_blocks(topo: topo_lib.ShardedTopology, fn, in_specs, out_specs,
@@ -471,54 +484,101 @@ def _quant_specs(topo: topo_lib.ShardedTopology):
                            initialized=rows)
 
 
+def _quantize_leaves(t_leaves, q_leaves, bits_prev, range_prev, initialized,
+                     uniforms, cfg: QuantConfig, group_ids: Sequence[int],
+                     n_groups: int, reduce_range=None):
+    """The grouped round (Eqs. 14-20, group-wise) leaf by leaf, each leaf in
+    its own shape, with no packed row: per-leaf ``max |theta - q_hat|``
+    reduced to (n, G) (and by ``reduce_range`` where the leaves are blocks
+    of larger ones), the Eq. (18) schedule, and leaf ``i`` rounded with
+    ``uniforms(i, leaf)``. Returns ``(q_hat leaves, range_new, bits,
+    delta)``."""
+    from repro.kernels.ref import stoch_quantize_ref
+    f32 = jnp.float32
+    range_new = _group_reduce(
+        [_over_worker(jnp.max, jnp.abs(t.astype(f32) - q.astype(f32)))
+         for t, q in zip(t_leaves, q_leaves)],
+        group_ids, n_groups, lambda s: jnp.max(s, axis=0))       # (n, G)
+    if reduce_range is not None:
+        range_new = reduce_range(range_new)
+    bits, delta, degen = quant_lib.bit_schedule(
+        bits_prev, range_new, range_prev, initialized, cfg.omega, cfg.b0,
+        cfg.b_max)
+    out = []
+    for i, (t, q) in enumerate(zip(t_leaves, q_leaves)):
+        g = group_ids[i]
+        q32 = q.astype(f32)
+        fresh = stoch_quantize_ref(t.astype(f32), q32, uniforms(i, t),
+                                   delta[:, g], range_new[:, g])
+        keep = degen[:, g].reshape((t.shape[0],) + (1,) * (t.ndim - 1))
+        out.append(jnp.where(keep, q32, fresh).astype(q.dtype))
+    return out, range_new, bits, delta
+
+
+def _packed_columns(theta: Tree) -> Tuple[int, list]:
+    """The packed row's width D and each leaf's first column in it."""
+    widths = [int(x.size // x.shape[0])
+              for x in jax.tree_util.tree_leaves(theta)]
+    return sum(widths), [int(o) for o in np.cumsum([0] + widths[:-1])]
+
+
+def grouped_quantize_step_leaves(
+    state: GroupQuantState, theta: Tree, key: jax.Array, cfg: QuantConfig,
+    group_ids: Sequence[int], rows: Optional[np.ndarray] = None,
+    n_workers: Optional[int] = None,
+) -> Tuple[GroupQuantState, Tree, jax.Array, jax.Array]:
+    """The packed round (:func:`_grouped_quantize_step_packed`) leaf by
+    leaf on one device, bit for bit: each leaf draws its own columns of
+    the packed draw (:func:`quantization.uniform_columns`), and, with
+    ``rows``, only those rows of an ``n_workers``-row state."""
+    leaves, treedef = jax.tree_util.tree_flatten(theta)
+    n = n_workers if rows is not None else leaves[0].shape[0]
+    d, offsets = _packed_columns(theta)
+
+    def uniforms(i, t):
+        return quant_lib.uniform_columns(key, n, d, offsets[i],
+                                         (n,) + t.shape[1:], rows=rows)
+
+    out, range_new, bits, delta = _quantize_leaves(
+        leaves, jax.tree_util.tree_leaves(state.q_hat), state.bits_prev,
+        state.range_prev, state.initialized, uniforms, cfg, group_ids,
+        state.n_groups)
+    return _finish_step(state, jax.tree_util.tree_unflatten(treedef, out),
+                        range_new, bits, delta, cfg,
+                        group_dims(theta, group_ids))
+
+
 def grouped_quantize_step_split(
     state: GroupQuantState, theta: Tree, key: jax.Array, cfg: QuantConfig,
     group_ids: Sequence[int], topo: topo_lib.ShardedTopology,
 ) -> Tuple[GroupQuantState, Tree, jax.Array, jax.Array]:
-    """The packed round (:func:`_grouped_quantize_step_packed`) for workers
-    whose leaves are split over several devices (``topo.leaf_specs``), on
-    each device's own blocks, leaf by leaf: packing the leaves into one
-    row would gather each worker's whole row onto every one of its
-    devices. Each block draws the uniforms at its own positions of the
-    packed draw (:func:`quantization.uniform_columns`), so every parameter
-    is rounded with the number the packed path gives it; only the (N, G)
-    ranges reduce over the worker's devices."""
+    """:func:`grouped_quantize_step_leaves` for workers whose leaves are
+    split over several devices (``topo.leaf_specs``), on each device's own
+    blocks: each block draws the uniforms at its own positions of the
+    packed draw, so every parameter is rounded with the number the packed
+    path gives it; only the (N, G) ranges reduce over the worker's
+    devices."""
     from jax.sharding import PartitionSpec
-    from repro.kernels.ref import stoch_quantize_ref
     leaves = jax.tree_util.tree_leaves(theta)
     n = leaves[0].shape[0]
     shapes = [x.shape for x in leaves]
-    widths = [int(x.size // n) for x in leaves]
-    offsets = [int(o) for o in np.cumsum([0] + widths[:-1])]
+    d, offsets = _packed_columns(theta)
     dims = group_dims(theta, group_ids)
     specs = jax.tree_util.tree_leaves(
         topo.leaf_specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
-    f32 = jnp.float32
 
     def local(theta_l, q_hat_l, bits_prev, range_prev, initialized, key):
         t_leaves, treedef = jax.tree_util.tree_flatten(theta_l)
-        q_leaves = jax.tree_util.tree_leaves(q_hat_l)
-        range_new = _group_reduce(
-            [_over_worker(jnp.max, jnp.abs(t.astype(f32) - q.astype(f32)))
-             for t, q in zip(t_leaves, q_leaves)],
-            group_ids, len(dims), lambda s: jnp.max(s, axis=0))  # (n, G)
-        range_new = jax.lax.pmax(range_new, topo.rest)
-        bits, delta, degen = quant_lib.bit_schedule(
-            bits_prev, range_new, range_prev, initialized, cfg.omega,
-            cfg.b0, cfg.b_max)
-        out = []
-        for i, (t, q) in enumerate(zip(t_leaves, q_leaves)):
-            g, rows = group_ids[i], t.shape[0]
-            u = quant_lib.uniform_columns(
-                key, n, sum(widths), offsets[i], shapes[i],
-                block=(_block_starts(specs[i], t.shape, topo.mesh),
-                       t.shape))
-            q32 = q.astype(f32).reshape(rows, -1)
-            fresh = stoch_quantize_ref(
-                t.astype(f32).reshape(rows, -1), q32, u.reshape(rows, -1),
-                delta[:, g], range_new[:, g])
-            out.append(jnp.where(degen[:, g][:, None], q32, fresh)
-                       .reshape(q.shape).astype(q.dtype))
+
+        def uniforms(i, t):
+            return quant_lib.uniform_columns(
+                key, n, d, offsets[i], shapes[i],
+                block=(_block_starts(specs[i], t.shape, topo.mesh), t.shape))
+
+        out, range_new, bits, delta = _quantize_leaves(
+            t_leaves, jax.tree_util.tree_leaves(q_hat_l), bits_prev,
+            range_prev, initialized, uniforms, cfg, group_ids, len(dims),
+            reduce_range=lambda r: jax.lax.pmax(r, topo.rest))
         return (jax.tree_util.tree_unflatten(treedef, out), range_new, bits,
                 delta)
 
@@ -988,64 +1048,49 @@ def init_state(theta: Tree, cfg: EngineConfig,
     )
 
 
-def _censor_masks(state: EngineState, candidate: Tree, cfg: EngineConfig,
+def _censor_masks(theta_hat: Tree, candidate: Tree, cfg: EngineConfig,
                   group_ids: Sequence[int], n_groups: int,
                   k_next: jax.Array,
                   split: Optional[topo_lib.ShardedTopology] = None
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Returns ``(worker_mask (N,), group_mask (N, G))`` censoring decisions.
-    ``split`` (the topology of workers whose leaves are split over
-    devices): the norms sum on each device's own blocks, leaf by leaf, and
-    only the (N, G) sums cross devices; no row is packed."""
+    """Returns ``(worker_mask (n,), group_mask (n, G))`` censoring decisions
+    for the n rows ``candidate`` and ``theta_hat`` hold. The squared norms
+    of ``candidate - theta_hat`` sum leaf by leaf, each leaf in its own
+    shape, to (n, G); ``split`` (the topology of workers whose leaves are
+    split over devices): on each device's own blocks, and only the (n, G)
+    sums cross devices."""
     leaves = jax.tree_util.tree_leaves(candidate)
     n = leaves[0].shape[0]
     if not cfg.censor.enabled:
         ones = jnp.ones((n,), jnp.float32)
         return ones, jnp.ones((n, n_groups), jnp.float32)
 
-    def sub(c, h):
-        return c.astype(jnp.float32) - h.astype(jnp.float32)
+    def sqnorms(cand, hat):
+        return _group_reduce(
+            [_over_worker(jnp.sum, jnp.square(c.astype(jnp.float32)
+                                              - h.astype(jnp.float32)))
+             for c, h in zip(jax.tree_util.tree_leaves(cand),
+                             jax.tree_util.tree_leaves(hat))],
+            group_ids, n_groups, lambda s: jnp.sum(s, axis=0))   # (n, G)
 
-    diff = jax.tree_util.tree_map(sub, candidate, state.theta_hat)
     tau = threshold(cfg.censor, k_next)
-    if split is not None:
+    if split is None:
+        sq = sqnorms(candidate, theta_hat)
+    else:
         from jax.sharding import PartitionSpec
-
-        def local(cand_l, hat_l):
-            sq = _group_reduce(
-                [_over_worker(jnp.sum, jnp.square(sub(c, h)))
-                 for c, h in zip(jax.tree_util.tree_leaves(cand_l),
-                                 jax.tree_util.tree_leaves(hat_l))],
-                group_ids, n_groups, lambda s: jnp.sum(s, axis=0))  # (n, G)
-            return jax.lax.psum(sq, split.rest)
-
-        sq = _on_blocks(split, local, (split.leaf_specs, split.leaf_specs),
-                        PartitionSpec(split.axis), candidate,
-                        state.theta_hat)
-        if cfg.censor_mode == "global":
-            cmask = (jnp.sqrt(jnp.sum(sq, axis=-1)) >= tau).astype(
-                jnp.float32)
-            return cmask, jnp.broadcast_to(cmask[:, None], (n, n_groups))
-        gmask = censor_lib.group_censor_mask(
-            jnp.sqrt(sq), censor_lib.group_thresholds(
-                tau, group_dims(candidate, group_ids), tree_dim(candidate)))
-        return jnp.max(gmask, axis=-1), gmask
-    pk = packing.make_packing(diff, group_ids)
-    diff_p = packing.pack(pk, diff)                       # (N, D) f32
+        sq = _on_blocks(
+            split, lambda c, h: jax.lax.psum(sqnorms(c, h), split.rest),
+            (split.leaf_specs, split.leaf_specs), PartitionSpec(split.axis),
+            candidate, theta_hat)
     if cfg.censor_mode == "global":
-        # the packed view makes the multi-leaf norm identical to the seed
-        # flat path's jnp.linalg.norm over the whole model vector
-        change = jnp.linalg.norm(diff_p, axis=-1)
-        cmask = (change >= tau).astype(jnp.float32)
+        cmask = (jnp.sqrt(jnp.sum(sq, axis=-1)) >= tau).astype(jnp.float32)
         return cmask, jnp.broadcast_to(cmask[:, None], (n, n_groups))
-
     # per-group censoring: tau_g^2 proportional to d_g so the group
-    # thresholds partition the global budget (sum_g tau_g^2 = tau^2); the
-    # per-group sums reduce over the packed buffer in one segment-sum.
+    # thresholds partition the global budget (sum_g tau_g^2 = tau^2).
     # Threshold math lives in core.censoring so every spec shape shares it.
-    change_g = jnp.sqrt(packing.segment_sqnorm(pk, diff_p))
-    tau_g = censor_lib.group_thresholds(tau, pk.group_dims, pk.dim)
-    gmask = censor_lib.group_censor_mask(change_g, tau_g)
+    gmask = censor_lib.group_censor_mask(
+        jnp.sqrt(sq), censor_lib.group_thresholds(
+            tau, group_dims(candidate, group_ids), tree_dim(candidate)))
     return jnp.max(gmask, axis=-1), gmask
 
 
@@ -1079,6 +1124,20 @@ def _count_rows(part: str, rows: Optional[np.ndarray], n: int) -> None:
         engine_phase_rows_counter().inc(n, part=part, path="masked")
     else:
         engine_phase_rows_counter().inc(len(rows), part=part, path="rows")
+
+
+def _count_layout(part: str, layout: str) -> None:
+    engine_exchange_layout_counter().inc(1, part=part, layout=layout)
+
+
+def _quantize_layout(theta: Tree, cfg: EngineConfig,
+                     split: Optional[topo_lib.ShardedTopology]) -> str:
+    """The layout :func:`_quantize` works on: ``packed`` where
+    :func:`grouped_quantize_step` takes the Pallas kernel over the ``(N,
+    D)`` buffer, else ``leaf``."""
+    packs = (cfg.quantize is not None and cfg.use_pallas_quant
+             and split is None and len(jax.tree_util.tree_leaves(theta)) > 1)
+    return "packed" if packs else "leaf"
 
 
 def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
@@ -1142,12 +1201,17 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
     n_groups = max(group_ids) + 1
     rho = cfg.rho
     with jax.named_scope("admm.mix"):
-        neigh = topo.mix(state.theta_hat)
+        # where the phase solves its acting rows alone, only their
+        # neighbour sums and primal terms are computed
+        _count_layout("mix", topo.layout)
+        neigh = topo.mix(state.theta_hat, rows=solve_rows)
         if cfg.alternating:
             # GGADMM primal, Eqs. (11)/(12)/(21)/(22)
+            alpha = state.alpha if solve_rows is None \
+                else take_rows(state.alpha, solve_rows)
             v = jax.tree_util.tree_map(
                 lambda a, nm: a.astype(jnp.float32)
-                - rho * nm.astype(jnp.float32), state.alpha, neigh)
+                - rho * nm.astype(jnp.float32), alpha, neigh)
             quad = rho_d
         else:
             # Jacobian C-ADMM primal (Liu et al., 2019b): proximal
@@ -1173,7 +1237,7 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
             def rows_of(a):
                 return take_rows(a, solve_rows)
             theta_r, mu_r, nu_r = solver.solve(
-                rows_of(state.theta), rows_of(v), rows_of(quad),
+                rows_of(state.theta), v, rows_of(quad),
                 rows_of(state.opt_mu), rows_of(state.opt_nu), rows_of(batch))
             theta = put_rows(state.theta, solve_rows, theta_r)
             mu = put_rows(state.opt_mu, solve_rows, mu_r)
@@ -1181,11 +1245,13 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
 
     _count_rows("quantize", rows, n)
     with jax.named_scope("admm.quantize"):
+        _count_layout("quantize", _quantize_layout(theta, cfg, split))
         if rows is None:
             quant_new, candidate, bits, payload = _quantize(
                 state.quant, theta, key, cfg, group_ids, split=split)
+            cand_rows = candidate
         else:
-            quant_r, _, bits_r, payload_r = _quantize(
+            quant_r, cand_rows, bits_r, payload_r = _quantize(
                 take_rows(state.quant, rows), take_rows(theta, rows), key,
                 cfg, group_ids, rows, n)
             quant_new = put_rows(state.quant, rows, quant_r)
@@ -1199,10 +1265,11 @@ def _phase(state: EngineState, phase_mask: jax.Array, solver: LocalSolver,
                                payload_r)
 
     with jax.named_scope("admm.censor"):
+        _count_layout("censor", "leaf")
         tx_mask, payload_tx, candidate_payload, group_tx, censor_mask, \
             offered_payload = _censor_and_price(
-                state, candidate, theta, bits, payload, phase_mask, cfg,
-                group_ids, n_groups, participation, split)
+                state, cand_rows, theta, bits, payload, phase_mask, cfg,
+                group_ids, n_groups, participation, split, rows)
         pm_col = phase_mask[:, None]
         phase_bits = bits * pm_col
 
@@ -1229,14 +1296,25 @@ def _censor_and_price(state: EngineState, candidate: Tree, theta: Tree,
                       phase_mask: jax.Array, cfg: EngineConfig,
                       group_ids: Sequence[int], n_groups: int,
                       participation: Optional[jax.Array],
-                      split: Optional[topo_lib.ShardedTopology] = None):
+                      split: Optional[topo_lib.ShardedTopology] = None,
+                      rows: Optional[np.ndarray] = None):
     """The censor test (Eq. 13/14), its composition with ``participation``
-    and the phase, and the bits each decision puts on the wire. Returns
+    and the phase, and the bits each decision puts on the wire. With
+    ``rows`` (static), ``candidate`` holds only those rows and only they
+    are tested: the others are masked out by the phase. Returns
     ``(tx_mask, payload_tx, candidate_payload, group_tx, censor_mask,
     offered_payload)``."""
     k_next = (state.k + 1).astype(jnp.float32)
-    cmask_cens, gmask_cens = _censor_masks(state, candidate, cfg, group_ids,
+    hat = state.theta_hat if rows is None \
+        else take_rows(state.theta_hat, rows)
+    cmask_cens, gmask_cens = _censor_masks(hat, candidate, cfg, group_ids,
                                            n_groups, k_next, split)
+    if rows is not None:
+        n = phase_mask.shape[0]
+        cmask_cens = put_rows(jnp.zeros((n,), jnp.float32), rows,
+                              cmask_cens)
+        gmask_cens = put_rows(jnp.zeros((n, n_groups), jnp.float32), rows,
+                              gmask_cens)
     if participation is not None:
         # timeout composes AFTER the censor test: tx = timeout & censor.
         # The censor decision itself (and the quantizer chain below) is
@@ -1399,6 +1477,7 @@ def make_step(graph: WorkerGraph, cfg: EngineConfig, solver: LocalSolver,
         # the same kernel routing) as the phase mixes — the seed bug where
         # the dual step silently dropped ``use_pallas_mix`` cannot recur.
         with jax.named_scope("admm.dual"):
+            _count_layout("dual", topo.layout)
             lap = topo.laplacian(state.theta_hat)
 
             def dual(a, lp):

@@ -1,11 +1,11 @@
 """Packed buffer view of worker-stacked pytrees (DESIGN.md §Packing).
 
-The engine's hot quantize/censor/mix path used to dispatch one op chain per
-pytree leaf (one ``jax.random.uniform`` + one kernel/XLA launch each) —
-exactly the overhead that L-FGADMM-style layer-wise mode multiplies by the
-number of layers. This module flattens all leaves of a worker-stacked tree
-into ONE contiguous ``(N, D)`` buffer so grouped quantization runs as a
-single fused call:
+This module flattens all leaves of a worker-stacked tree into ONE
+contiguous ``(N, D)`` buffer, the slab the Pallas kernels of the exchange
+take (``use_pallas_quant`` / ``use_pallas_mix``): grouped quantization then
+runs as a single fused kernel call. The engine's default exchange works on
+each leaf in its own shape instead and builds no such buffer (DESIGN.md
+§Packing):
 
 * :class:`Packing` holds the *static* layout metadata — leaf shapes/dtypes,
   flat dims, column offsets, and the per-column group-id map ``col_group_ids``
@@ -17,12 +17,9 @@ single fused call:
   view. Leaves are concatenated in ``tree_leaves`` order, each reshaped to
   ``(N, d_leaf)``; a one-leaf tree packs to a plain reshape (no concat), so
   the flat ``(N, d)`` seed workload is the identity transform.
-* :func:`segment_maxabs` / :func:`segment_sqnorm` are the grouped
-  side-information computations: per-worker per-group ``max |.|``
-  (quantizer range R_g) and ``sum .^2`` (group-censor norm), both
-  ``(N, G)`` — transpose-free lane-axis reductions over each leaf's
-  static contiguous column slice, instead of the former
-  ``op(buf.T, ...)`` segment reductions that copied the whole buffer.
+* :func:`segment_maxabs` is the two-pass path's grouped side
+  information: per-worker per-group ``max |.|`` (quantizer range R_g),
+  ``(N, G)``.
 
 Everything here is jit-traceable; the cache only avoids re-deriving static
 layout (and keeps ``col_group_ids`` as one host array per layout).
@@ -33,8 +30,8 @@ auto partitions (``"auto:K"``), explicit index buckets
 (``((0, 1), (2, 3))``) and the greedy range-similarity clustering that the
 engine's :class:`~repro.core.engine.AutoGrouper` re-runs from live range
 statistics. Every spec form compiles down to the same per-leaf group-id
-tuple that :func:`make_packing` already consumes, so the fused quantize
-kernel, the group-censor norms and the payload accounting are spec-agnostic.
+tuple, so the quantizer, the group-censor norms and the payload
+accounting are spec-agnostic.
 """
 from __future__ import annotations
 
@@ -158,37 +155,22 @@ def unpack(pk: Packing, buf: jax.Array, like: Tree = None) -> Tree:
     return jax.tree_util.tree_unflatten(pk.treedef, out)
 
 
-def _grouped_colreduce(pk: Packing, mat: jax.Array, reduce_fn,
-                       combine_fn) -> jax.Array:
-    """Lane-axis reduction per group, transpose-free: each leaf occupies a
-    static contiguous column slice, so every leaf reduces along axis 1 and
-    leaves sharing a group combine with one more reduction. O(N·D) work
-    and O(1) extra memory — the old ``op(buf.T, ...)`` segment reductions
-    materialized a (D, N) transpose on the hot path (~10% steady-state
-    overhead on small trees)."""
-    if pk.n_groups == 1:
-        return reduce_fn(mat, axis=1)[:, None]
-    per_group = [[] for _ in range(pk.n_groups)]
-    for off, d, g in zip(pk.offsets, pk.dims, pk.group_ids):
-        per_group[g].append(reduce_fn(mat[:, off:off + d], axis=1))
-    cols = [parts[0] if len(parts) == 1
-            else combine_fn(jnp.stack(parts, axis=0), axis=0)
-            for parts in per_group]
-    return jnp.stack(cols, axis=1)
-
-
 def segment_maxabs(pk: Packing, buf: jax.Array) -> jax.Array:
     """Per-worker per-group ``max |buf|`` — the grouped quantizer range
-    R_g: ``(N, G)``. Max is order-independent, so the slice-based form is
-    value-identical to the old transposed segment_max."""
-    return _grouped_colreduce(pk, jnp.abs(buf), jnp.max, jnp.max)
-
-
-def segment_sqnorm(pk: Packing, buf: jax.Array) -> jax.Array:
-    """Per-worker per-group ``sum buf^2`` — the group-censor norm term:
-    ``(N, G)``."""
-    return _grouped_colreduce(pk, jnp.square(buf.astype(jnp.float32)),
-                              jnp.sum, jnp.sum)
+    R_g: ``(N, G)``. Transpose-free: each leaf occupies a static
+    contiguous column slice, so every leaf reduces along axis 1 and
+    leaves sharing a group combine with one more max (order-independent,
+    so value-identical to a transposed segment max)."""
+    absbuf = jnp.abs(buf)
+    if pk.n_groups == 1:
+        return jnp.max(absbuf, axis=1)[:, None]
+    per_group = [[] for _ in range(pk.n_groups)]
+    for off, d, g in zip(pk.offsets, pk.dims, pk.group_ids):
+        per_group[g].append(jnp.max(absbuf[:, off:off + d], axis=1))
+    cols = [parts[0] if len(parts) == 1
+            else jnp.max(jnp.stack(parts, axis=0), axis=0)
+            for parts in per_group]
+    return jnp.stack(cols, axis=1)
 
 
 # ------------------------------------------------------------ group specs --

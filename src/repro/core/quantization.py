@@ -151,14 +151,17 @@ def uniform_rows(key: jax.Array, n: int, d: int,
 
 
 def uniform_columns(key: jax.Array, n: int, d: int, offset: int,
-                    shape: Tuple[int, ...], block=None) -> jax.Array:
+                    shape: Tuple[int, ...], block=None,
+                    rows: Optional[np.ndarray] = None) -> jax.Array:
     """Columns ``offset .. offset + prod(shape[1:]) - 1`` of every row of
     ``jax.random.uniform(key, (n, d), float32)``, bit for bit, drawn in
     ``shape`` (``(n,) + leaf shape``, row-major over the leaf): a packed
     leaf's own uniforms, computed without the packed row. With ``block =
     (starts, local_shape)`` only the block of that shape at index
     ``starts`` (one start per axis, axis 0 the rows; traced values allowed)
-    is drawn: a device's own part of a leaf split over devices.
+    is drawn: a device's own part of a leaf split over devices. With
+    ``rows`` (static) only those rows are drawn: ``(len(rows),) +
+    shape[1:]``.
 
     Element ``idx`` of row ``r`` takes the counter ``r * d + offset +
     flat(idx)`` (partitionable threefry, as :func:`uniform_rows`), made of
@@ -166,11 +169,16 @@ def uniform_columns(key: jax.Array, n: int, d: int, offset: int,
     draw and a slice."""
     starts, local = block if block is not None else ((0,) * len(shape),
                                                      tuple(shape))
+    if rows is not None:
+        rows = np.asarray(rows, np.int64)
+        local = (rows.size,) + tuple(shape[1:])
     size = int(np.prod(shape[1:], dtype=np.int64))
     data = _threefry_key_data(key)
     if data is None or not jax.config.jax_threefry_partitionable:
         full = jax.random.uniform(key, (n, d), jnp.float32)
         leaf = full[:, offset:offset + size].reshape(shape)
+        if rows is not None:
+            return leaf[rows]
         return jax.lax.dynamic_slice(leaf, tuple(starts), tuple(local))
     if size >= 2 ** 32:
         raise ValueError(f"a leaf of {size} elements exceeds one 32-bit "
@@ -184,6 +192,8 @@ def uniform_columns(key: jax.Array, n: int, d: int, offset: int,
         flat = flat + idx * jnp.uint32(stride)
         stride *= int(shape[axis])
     start = np.arange(n, dtype=np.uint64) * np.uint64(d) + np.uint64(offset)
+    if rows is not None:
+        start = start[rows]
     lo_rows = jnp.asarray((start & np.uint64(0xFFFFFFFF)).astype(np.uint32))
     hi_rows = jnp.asarray((start >> np.uint64(32)).astype(np.uint32))
     if block is not None:

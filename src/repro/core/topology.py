@@ -34,10 +34,15 @@ gather/scatter at any paper density, so sparse's wall-time win is an
 accelerator/scale story; its unconditional win at p ≤ 0.5 is state size
 (O(E) edge arrays vs the O(N²) adjacency operand).
 
-Trees mix through the packed ``(N, D)`` buffer view (``core/packing.py``)
-whenever the leaves share a dtype — one backend invocation for the whole
-tree; mixed-dtype trees fall back to leaf-wise application with identical
-semantics.
+Trees mix leaf by leaf, each leaf in its own shape: the dense sum is a
+contraction over the worker axis, the sparse gather and segment-sum run
+along it, and the sharded backend mixes each leaf in its own layout. No
+leaf is reshaped to ``(N, d)``: on a TPU a leaf lies in whole (8, 128)
+tiles, while an ``(N, D)`` row of a few workers fills only N of a tile's 8
+sublanes, so packing costs a relayout copy and every pass over the packed
+row runs at a fraction of the vector unit. Only the Pallas kernels
+(``use_pallas_mix``) take the packed ``(N, D)`` buffer
+(``core/packing.py``), whose layout they are written for.
 """
 from __future__ import annotations
 
@@ -58,9 +63,9 @@ BACKENDS = ("dense", "sparse", "sharded")
 
 
 def _apply_flat(fn, a: Tree) -> Tree:
-    """Apply a ``(N, d) -> (N, d)`` map to a tree: through the packed
-    buffer when all leaves share a dtype (one call for the whole tree),
-    leaf-wise otherwise. Mirrors the seed ``tree_mix`` dispatch exactly."""
+    """Apply a Pallas kernel's ``(N, d) -> (N, d)`` map to a tree: through
+    the packed buffer when all leaves share a dtype (one call for the whole
+    tree), one ``(N, d)`` view per leaf otherwise."""
     def one(x):
         return fn(x.reshape(x.shape[0], -1)).reshape(x.shape)
 
@@ -72,24 +77,44 @@ def _apply_flat(fn, a: Tree) -> Tree:
     return jax.tree_util.tree_map(one, a)
 
 
+def _take_rows(x: jax.Array, rows: Optional[np.ndarray]) -> jax.Array:
+    return x if rows is None else x[np.asarray(rows)]
+
+
 def _neighbor_sum(adjacency: jax.Array, values: jax.Array) -> jax.Array:
-    """``A @ V`` at full f32 precision. A TPU's default f32 matmul rounds
-    its operands to bfloat16, which would round every model the workers
-    exchange; with a 0/1 adjacency the full-precision product is exact."""
-    return jnp.matmul(adjacency.astype(values.dtype), values,
-                      precision=jax.lax.Precision.HIGHEST)
+    """``A @ V`` over the leading worker axis of ``values``, in its own
+    shape, with no operand rounded: a TPU's default f32 matmul rounds its
+    operands to bfloat16, which would round every model the workers
+    exchange. An ``(N, d)`` leaf, whose rows already lie along the tiles,
+    takes the full-precision matmul (exact for a 0/1 adjacency; the seed's
+    flat stepper, bit for bit). A leaf of higher rank lies in whole tiles
+    of its last two axes, and a dot over its worker axis would move that
+    axis into the tiles and back (a relayout copy of the leaf on a TPU):
+    it takes ``sum_m A[:, m] * V[m]``, added in the order of ``m``, as
+    element-wise work. Each product with a 0/1 adjacency is exact, and so
+    is the sum where a worker has one neighbour."""
+    a = adjacency.astype(values.dtype)
+    if values.ndim == 2:
+        return jnp.matmul(a, values, precision=jax.lax.Precision.HIGHEST)
+    col = (a.shape[0],) + (1,) * (values.ndim - 1)
+    out = a[:, 0].reshape(col) * values[0:1]
+    for m in range(1, a.shape[1]):
+        out = out + a[:, m].reshape(col) * values[m:m + 1]
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
 class Topology:
     """Graph-structure operations behind one interface.
 
-    Subclasses implement ``_mix_flat`` on a ``(N, d)`` buffer; everything
-    else (tree dispatch, the Laplacian dual term, residuals) is shared, so
-    every engine consumer of the graph — phase mix, dual update, metrics —
-    automatically uses the selected backend (and its kernel routing: the
-    seed bug of the dual step silently skipping ``use_pallas_mix`` cannot
-    recur, there is no second mix implementation to drift)."""
+    Subclasses implement ``_mix_leaf`` on one leaf in its own shape and
+    ``_mix_flat``, the Pallas kernel on a packed ``(N, d)`` buffer;
+    everything else (tree dispatch, the Laplacian dual term, residuals) is
+    shared, so every engine consumer of the graph — phase mix, dual
+    update, metrics — automatically uses the selected backend (and its
+    kernel routing: the seed bug of the dual step silently skipping
+    ``use_pallas_mix`` cannot recur, there is no second mix implementation
+    to drift)."""
 
     n: int
     degrees: jax.Array          # (N,) float32
@@ -97,13 +122,27 @@ class Topology:
 
     backend = "abstract"
 
+    @property
+    def layout(self) -> str:
+        """What :meth:`mix` works on: ``packed`` (the Pallas kernels' one
+        ``(N, D)`` buffer) or ``leaf`` (each leaf in its own shape)."""
+        return "packed" if self.use_pallas else "leaf"
+
+    def _mix_leaf(self, x: jax.Array,
+                  rows: Optional[np.ndarray]) -> jax.Array:
+        raise NotImplementedError
+
     def _mix_flat(self, flat: jax.Array) -> jax.Array:
         raise NotImplementedError
 
     # ------------------------------------------------------------- mix --
-    def mix(self, a: Tree) -> Tree:
-        """Neighbor sum per worker: out_n = sum_{m in N_n} a_m."""
-        return _apply_flat(self._mix_flat, a)
+    def mix(self, a: Tree, rows: Optional[np.ndarray] = None) -> Tree:
+        """Neighbor sum per worker: out_n = sum_{m in N_n} a_m, for every
+        worker or, with ``rows`` (static), for those rows alone."""
+        if self.use_pallas:
+            return jax.tree_util.tree_map(
+                lambda x: _take_rows(x, rows), _apply_flat(self._mix_flat, a))
+        return jax.tree_util.tree_map(lambda x: self._mix_leaf(x, rows), a)
 
     # ----------------------------------------------------- dual update --
     def laplacian(self, a: Tree) -> Tree:
@@ -162,11 +201,13 @@ class DenseTopology(Topology):
 
     backend = "dense"
 
+    def _mix_leaf(self, x: jax.Array,
+                  rows: Optional[np.ndarray]) -> jax.Array:
+        return _neighbor_sum(_take_rows(self.adjacency, rows), x)
+
     def _mix_flat(self, flat: jax.Array) -> jax.Array:
-        if self.use_pallas:
-            from repro.kernels import ops as kernel_ops
-            return kernel_ops.bipartite_mix(self.adjacency, flat)
-        return _neighbor_sum(self.adjacency, flat)
+        from repro.kernels import ops as kernel_ops
+        return kernel_ops.bipartite_mix(self.adjacency, flat)
 
     def primal_residual(self, theta: jax.Array) -> jax.Array:
         diffs = theta[:, None, :] - theta[None, :, :]
@@ -193,15 +234,17 @@ class SparseTopology(Topology):
 
     backend = "sparse"
 
+    def _mix_leaf(self, x: jax.Array,
+                  rows: Optional[np.ndarray]) -> jax.Array:
+        src = x.at[self.edge_src].get(mode="promise_in_bounds")
+        return _take_rows(jax.ops.segment_sum(
+            src, self.edge_dst, num_segments=self.n,
+            indices_are_sorted=True), rows)
+
     def _mix_flat(self, flat: jax.Array) -> jax.Array:
-        if self.use_pallas:
-            from repro.kernels import ops as kernel_ops
-            return kernel_ops.edge_gather_mix(
-                flat, self.nbr_table, self.nbr_valid).astype(flat.dtype)
-        rows = flat.at[self.edge_src].get(mode="promise_in_bounds")
-        return jax.ops.segment_sum(rows, self.edge_dst,
-                                   num_segments=self.n,
-                                   indices_are_sorted=True)
+        from repro.kernels import ops as kernel_ops
+        return kernel_ops.edge_gather_mix(
+            flat, self.nbr_table, self.nbr_valid).astype(flat.dtype)
 
     def primal_residual(self, theta: jax.Array) -> jax.Array:
         t32 = theta.astype(jnp.float32)
@@ -214,17 +257,19 @@ class SparseTopology(Topology):
 class ShardedTopology(DenseTopology):
     """SPMD mixing: shard_map over the worker mesh axis.
 
-    Each shard keeps its (N/w, N) adjacency row block and its (N/w, d)
-    value rows, all-gathers the peer rows once (tiled, one explicit
-    collective over exactly the worker axis), and writes only its own
-    output block — in_specs/out_specs pin every operand's layout so XLA
-    never has to invent a collective-permute schedule. The program
-    is fully manual over the whole mesh: the feature axis additionally
-    splits over the non-worker axes (tensor parallel) whenever it
-    divides, so each device mixes only its (N/w, d/rest) tile and no
-    cross-replica resharding is introduced. ``use_pallas`` runs each
-    shard's local row-block matmul on the ``bipartite_mix`` MXU kernel;
-    the residual reduction is inherited from the dense backend."""
+    Each shard keeps its (N/w, N) adjacency row block and its block of
+    the workers' rows of a leaf, all-gathers the peer rows once (tiled,
+    one explicit collective over exactly the worker axis), and writes only
+    its own output block — in_specs/out_specs pin every operand's layout
+    so XLA never has to invent a collective-permute schedule. Each leaf
+    mixes in its own layout: ``leaf_specs`` where the workers' leaves are
+    split over devices, else the worker axis with the leaf's last axis
+    split over the non-worker axes (tensor parallel) whenever it divides,
+    so each device mixes only its tile and no cross-replica resharding is
+    introduced. ``use_pallas`` (without ``leaf_specs``) runs each shard's
+    local row-block matmul on the packed buffer with the
+    ``bipartite_mix`` MXU kernel; the residual reduction is inherited
+    from the dense backend."""
 
     mesh: Any = None
     axis: str = "workers"
@@ -236,12 +281,33 @@ class ShardedTopology(DenseTopology):
 
     backend = "sharded"
 
-    def mix(self, a: Tree) -> Tree:
-        if self.leaf_specs is None:
-            return super().mix(a)
-        return jax.tree_util.tree_map(self._mix_leaf, a, self.leaf_specs)
+    @property
+    def layout(self) -> str:
+        return "leaf" if self.leaf_specs is not None else super().layout
 
-    def _mix_leaf(self, x: jax.Array, spec) -> jax.Array:
+    def _rest_size(self) -> int:
+        return int(np.prod([self.mesh.shape[a] for a in self.rest],
+                           dtype=np.int64))
+
+    def mix(self, a: Tree, rows: Optional[np.ndarray] = None) -> Tree:
+        if rows is not None:
+            raise ValueError("the sharded backend mixes every worker's rows")
+        if self.layout == "packed":
+            return super().mix(a)
+        if self.leaf_specs is not None:
+            return jax.tree_util.tree_map(self._mix_spec, a, self.leaf_specs)
+        return jax.tree_util.tree_map(
+            lambda x: self._mix_spec(x, self._default_spec(x)), a)
+
+    def _default_spec(self, x: jax.Array):
+        """A leaf's rows over the worker axis, its last axis over the
+        non-worker axes where they divide it."""
+        from jax.sharding import PartitionSpec as P
+        if self.rest and x.ndim > 1 and x.shape[-1] % self._rest_size() == 0:
+            return P(self.axis, *([None] * (x.ndim - 2)), self.rest)
+        return P(self.axis)
+
+    def _mix_spec(self, x: jax.Array, spec) -> jax.Array:
         """One leaf in its own layout: each device gathers its share of
         every worker's leaf over the worker axis alone."""
         from jax.sharding import PartitionSpec as P
@@ -259,19 +325,14 @@ class ShardedTopology(DenseTopology):
 
     def _mix_flat(self, flat: jax.Array) -> jax.Array:
         from jax.sharding import PartitionSpec as P
+        from repro.kernels import ops as kernel_ops
 
-        rest_size = 1
-        for a in self.rest:
-            rest_size *= self.mesh.shape[a]
         feat = self.rest if (self.rest and
-                             flat.shape[1] % rest_size == 0) else None
+                             flat.shape[1] % self._rest_size() == 0) else None
 
         def local(a_blk, v_blk):
             v_all = jax.lax.all_gather(v_blk, self.axis, axis=0, tiled=True)
-            if self.use_pallas:
-                from repro.kernels import ops as kernel_ops
-                return kernel_ops.bipartite_mix(a_blk, v_all)
-            return _neighbor_sum(a_blk, v_all)
+            return kernel_ops.bipartite_mix(a_blk, v_all)
 
         vspec = P(self.axis, feat)
         return jax.shard_map(local, mesh=self.mesh,

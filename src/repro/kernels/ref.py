@@ -30,21 +30,23 @@ def stoch_quantize_ref(theta: jax.Array, q_hat_prev: jax.Array,
     """Fused quantize->dequantize (paper Eqs. 14, 15, 20).
 
     Args:
-      theta: (N, d) current models.
-      q_hat_prev: (N, d) previous quantized models Q̂^{k-1}.
-      uniforms: (N, d) U(0,1) draws for the stochastic rounding.
+      theta: (N, ...) current models: (N, d) rows, or a leaf in its own
+        shape.
+      q_hat_prev: previous quantized models Q̂^{k-1}, shaped as ``theta``.
+      uniforms: U(0,1) draws for the stochastic rounding, as ``theta``.
       delta: (N,) step sizes Δ_n^k.
       qrange: (N,) ranges R_n^k.
 
     Returns:
-      (N, d) reconstruction Q̂^k = Q̂^{k-1} + Δ q - R 1.
+      The reconstruction Q̂^k = Q̂^{k-1} + Δ q - R 1, shaped as ``theta``.
     """
     dtype = theta.dtype
+    col = (theta.shape[0],) + (1,) * (theta.ndim - 1)
     theta32 = theta.astype(jnp.float32)
     qprev32 = q_hat_prev.astype(jnp.float32)
     unif32 = uniforms.astype(jnp.float32)
-    safe_delta = jnp.maximum(delta.astype(jnp.float32), _EPS)[:, None]
-    r = qrange.astype(jnp.float32)[:, None]
+    safe_delta = jnp.maximum(delta.astype(jnp.float32), _EPS).reshape(col)
+    r = qrange.astype(jnp.float32).reshape(col)
     c = (theta32 - qprev32 + r) / safe_delta
     floor_c = jnp.floor(c)
     q = floor_c + (unif32 < (c - floor_c)).astype(jnp.float32)

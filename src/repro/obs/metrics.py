@@ -330,6 +330,19 @@ def engine_phase_rows_counter() -> Counter:
     )
 
 
+def engine_exchange_layout_counter() -> Counter:
+    """Traces of each exchange part of the consensus engine's round by the
+    layout it works on: ``part`` (``quantize``, ``censor``, ``mix``,
+    ``dual``) and ``layout`` (``leaf``: each parameter leaf in its own
+    shape; ``packed``: one ``(N, D)`` buffer of every leaf, which only the
+    Pallas options take). Bumped in ``core/engine.py`` once per trace of
+    each part, never inside a compiled program."""
+    return REGISTRY.counter(
+        "engine_exchange_layout", labels=("part", "layout"),
+        help="traced engine exchange parts, by part and data layout",
+    )
+
+
 def engine_worker_layout_counter() -> Counter:
     """Traces of the consensus engine's round by worker layout:
     ``workers`` (N) and ``chips_per_worker``, the devices each worker's

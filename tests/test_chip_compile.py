@@ -157,6 +157,54 @@ def test_slstm_cell_compiles_at_xlstm_width(shape):
              shape((h, dh)), state, state, state, state)
 
 
+def _one_chip_xlstm_round(topo):
+    """xLSTM-125M at its published widths, cut to one unit (an mLSTM and
+    an sLSTM block, the least cut the registry takes), its round
+    compiled as the xLSTM cells' 2 CQ-GGADMM workers (one edge, dense mix,
+    1 x 128 tokens each, 1 local step) on one chip of a described v5e."""
+    from repro.core import engine as E
+    from repro.launch import train
+    args = train.build_parser().parse_args([
+        "--arch", "xlstm-125m", "--workers", "2", "--batch", "2",
+        "--seq", "128", "--local-steps", "1", "--lr", "1e-4",
+        "--rho", "0.01", "--bits", "4", "--omega", "0.999"])
+    setup = train.admm_setup(base.get_config("xlstm-125m").cut_depth(2),
+                             args)
+    assert setup.mesh is None
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    abstract = jax.eval_shape(lambda: E.init_state(
+        setup.stacked_params(), setup.ecfg, setup.solver))
+    state = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        abstract)
+    tok = jax.ShapeDtypeStruct((2, 1, 128), jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = setup.build_step().lower(
+        state, {"tokens": tok, "labels": tok}, key).compile()
+    return compiled, E.tree_dim(abstract.theta)
+
+
+# Arguments plus temp of the round below, compiled here: 4,739,716,608
+# bytes (2,619,616,256 + 2,120,100,352) when the exchange packed every
+# leaf into one (N, D) row, whose compiled text then held 481 arrays of
+# last axis D; 3,255,974,400 (2,619,616,256 + 636,358,144) leaf by leaf.
+PACKED_XLSTM_ROUND_BYTES = 4_739_716_608
+
+
+def test_one_chip_xlstm_round_keeps_each_leaf_in_its_shape(topo):
+    """The xLSTM cells' round on one chip: no array of the compiled
+    program has the tree's whole width D as its last axis (no packed
+    ``(N, D)`` or ``(1, D)`` row in quantize, censor, mix or dual), and
+    it needs no more memory than the packed round it replaces."""
+    import re
+    compiled, d = _one_chip_xlstm_round(topo)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        <= PACKED_XLSTM_ROUND_BYTES
+    rows = re.findall(r"\[[\d,]*\b%d\]" % d, compiled.as_text())
+    assert not rows, rows[:5]
+
+
 def _replica_groups(line):
     """The device groups of one collective in compiled HLO text, by their
     logical ids: an explicit list, an iota form, or permute pairs."""

@@ -536,3 +536,182 @@ def test_phase_rows_counter(solver_kind, with_batch, alternating, backend,
     state = E.init_state(theta0, cfg, solver)
     got = _phase_rows_read(step, state, batch if with_batch else None)
     assert got == {k: want.get(k, 0) for k in got}
+
+
+# ---------------------------------------------------- exchange layout ----
+def _layout_graph(name):
+    """``one-edge``: the cells' two workers; ``bipartite``: six workers
+    whose heads and tails are not contiguous, some of degree 2 or more."""
+    from repro.core.graph import pod_pair_graph
+    if name == "one-edge":
+        return pod_pair_graph()
+    g = random_bipartite_graph(6, 0.5, seed=0)
+    assert int(np.max(g.degrees)) > 1
+    return g
+
+
+def _leafy_problem(n):
+    """Per-worker regression over a tree of leaves of rank 2, 3 and 4
+    (per worker: 5, 3 x 4 and 2 x 3 x 4), its data in the batch."""
+    kx, kw = jax.random.split(jax.random.PRNGKey(5))
+    x = jax.random.normal(kx, (n, 16, 4))
+    batch = {"x": x, "y": jnp.tanh(x @ jax.random.normal(kw, (n, 4, 5)))}
+
+    def loss(p, b):
+        h = b["x"] @ p["w"] + jnp.sum(p["u"], axis=0) \
+            + jnp.mean(p["k"], axis=(0, 1))[None, :1]
+        return jnp.mean(jnp.square(h[:, :3] @ p["v"] + p["c"] - b["y"]))
+
+    def grad_fn(theta, b):
+        return jax.vmap(jax.grad(loss))(theta, b)
+
+    theta0 = {"c": jnp.zeros((n, 5)), "k": jnp.zeros((n, 2, 3, 4)),
+              "u": jnp.zeros((n, 3, 4)), "v": jnp.zeros((n, 3, 5)),
+              "w": jnp.zeros((n, 4, 4))}
+    return theta0, batch, grad_fn
+
+
+def _layout_reads(step, *args):
+    from repro.obs import metrics as obs_metrics
+    c = obs_metrics.engine_exchange_layout_counter()
+    keys = [(part, layout) for part in ("quantize", "censor", "mix", "dual")
+            for layout in ("leaf", "packed")]
+    before = {k: c.value(part=k[0], layout=k[1]) for k in keys}
+    jax.eval_shape(step, *args)
+    return {k: c.value(part=k[0], layout=k[1]) - before[k] for k in keys}
+
+
+@pytest.mark.parametrize("groups", ["model", "leaf"])
+@pytest.mark.parametrize("graph", ["one-edge", "bipartite"])
+def test_leaf_quantize_is_the_packed_round(graph, groups):
+    """Each phase's acting rows, quantized leaf by leaf in each leaf's own
+    shape, give bit for bit what the packed ``(N, D)`` round gives on the
+    same rows: ranges, bit widths, step sizes, payload and every
+    reconstructed parameter, a degenerate group's passthrough included."""
+    g = _layout_graph(graph)
+    n = g.n
+    theta0, _, _ = _leafy_problem(n)
+    keys = iter(jax.random.split(jax.random.PRNGKey(9), 10))
+    theta = jax.tree_util.tree_map(
+        lambda x: jax.random.normal(next(keys), x.shape), theta0)
+    q_prev = jax.tree_util.tree_map(
+        lambda x: x + 0.1 * jax.random.normal(next(keys), x.shape), theta)
+    q_prev["c"] = theta["c"]                 # a group with nothing to send
+    gids = E.resolve_groups(theta, groups)
+    n_groups = max(gids) + 1
+    state = E.GroupQuantState(
+        q_hat=q_prev,
+        range_prev=jnp.full((n, n_groups), 0.3, jnp.float32),
+        bits_prev=jnp.full((n, n_groups), 3.0, jnp.float32),
+        delta_prev=jnp.full((n, n_groups), 0.1, jnp.float32),
+        initialized=jnp.ones((n, n_groups), jnp.float32))
+    qcfg = QuantConfig(b0=3, omega=0.99)
+    key = jax.random.PRNGKey(13)
+    for rows in (np.flatnonzero(g.head_mask), np.flatnonzero(~g.head_mask)):
+        args = (E.take_rows(state, rows), E.take_rows(theta, rows), key,
+                qcfg, gids)
+        got = jax.jit(lambda *a: E.grouped_quantize_step(
+            *a, rows=rows, n_workers=n), static_argnums=(3, 4))(*args)
+        want = jax.jit(lambda *a: E._grouped_quantize_step_packed(
+            *a, rows=rows, n_workers=n), static_argnums=(3, 4))(*args)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if groups == "leaf":
+            assert bool(jnp.all(got[0].range_prev[:, 0] == 0.3))
+
+
+@pytest.mark.parametrize("graph", ["one-edge", "bipartite"])
+def test_leaf_round_follows_the_packed_round(graph):
+    """Three rounds with every exchange part leaf by leaf against the same
+    rounds on the packed ``(N, D)`` buffer (the Pallas options, interpreted
+    here): identical transmit decisions, bit widths and payloads;
+    ``theta_hat`` and ``alpha`` equal where every worker has one
+    neighbour (the sums are then exact), within ``CROSS_PROGRAM_RTOL``
+    otherwise. ``engine_exchange_layout`` reads ``leaf`` for all four
+    parts, and ``packed`` for all but the censor under the options."""
+    g = _layout_graph(graph)
+    theta0, batch, grad_fn = _leafy_problem(g.n)
+    solver = E.InexactSolver(grad_fn=grad_fn, local_steps=2, local_lr=0.05)
+    runs, reads = {}, {}
+    for layout, pallas in (("leaf", False), ("packed", True)):
+        cfg = E.EngineConfig(rho=0.5, censor=CensorConfig(tau0=0.05, xi=0.9),
+                             quantize=QuantConfig(b0=3, omega=0.99),
+                             use_pallas_mix=pallas, use_pallas_quant=pallas)
+        step = E.make_step(g, cfg, solver)
+        state = E.init_state(theta0, cfg, solver)
+        reads[layout] = _layout_reads(step, state, batch,
+                                      jax.random.PRNGKey(0))
+        step = jax.jit(step)
+        hist = []
+        for i in range(3):
+            state, m = step(state, batch, jax.random.PRNGKey(i))
+            hist.append(m)
+        runs[layout] = (state, hist)
+    for a, b in zip(runs["leaf"][1], runs["packed"][1]):
+        for name in ("tx_mask", "bits_per_group", "payload_bits"):
+            np.testing.assert_array_equal(np.asarray(a[name]),
+                                          np.asarray(b[name]), err_msg=name)
+    assert sum(float(m["tx_mask"].sum()) for m in runs["leaf"][1]) > 0
+    for part in ("theta_hat", "alpha"):
+        got, want = (getattr(runs[k][0], part) for k in ("leaf", "packed"))
+        if graph == "one-edge":
+            for a, b in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            assert E.tree_rel_err(got, want) <= E.CROSS_PROGRAM_RTOL, part
+    parts = ("quantize", "censor", "mix", "dual")
+    assert reads["leaf"] == {(p, lay): int(lay == "leaf") * 2 ** (p != "dual")
+                             for p in parts for lay in ("leaf", "packed")}
+    assert reads["packed"] == {
+        (p, lay): int(lay == ("leaf" if p == "censor" else "packed"))
+        * 2 ** (p != "dual") for p in parts for lay in ("leaf", "packed")}
+
+
+def _smoke_xlstm_round(pallas: bool):
+    """The smoke xLSTM's round (two workers on this one device, one edge)
+    lowered, with the engine's counters of exchange layouts read over its
+    trace, and the packed width D of its tree."""
+    from repro.configs import base
+    from repro.launch import train
+    from repro.obs import metrics as obs_metrics
+    args = train.build_parser().parse_args([
+        "--arch", "xlstm-125m", "--smoke", "--workers", "2", "--batch", "2",
+        "--seq", "16", "--local-steps", "1", "--bits", "4"])
+    setup = train.admm_setup(base.get_smoke_config("xlstm-125m"), args)
+    assert setup.mesh is None
+    ecfg = dataclasses.replace(setup.ecfg, use_pallas_mix=pallas,
+                               use_pallas_quant=pallas)
+    state = jax.eval_shape(lambda: E.init_state(setup.stacked_params(),
+                                                ecfg, setup.solver))
+    c = obs_metrics.engine_exchange_layout_counter()
+    keys = [(p, lay) for p in ("quantize", "censor", "mix", "dual")
+            for lay in ("leaf", "packed")]
+    before = {k: c.value(part=k[0], layout=k[1]) for k in keys}
+    text = jax.jit(setup.build_step(ecfg)).lower(
+        state, setup.batch(0), setup.key(0)).as_text()
+    reads = {k: c.value(part=k[0], layout=k[1]) - before[k] for k in keys}
+    return text, reads, E.tree_dim(state.theta)
+
+
+@pytest.mark.parametrize("pallas", [False, True],
+                         ids=["default", "pallas-options"])
+def test_one_device_round_has_no_packed_row(pallas):
+    """The smoke xLSTM round on one device holds no array whose last axis
+    is the tree's whole width D: quantize, censor, mix and dual work on
+    each leaf in its own shape, and ``engine_exchange_layout`` reads
+    ``leaf`` for each. Under the Pallas options, which take the packed
+    ``(N, D)`` buffer, the same search finds it."""
+    import re
+    text, reads, d = _smoke_xlstm_round(pallas)
+    packed_rows = re.findall(r"[<x]%dx[a-z]\w*>" % d, text)
+    parts = ("quantize", "censor", "mix", "dual")
+    if not pallas:
+        assert not packed_rows, packed_rows[:5]
+        assert all(reads[(p, "leaf")] >= 1 and reads[(p, "packed")] == 0
+                   for p in parts), reads
+    else:
+        assert packed_rows
+        assert all(reads[(p, "packed")] >= 1 for p in parts
+                   if p != "censor"), reads

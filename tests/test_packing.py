@@ -73,11 +73,6 @@ def test_segment_reductions_match_per_leaf():
          np.abs(leaves[1]).max(1)], axis=1)
     np.testing.assert_allclose(np.asarray(P.segment_maxabs(pk, buf)),
                                want_max, rtol=1e-6)
-    want_sq = np.stack(
-        [(np.concatenate([leaves[0], leaves[2]], 1) ** 2).sum(1),
-         (leaves[1] ** 2).sum(1)], axis=1)
-    np.testing.assert_allclose(np.asarray(P.segment_sqnorm(pk, buf)),
-                               want_sq, rtol=1e-5)
 
 
 def test_cache_returns_same_instance():

@@ -95,7 +95,7 @@ topo = topo_lib.build(split.graph, "sharded", mesh=split.mesh,
 key = jax.random.PRNGKey(11)
 got = jax.jit(lambda q, t, k: E.grouped_quantize_step_split(
     q, t, k, qcfg, gids, topo))(quant, moved, key)
-want = jax.jit(lambda q, t, k: E.grouped_quantize_step(
+want = jax.jit(lambda q, t, k: E._grouped_quantize_step_packed(
     q, t, k, qcfg, gids))(*jax.device_get((quant, moved, key)))
 out["quantize_equal"] = all(
     np.array_equal(np.asarray(a), np.asarray(b))
@@ -188,15 +188,18 @@ def test_one_device_placement_unchanged():
     assert c.value(workers="2", chips_per_worker="1") == before + 1
 
 
-@pytest.mark.parametrize("shape,block", [
-    ((3, 10, 7), None),
-    ((3, 4, 5, 6), None),
-    ((3, 4, 5, 6), ((1, 2, 0, 3), (2, 2, 5, 3))),
-    ((3, 810), ((2, 405), (1, 405))),
-], ids=["3d", "4d", "4d-block", "2d-block"])
-def test_uniform_columns_are_the_packed_draw(shape, block):
-    """A leaf's uniforms, or a device's block of them, are bit for bit its
-    columns of the draw over the packed ``(n, d)`` row."""
+@pytest.mark.parametrize("shape,block,rows", [
+    ((3, 10, 7), None, None),
+    ((3, 4, 5, 6), None, None),
+    ((3, 4, 5, 6), ((1, 2, 0, 3), (2, 2, 5, 3)), None),
+    ((3, 810), ((2, 405), (1, 405)), None),
+    ((3, 4, 5, 6), None, (1,)),
+    ((3, 10, 7), None, (0, 2)),
+], ids=["3d", "4d", "4d-block", "2d-block", "4d-row", "3d-rows"])
+def test_uniform_columns_are_the_packed_draw(shape, block, rows):
+    """A leaf's uniforms, a device's block of them, or the acting rows'
+    uniforms, are bit for bit their columns of the draw over the packed
+    ``(n, d)`` row."""
     from repro.core import quantization as Q
     n, d, offset = shape[0], 2000, 70
     key = jax.random.PRNGKey(7)
@@ -204,7 +207,10 @@ def test_uniform_columns_are_the_packed_draw(shape, block):
     size = int(np.prod(shape[1:]))
     want = full[:, offset:offset + size].reshape(shape)
     got = np.asarray(jax.jit(lambda k: Q.uniform_columns(
-        k, n, d, offset, shape, block=block))(key))
+        k, n, d, offset, shape, block=block,
+        rows=None if rows is None else np.asarray(rows)))(key))
     if block is not None:
         want = want[tuple(slice(s, s + w) for s, w in zip(*block))]
+    if rows is not None:
+        want = want[list(rows)]
     np.testing.assert_array_equal(got, want)
